@@ -20,6 +20,8 @@ throughput is the overlap win the ring buys.
 
 Run as a module for one JSON line (bench.py merges it):
     python -m brpc_tpu.device_path [payload_mb] [reps] [ring_depth] [chunk_kb]
+That drives the first device; `run(..., device=d)` drives any one of
+`jax.devices()` (chip_smoke.py walks them all from one process).
 """
 import json
 import os
@@ -47,6 +49,34 @@ def _integrity_word(words):
     idx = jnp.arange(words.shape[-1], dtype=jnp.uint32)
     return jnp.sum(words * (idx * jnp.uint32(2) + jnp.uint32(1)),
                    dtype=jnp.uint32)
+
+
+def _integrity_word_host(words: np.ndarray) -> int:
+    """The same word computed by plain numpy on the host — the reference
+    the on-device kernel is held to (uint32 products and sum wrap)."""
+    idx = np.arange(words.shape[-1], dtype=np.uint32)
+    return int(np.sum(words * (idx * np.uint32(2) + np.uint32(1)),
+                      dtype=np.uint32))
+
+
+def _resolve_device(device):
+    """The device to drive; never a CPU standing in for a chip.
+
+    The cpu platform is accepted only where the process was pinned to it
+    on purpose (`JAX_PLATFORMS=cpu`: the tests, this sandbox). With any
+    other platform list — or none, where jax falls back to the CPU when
+    it finds no accelerator — a cpu device is an error, so a chip run can
+    never put a CPU's number under a device key."""
+    import jax
+
+    dev = jax.devices()[0] if device is None else device
+    if dev.platform == "cpu" and jax.config.jax_platforms != "cpu":
+        raise RuntimeError(
+            f"device path would run on {dev} but this process is not "
+            f"pinned to the cpu (jax_platforms={jax.config.jax_platforms!r}"
+            f", default backend {jax.default_backend()!r}): no accelerator "
+            "was found, or a cpu device was passed in a chip run")
+    return dev
 
 
 @lru_cache(maxsize=4)
@@ -188,12 +218,11 @@ class _ChunkPipeline:
 
 
 def run(payload_mb: int = 4, reps: int = 5, ring_depth: int = 4,
-        chunk_kb: int = 2044) -> dict:
-    from brpc_tpu import native
+        chunk_kb: int = 2044, device=None) -> dict:
+    from brpc_tpu import compile_cache, native
 
-    import jax
-
-    dev = jax.devices()[0]
+    compile_cache.enable()
+    dev = _resolve_device(device)
     chunk_bytes = (chunk_kb << 10) & ~4095
     n_chunks = max(1, (payload_mb << 20) // chunk_bytes)
     payload_bytes = n_chunks * chunk_bytes
@@ -248,15 +277,11 @@ def run(payload_mb: int = 4, reps: int = 5, ring_depth: int = 4,
     ring_s.close()
     ring_p.close()
 
-    # On-device integrity words must agree between the two paths (same
-    # chunks, same kernel), and off-cpu the first chunk's word is
-    # cross-checked against an independent host (cpu-jit) computation.
-    dev_ok = (len(pipe.dev_checks) == n_chunks * passes * samples and
-              pipe.dev_checks[:n_chunks] == serial.dev_checks[:n_chunks])
-    if dev.platform != "cpu":
-        host_chk = int(jax.jit(_integrity_word,
-                               backend="cpu")(chunks[0]))
-        dev_ok = dev_ok and pipe.dev_checks[0] == host_chk
+    # Every on-device integrity word, on both paths and every pass, must
+    # equal the independent numpy computation over the same chunk.
+    host_chk = [_integrity_word_host(c) for c in chunks]
+    want = host_chk * (passes * samples)
+    dev_ok = pipe.dev_checks == want and serial.dev_checks == want
     ok = serial.ok and pipe.ok and dev_ok
 
     # Bytes cross host->device and device->host once per chunk per rep.

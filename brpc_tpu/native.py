@@ -14,6 +14,9 @@ DMAs to HBM.
 from __future__ import annotations
 
 import ctypes
+import re
+import shutil
+import subprocess
 from pathlib import Path
 
 import numpy as np
@@ -22,13 +25,48 @@ _REPO = Path(__file__).resolve().parent.parent
 _LIB = None
 
 
+def build(repo: Path = _REPO, timeout: float | None = None) -> Path:
+    """cmake -G Ninja + ninja into `<repo>/build`; returns that directory.
+
+    The one build rule for chip_smoke.py, bench.py and the tests: a
+    `build/` that was not configured for THIS checkout (its
+    CMAKE_HOME_DIRECTORY names another path — a copied tree, which is what
+    the chip tool makes — or it has no cache at all) is discarded and
+    reconfigured, never trusted. Generated `*.pb.{h,cc}` come from this
+    build's protoc run only. Raises RuntimeError with the tool's last
+    output on failure."""
+    build_dir = repo / "build"
+    if build_dir.exists():
+        try:
+            m = re.search(r"^CMAKE_HOME_DIRECTORY:INTERNAL=(.*)$",
+                          (build_dir / "CMakeCache.txt").read_text(), re.M)
+        except FileNotFoundError:
+            m = None
+        if m is None or Path(m.group(1)).resolve() != repo.resolve():
+            shutil.rmtree(build_dir)
+    steps = []
+    if not (build_dir / "build.ninja").exists():
+        steps.append(["cmake", "-G", "Ninja", "-S", str(repo),
+                      "-B", str(build_dir)])
+    steps.append(["ninja", "-C", str(build_dir)])
+    for cmd in steps:
+        proc = subprocess.run(cmd, stdout=subprocess.PIPE,
+                              stderr=subprocess.STDOUT, text=True,
+                              timeout=timeout)
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"{' '.join(cmd)} failed (rc {proc.returncode}):\n"
+                f"{proc.stdout[-4000:]}")
+    return build_dir
+
+
 def lib() -> ctypes.CDLL:
     global _LIB
     if _LIB is None:
         so = _REPO / "build" / "libtpurpc.so"
         if not so.exists():
             raise FileNotFoundError(
-                f"{so} not built; run cmake/ninja first (bench.py build())"
+                f"{so} not built; run brpc_tpu.native.build() first"
             )
         L = ctypes.CDLL(str(so))
         L.tpurpc_global_init.restype = ctypes.c_int

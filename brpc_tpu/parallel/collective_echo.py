@@ -17,6 +17,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from brpc_tpu import compile_cache
+
+# Every step below is jitted: place the persistent compile cache before
+# the first of them compiles.
+compile_cache.enable()
+
 _MOD = jnp.uint32(65521)
 
 

@@ -6,7 +6,9 @@
 #include <unistd.h>
 
 #include <atomic>
+#include <condition_variable>
 #include <cstring>
+#include <mutex>
 #include <string>
 #include <thread>
 #include <vector>
@@ -267,6 +269,72 @@ TEST(SlabPool, PerThreadCacheKeepsClassMutexCold) {
     EXPECT_LE(mutex_touches, (size_t)(kThreads * 4));
 }
 
+// Regression for the descriptor leg on a many-core host: 1MiB pool
+// attachments are allocated by the caller and released wherever the
+// last reference drops — fiber workers, hardware_concurrency()+1 of
+// them. Parked in those threads' caches (8 slots each) the slots never
+// came back to the allocating thread; every round carved a fresh one,
+// the 64MiB shm region ran out, the next arena landed in anonymous
+// overflow and OffsetOf refused it ("pool attachment alloc failed").
+TEST(SlabPool, JumboSlotsFreedOnOtherThreadsComeBack) {
+    ASSERT_EQ(0, IciBlockPool::Init());
+    const int kWorkers = (int)std::thread::hardware_concurrency() + 1;
+    const int kRounds = 600;
+    const size_t kBytes = (1u << 20) - 128;  // echo_bench's descriptor size
+    const int cls = IciBlockPool::SlabClassOf(1u << 20);
+    const size_t carved0 = IciBlockPool::slab_class_stat(cls).carved;
+
+    // Long-lived workers (a thread's cache drains when it exits, which
+    // would hide the stranding), one mailbox each.
+    struct Mailbox {
+        std::mutex mu;
+        std::condition_variable cv;
+        IOBuf* item = nullptr;
+        bool quit = false;
+    };
+    std::vector<Mailbox> boxes((size_t)kWorkers);
+    std::vector<std::thread> workers;
+    for (int w = 0; w < kWorkers; ++w) {
+        workers.emplace_back([&boxes, w] {
+            Mailbox& b = boxes[(size_t)w];
+            std::unique_lock<std::mutex> lk(b.mu);
+            while (true) {
+                b.cv.wait(lk, [&b] { return b.item != nullptr || b.quit; });
+                if (b.item == nullptr) return;
+                delete b.item;  // last reference: the slot is freed HERE
+                b.item = nullptr;
+                b.cv.notify_all();
+            }
+        });
+    }
+    int failed_round = -1;
+    for (int r = 0; r < kRounds && failed_round < 0; ++r) {
+        auto* att = new IOBuf;
+        char* data = nullptr;
+        if (!IciBlockPool::AllocatePoolAttachment(kBytes, att, &data)) {
+            delete att;
+            failed_round = r;
+            break;
+        }
+        data[0] = (char)r;
+        Mailbox& b = boxes[(size_t)(r % kWorkers)];
+        std::unique_lock<std::mutex> lk(b.mu);
+        b.item = att;
+        b.cv.notify_all();
+        b.cv.wait(lk, [&b] { return b.item == nullptr; });
+    }
+    for (Mailbox& b : boxes) {
+        std::lock_guard<std::mutex> lk(b.mu);
+        b.quit = true;
+        b.cv.notify_all();
+    }
+    for (auto& th : workers) th.join();
+    EXPECT_EQ(-1, failed_round);  // never left the shm region
+    // One attachment live at a time: at most one fresh carve, whatever
+    // the host's core count.
+    EXPECT_LE(IciBlockPool::slab_class_stat(cls).carved, carved0 + 1);
+}
+
 // ---------------- device staging ring (ISSUE 9a) ----------------
 
 TEST(DeviceStagingRing, FifoAcquireCompleteOrderingUnder8Threads) {
@@ -497,7 +565,8 @@ TEST(BlockLease, ExactlyOnceReleaseAndExpiryReap) {
     ASSERT_TRUE(IciBlockPool::AllocatePoolAttachment(10000, &att2, &data));
     const uint64_t l2 = block_lease::Pin(std::move(att2));
     block_lease::Arm(l2, /*call_id=*/42,
-                     monotonic_time_us() - 10 * 1000 * 1000, /*peer=*/0);
+                     monotonic_time_us() - 10 * 1000 * 1000,
+                     block_lease::kNoPeer);
     const uint64_t reaped0 = block_lease::expired_reaped();
     EXPECT_GE(block_lease::ReapExpired(monotonic_time_us()), (size_t)1);
     EXPECT_EQ(reaped0 + 1, block_lease::expired_reaped());
@@ -596,6 +665,55 @@ TEST(BlockLease, LateLoserAckValidatesCallAndPeer) {
     EXPECT_TRUE(block_lease::Alive(l2));
     EXPECT_TRUE(block_lease::ReleaseAcked(l2, 9, 111));
     EXPECT_FALSE(block_lease::ReleaseAcked(l2, 9, 111));  // exactly once
+    EXPECT_EQ(live0, IciBlockPool::slab_allocated());
+}
+
+// SocketId 0 is a real socket — the first one a process creates, which
+// is the server side of echo_bench --ici. The registry once used 0 as
+// "no peer", so a response pin armed for it refused every desc_ack and
+// stayed pinned until the reaper.
+TEST(BlockLease, SocketIdZeroIsARealPeer) {
+    ASSERT_EQ(0, IciBlockPool::Init());
+    const size_t live0 = IciBlockPool::slab_allocated();
+    const int64_t dl = monotonic_time_us() + (int64_t)60e6;
+    const uint64_t kSid0 = 0;
+    char* data = nullptr;
+    IOBuf att;
+    ASSERT_TRUE(IciBlockPool::AllocatePoolAttachment(8000, &att, &data));
+    const uint64_t l = block_lease::Pin(std::move(att), "rsp");
+    ASSERT_TRUE(block_lease::Arm(l, 5, dl, kSid0));
+    // Another connection's ack (token-carrying or not) frees nothing.
+    EXPECT_FALSE(block_lease::ReleaseAcked(l, 5, 1));
+    EXPECT_FALSE(block_lease::ReleaseAcked(l, 5, block_lease::kNoPeer));
+    EXPECT_EQ((size_t)0, block_lease::ReleaseByCall(5, 1));
+    EXPECT_TRUE(block_lease::Alive(l));
+    // Its own desc_ack does, exactly once.
+    EXPECT_TRUE(block_lease::ReleaseAcked(l, 5, kSid0));
+    EXPECT_FALSE(block_lease::ReleaseAcked(l, 5, kSid0));
+
+    // Token-less ack and peer death name socket 0 the same way.
+    IOBuf att2, att3;
+    ASSERT_TRUE(IciBlockPool::AllocatePoolAttachment(8000, &att2, &data));
+    ASSERT_TRUE(IciBlockPool::AllocatePoolAttachment(8000, &att3, &data));
+    const uint64_t l2 = block_lease::Pin(std::move(att2), "rsp");
+    const uint64_t l3 = block_lease::Pin(std::move(att3), "rsp");
+    ASSERT_TRUE(block_lease::Arm(l2, 6, dl, kSid0));
+    ASSERT_TRUE(block_lease::Arm(l3, 7, dl, kSid0));
+    EXPECT_EQ((size_t)1, block_lease::ReleaseByCall(6, kSid0));
+    EXPECT_EQ((size_t)1, block_lease::ReleasePeer(kSid0));
+    EXPECT_FALSE(block_lease::Alive(l2));
+    EXPECT_FALSE(block_lease::Alive(l3));
+
+    // A lease armed with NO peer answers to no connection at all.
+    IOBuf att4;
+    ASSERT_TRUE(IciBlockPool::AllocatePoolAttachment(8000, &att4, &data));
+    const uint64_t l4 = block_lease::Pin(std::move(att4));
+    ASSERT_TRUE(block_lease::Arm(l4, 8, dl, block_lease::kNoPeer));
+    EXPECT_FALSE(block_lease::ReleaseAcked(l4, 8, kSid0));
+    EXPECT_FALSE(block_lease::ReleaseAcked(l4, 8, block_lease::kNoPeer));
+    EXPECT_EQ((size_t)0, block_lease::ReleasePeer(kSid0));
+    EXPECT_EQ((size_t)0, block_lease::ReleasePeer(block_lease::kNoPeer));
+    EXPECT_TRUE(block_lease::Release(l4));
     EXPECT_EQ(live0, IciBlockPool::slab_allocated());
 }
 

@@ -1,6 +1,7 @@
 // I/O core loopback tests: real sockets, real epoll, full read/write paths —
 // the in-process loopback style of the reference's tests (e.g.
 // test/brpc_channel_unittest.cpp:195 starts a real listener in-process).
+#include <sys/eventfd.h>
 #include <sys/socket.h>
 #include <unistd.h>
 
@@ -8,10 +9,12 @@
 #include <atomic>
 #include <cstring>
 #include <mutex>
+#include <thread>
 #include <vector>
 
 #include "tbase/errno.h"
 #include "tbase/flags.h"
+#include "tbase/time.h"
 #include "tfiber/fiber_sync.h"
 #include "tnet/acceptor.h"
 #include "tnet/event_dispatcher.h"
@@ -582,6 +585,142 @@ TEST(Net, ConnectFailureFailsSocket) {
         usleep(10000);
     }
     EXPECT_TRUE(s->Failed());
+}
+
+// ---------------- single-writer queue on a many-core host ----------------
+
+namespace {
+
+// A transport that takes every byte at once: the writer never parks, so
+// it retires — and the next Write elects a new one — about once per
+// request. Each request is one {thread, seq} record, and the transport
+// is the single place they all pass through, so it can tell a lost or
+// repeated request from its per-thread sequence and a second concurrent
+// writer from re-entry.
+class DrainTransport : public TransportEndpoint {
+public:
+    explicit DrainTransport(int nthreads)
+        : next_seq((size_t)nthreads), efd_(eventfd(0, EFD_NONBLOCK)) {
+        for (auto& n : next_seq) n.store(0, std::memory_order_relaxed);
+    }
+    ~DrainTransport() override { close(efd_); }
+    int event_fd() const override { return efd_; }
+    bool Established() const override { return true; }
+    ssize_t CutFromIOBufList(IOBuf* const* pieces, size_t count) override {
+        if (inside_.exchange(true, std::memory_order_acq_rel)) {
+            overlaps.fetch_add(1, std::memory_order_relaxed);
+        }
+        ssize_t n = 0;
+        for (size_t i = 0; i < count; ++i) {
+            uint32_t rec[2];
+            while (pieces[i]->cutn(rec, sizeof(rec)) == sizeof(rec)) {
+                n += (ssize_t)sizeof(rec);
+                if (rec[0] >= next_seq.size() ||
+                    rec[1] != next_seq[rec[0]].fetch_add(
+                                  1, std::memory_order_acq_rel)) {
+                    misordered.fetch_add(1, std::memory_order_relaxed);
+                }
+            }
+        }
+        inside_.store(false, std::memory_order_release);
+        return n;
+    }
+    int WaitWritable(int64_t) override { return 0; }
+    ssize_t Pump(IOPortal*) override {
+        errno = EAGAIN;
+        return -1;
+    }
+    void Close() override {}
+    void Release() override { delete this; }  // owned by the socket
+
+    // Records delivered so far per producing thread (= the next expected).
+    std::vector<std::atomic<uint32_t>> next_seq;
+    std::atomic<int64_t> misordered{0};
+    std::atomic<int64_t> overlaps{0};
+
+private:
+    int efd_;
+    std::atomic<bool> inside_{false};
+};
+
+}  // namespace
+
+// Regression for the writer-retire race: the fetch_sub that brings
+// write_pending_ to zero hands the writer role over, and the old code
+// zeroed the shared consumed-count AFTER it — clobbering (or leaking a
+// stale count into) a writer elected in that gap, so the queue wedged
+// or elected two writers at once. One core never shows it; more threads
+// than cores on a many-core host shows it within a second.
+TEST(Net, ConcurrentWritersRetireExactlyOnce) {
+    const int kThreads =
+        std::max(8, (int)std::thread::hardware_concurrency() + 1);
+    auto* transport = new DrainTransport(kThreads);
+    SocketOptions opts;
+    opts.fd = transport->event_fd();
+    opts.transport = transport;
+    opts.owns_transport = true;  // freed at recycle, after the last writer
+    SocketId id;
+    ASSERT_EQ(Socket::Create(opts, &id), 0);
+    SocketUniquePtr s;
+    ASSERT_EQ(Socket::AddressSocket(id, &s), 0);
+
+    // Each thread keeps ONE request in flight: the queue runs empty all
+    // the time, so writers retire and get elected at the highest rate.
+    const int64_t deadline_us = monotonic_time_us() + 3 * 1000 * 1000;
+    std::atomic<int64_t> written{0};
+    std::atomic<int64_t> refused{0};
+    std::atomic<int64_t> lost{0};
+    std::atomic<int> finished{0};
+    std::vector<std::thread> threads;
+    for (int t = 0; t < kThreads; ++t) {
+        threads.emplace_back([&, t] {
+            for (uint32_t seq = 0; monotonic_time_us() < deadline_us; ++seq) {
+                const uint32_t rec[2] = {(uint32_t)t, seq};
+                IOBuf data;
+                data.append(rec, sizeof(rec));
+                if (s->Write(&data) != 0) {
+                    refused.fetch_add(1, std::memory_order_relaxed);
+                    break;
+                }
+                written.fetch_add(1, std::memory_order_relaxed);
+                // A wedged queue never delivers: give up after 5s.
+                const int64_t give_up = monotonic_time_us() + 5 * 1000 * 1000;
+                while (transport->next_seq[(size_t)t].load(
+                           std::memory_order_acquire) <= seq) {
+                    if (monotonic_time_us() > give_up) {
+                        lost.fetch_add(1, std::memory_order_relaxed);
+                        finished.fetch_add(1);
+                        return;
+                    }
+                    std::this_thread::yield();
+                }
+            }
+            finished.fetch_add(1);
+        });
+    }
+    // A writer that lost count spins inside Write forever; such a thread
+    // cannot be joined, so a stuck run ends the process loudly instead.
+    for (int i = 0; i < 2000 && finished.load() < kThreads; ++i) usleep(10000);
+    if (finished.load() < kThreads) {
+        fprintf(stderr,
+                "ConcurrentWritersRetireExactlyOnce: %d of %d writers stuck "
+                "inside Socket::Write (pending_writes=%lld)\n",
+                kThreads - finished.load(), kThreads,
+                (long long)s->pending_writes());
+        abort();
+    }
+    for (auto& th : threads) th.join();
+    int64_t delivered = 0;
+    for (auto& n : transport->next_seq) delivered += n.load();
+    EXPECT_EQ(0, refused.load());
+    EXPECT_EQ(0, lost.load());
+    EXPECT_EQ(0, s->pending_writes());
+    EXPECT_EQ(0, s->unwritten_bytes());
+    EXPECT_EQ(written.load(), delivered);
+    EXPECT_EQ(0, transport->misordered.load());
+    EXPECT_EQ(0, transport->overlaps.load());
+    EXPECT_GT(written.load(), (int64_t)kThreads);
+    s->SetFailed();
 }
 
 // ---------------- transport tier registry (ISSUE 12) ----------------

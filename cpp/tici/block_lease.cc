@@ -43,7 +43,7 @@ struct Lease {
     // the backup's arm ADDS its key; only when every entitled peer is
     // gone may peer-death reclamation free the pin (a retry, whose
     // previous try is finished, REPLACES instead).
-    uint64_t peer_keys[2] = {0, 0};
+    uint64_t peer_keys[2] = {kNoPeer, kNoPeer};
     int npeers = 0;
 };
 
@@ -145,8 +145,8 @@ bool Arm(uint64_t lease_id, uint64_t call_id, int64_t deadline_us,
         l.npeers = 2;
     } else {
         l.peer_keys[0] = peer_key;
-        l.peer_keys[1] = 0;
-        l.npeers = peer_key != 0 ? 1 : 0;
+        l.peer_keys[1] = kNoPeer;
+        l.npeers = peer_key != kNoPeer ? 1 : 0;
     }
     flight::Record(flight::kLeaseArm, lease_id, call_id);
     return true;
@@ -208,7 +208,7 @@ size_t ReapExpired(int64_t now_us) {
 }
 
 size_t ReleasePeer(uint64_t peer_key) {
-    if (peer_key == 0) return 0;
+    if (peer_key == kNoPeer) return 0;
     std::vector<IOBuf> pins;
     {
         std::lock_guard<std::mutex> g(mu());
@@ -220,7 +220,7 @@ size_t ReleasePeer(uint64_t peer_key) {
                 if (l.peer_keys[i] == peer_key) {
                     // Drop this peer's entitlement; compact.
                     l.peer_keys[i] = l.peer_keys[l.npeers - 1];
-                    l.peer_keys[--l.npeers] = 0;
+                    l.peer_keys[--l.npeers] = kNoPeer;
                     held = true;
                     break;
                 }
@@ -343,12 +343,11 @@ std::string DebugString() {
         const Lease& l = kv.second;
         snprintf(line, sizeof(line),
                  "lease %llu dir=%s bytes=%zu call=%llu "
-                 "deadline_in_ms=%lld peer=%llu peer2=%llu\n",
+                 "deadline_in_ms=%lld peer=%lld peer2=%lld\n",
                  (unsigned long long)kv.first, l.direction,
                  l.pinned.size(), (unsigned long long)l.call_id,
                  (long long)((l.deadline_us - now) / 1000),
-                 (unsigned long long)l.peer_keys[0],
-                 (unsigned long long)l.peer_keys[1]);
+                 (long long)l.peer_keys[0], (long long)l.peer_keys[1]);
         out += line;
     }
     return out;
@@ -366,12 +365,12 @@ std::string JsonLeases(size_t max) {
         snprintf(line, sizeof(line),
                  "%s{\"id\": %llu, \"direction\": \"%s\", \"bytes\": %zu, "
                  "\"call\": %llu, \"deadline_in_ms\": %lld, "
-                 "\"peer\": %llu}",
+                 "\"peer\": %lld}",
                  shown == 0 ? "" : ", ", (unsigned long long)kv.first,
                  l.direction, l.pinned.size(),
                  (unsigned long long)l.call_id,
                  (long long)((l.deadline_us - now) / 1000),
-                 (unsigned long long)l.peer_keys[0]);
+                 (long long)l.peer_keys[0]);
         out += line;
         ++shown;
     }

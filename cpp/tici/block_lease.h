@@ -43,6 +43,11 @@
 namespace tpurpc {
 namespace block_lease {
 
+// The "no entitled peer" key. Peer keys are SocketIds and 0 IS one (the
+// first socket a process creates), so the sentinel is the one value no
+// live socket carries (== INVALID_VREF_ID; printed as -1 on /pools).
+constexpr uint64_t kNoPeer = ~(uint64_t)0;
+
 // Pin `buf` (ownership moves into the registry). Returns a nonzero
 // lease id. The bytes stay readable by peers until the first Release.
 // `direction` tags the lease for the /pools ledger: "req" = a client

@@ -325,6 +325,16 @@ constexpr size_t kSlabClassBytes[] = {8u << 10, 64u << 10, 256u << 10,
 constexpr int kSlabClasses =
     (int)(sizeof(kSlabClassBytes) / sizeof(kSlabClassBytes[0]));
 constexpr int kTlsSlotsPerClass = 8;
+// A thread parks at most this many BYTES of freed slots per class, so the
+// 1M/4M classes never park: slots freed on a thread that does not
+// allocate them (attachments released on fiber workers) would otherwise
+// strand — workers x 8 x 1MiB outgrows the 64MiB shm region, the next
+// arena lands in anonymous overflow and OffsetOf refuses it.
+constexpr size_t kTlsBytesPerClass = 512u << 10;
+constexpr int tls_slots_of(int cls) {
+    return (int)std::min<size_t>(kTlsSlotsPerClass,
+                                 kTlsBytesPerClass / kSlabClassBytes[cls]);
+}
 
 // One registered arena, chopped into slots of a single class. The arena
 // table is append-only and scanned lock-free (count published with
@@ -506,7 +516,7 @@ void IciBlockPool::FreeSlab(void* p) {
     g_slab_live.fetch_sub(1, std::memory_order_relaxed);
     g_class_live[cls].fetch_sub(1, std::memory_order_relaxed);
     TlsSlabCache& tls = g_tls_slabs;
-    if (tls.n[cls] < kTlsSlotsPerClass) {
+    if (tls.n[cls] < tls_slots_of(cls)) {
         tls.slots[cls][tls.n[cls]++] = p;
         return;
     }

@@ -285,7 +285,10 @@ int GrantWindow(uint64_t peer_key, uint64_t length, uint32_t mode,
     // The arm is the liveness registration: the reaper and peer-death
     // reclamation free the pin through the SAME lease machinery the
     // descriptor plane uses (call id = window id for the ledger).
-    block_lease::Arm(lease, lease, deadline, peer_key);
+    // This plane's "loopback, no link" peer is 0; the lease registry's is
+    // kNoPeer (0 is a real SocketId there).
+    block_lease::Arm(lease, lease, deadline,
+                     peer_key != 0 ? peer_key : block_lease::kNoPeer);
     VerbsStateImpl& s = S();
     Window w;
     w.lease = lease;

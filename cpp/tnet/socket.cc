@@ -477,11 +477,18 @@ int Socket::Write(IOBuf* data, uint64_t notify_id) {
         queued_highwater_.store(queued, std::memory_order_relaxed);
         queued_write_highwater_cell()->update_max(queued);
     }
+    // Count the request BEFORE publishing it. The writer subtracts what it
+    // consumed and retires when that reaches zero; were a request visible
+    // before it is counted, the writer could consume it first, drive the
+    // count through zero while still holding the role, and the next Write
+    // would elect a second writer beside it. Counted-first, the count never
+    // runs below the requests still owed, and a writer that sees a count
+    // without its request just grabs again until the push lands.
+    const bool elected =
+        write_pending_.fetch_add(1, std::memory_order_acq_rel) == 0;
     WriteRequest* old = write_head_.exchange(req, std::memory_order_acq_rel);
     req->next.store(old, std::memory_order_release);
-    if (write_pending_.fetch_add(1, std::memory_order_acq_rel) != 0) {
-        return 0;  // an active writer owns the queue
-    }
+    if (!elected) return 0;  // an active writer owns the queue
     // Elected the writer. Inside a coalescing round, hold the flush: later
     // responses of this round pile onto the queue and leave in ONE writev
     // when the scope flushes (chaos mode keeps the per-write KeepWrite
@@ -639,11 +646,12 @@ void Socket::DrainWriteQueue() {
             }
         }
         if (inflight_index_ >= inflight_batch_.size()) {
-            const int64_t prev =
-                write_pending_.fetch_sub(consumed, std::memory_order_acq_rel);
-            const bool retired = (prev == consumed);
+            // Zero the member BEFORE the fetch_sub (see FlushOnce).
+            const int64_t done = consumed;
             consumed = 0;
-            if (retired) return;
+            const int64_t prev =
+                write_pending_.fetch_sub(done, std::memory_order_acq_rel);
+            if (prev == done) return;
             continue;  // racing Write slipped in: grab again
         }
         while (inflight_index_ < inflight_batch_.size()) {
@@ -704,14 +712,17 @@ bool Socket::FlushOnce(bool allow_block) {
         }
         if (inflight_index_ >= inflight_batch_.size()) {
             // Nothing visible: try to retire.
-            const int64_t prev =
-                write_pending_.fetch_sub(consumed, std::memory_order_acq_rel);
-            const bool retired = (prev == consumed);
-            // Either way these requests are now accounted; the next writer
-            // generation must start from zero or it over-subtracts the
-            // election count and the queue wedges.
+            // The fetch_sub that reaches zero RELEASES the writer role: a
+            // Write on another thread may be elected the instant it lands.
+            // So the shared counter is zeroed first and only a local is
+            // subtracted; touching writer_consumed_ after the fetch_sub
+            // would clobber the next writer's count (wedged queue) or hand
+            // it a stale one (second writer elected, double free).
+            const int64_t done = consumed;
             consumed = 0;
-            if (retired) return true;
+            const int64_t prev =
+                write_pending_.fetch_sub(done, std::memory_order_acq_rel);
+            if (prev == done) return true;
             continue;  // more requests were queued; grab again
         }
         // Gather up to 64 iovecs from the batch tail.

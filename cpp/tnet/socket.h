@@ -298,6 +298,12 @@ public:
         return unwritten_bytes_.load(std::memory_order_relaxed);
     }
 
+    // Requests queued and not yet retired by the writer; 0 means no
+    // writer is elected (the single-writer election count).
+    int64_t pending_writes() const {
+        return write_pending_.load(std::memory_order_acquire);
+    }
+
     // ---- per-socket stats (reference socket.h:127 SocketStat) ----
     void add_bytes_read(int64_t n) {
         bytes_read_.fetch_add(n, std::memory_order_relaxed);

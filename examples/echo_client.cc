@@ -2,6 +2,7 @@
 //   echo_client HOST:PORT [count]
 #include <cstdio>
 #include <cstdlib>
+#include <string>
 
 #include "bench_echo.pb.h"
 #include "tbase/time.h"
@@ -30,11 +31,17 @@ int main(int argc, char** argv) {
         benchpb::EchoRequest request;
         benchpb::EchoResponse response;
         request.set_send_ts_us(monotonic_time_us());
-        cntl.request_attachment().append("hello tpu-rpc");
+        const std::string body = "hello tpu-rpc #" + std::to_string(i);
+        cntl.request_attachment().append(body);
         stub.Echo(&cntl, &request, &response, nullptr);  // sync: done=null
         if (cntl.Failed()) {
             fprintf(stderr, "rpc %d failed: %s\n", i,
                     cntl.ErrorText().c_str());
+            return 1;
+        }
+        if (cntl.response_attachment().to_string() != body) {
+            fprintf(stderr, "rpc %d: reply bytes differ from the request\n",
+                    i);
             return 1;
         }
         printf("echo %d: rtt=%lldus attachment=%zuB\n", i,

@@ -79,5 +79,9 @@ int main(int argc, char** argv) {
     const double secs = (double)(monotonic_time_us() - t0) / 1e6;
     printf("qps=%.0f  (%d fibers, %zuB payload)\n",
            (double)calls.load() / secs, nfibers, payload);
+    if (calls.load() == 0) {
+        fprintf(stderr, "no call succeeded against %s\n", argv[1]);
+        return 1;
+    }
     return 0;
 }

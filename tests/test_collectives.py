@@ -11,6 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from brpc_tpu.parallel.reference import coll_checksum, fill_deterministic
+
 
 @pytest.fixture(scope="module")
 def mesh():
@@ -75,33 +77,11 @@ def test_partition_step_compiles_with_collective(mesh):
 
 # ---------------- ISSUE 13: mesh-collective lowerings ----------------
 
-def _fill_deterministic(seq, key, n):
-    """numpy twin of CollectiveEngine::FillDeterministic (uint32 wrap):
-    word(i) = 0x9E3779B1*seq + 0x85EBCA77*key + 0xC2B2AE35*i."""
-    i = np.arange(n, dtype=np.uint64)
-    base = (0x9E3779B1 * (seq & 0xFFFFFFFF) +
-            0x85EBCA77 * (key & 0xFFFFFFFF)) & 0xFFFFFFFF
-    return ((base + 0xC2B2AE35 * i) & 0xFFFFFFFF).astype(np.uint32)
-
-
-def _coll_checksum(words):
-    """numpy twin of CollectiveEngine::Checksum == the adler frame
-    checksum of collective_echo (uint32 WRAPAROUND cumsum, mod 65521)."""
-    w = np.asarray(words, dtype=np.uint32)
-    lo = w & np.uint32(0xFFFF)
-    hi = w >> np.uint32(16)
-    halves = np.stack([lo, hi], axis=-1).reshape(-1).astype(np.uint64)
-    s1 = np.cumsum(halves) & 0xFFFFFFFF
-    a = int(s1[-1]) % 65521
-    b = int(np.sum(s1 % 65521)) % 65521
-    return (b << 16) | a
-
-
 def test_coll_checksum_matches_cpp_golden():
     # Locked against Collective.ChecksumAndFillAreStable in
     # cpp/tests/tcollective_test.cc — one formula, two languages.
-    assert _coll_checksum([1, 2, 3]) == 1310726
-    w = _fill_deterministic(7, 9001, 2)
+    assert coll_checksum([1, 2, 3]) == 1310726
+    w = fill_deterministic(7, 9001, 2)
     assert int(w[0]) == (0x9E3779B1 * 7 + 0x85EBCA77 * 9001) % (1 << 32)
     assert int(w[1]) == (int(w[0]) + 0xC2B2AE35) % (1 << 32)
 
@@ -196,7 +176,7 @@ def test_cpp_mesh_allreduce_bitexact_vs_jax(cpp_build, tmp_path, mesh):
         # Same payloads in JAX: row r = the deterministic fill of the
         # node with the r-th smallest port (the engine's rank order).
         rows = np.stack(
-            [_fill_deterministic(seq, p, nwords) for p in sorted(ports)]
+            [fill_deterministic(seq, p, nwords) for p in sorted(ports)]
         )
         step = make_allreduce_step(
             jax.sharding.Mesh(jax.devices("cpu")[:num], ("peers",))
@@ -207,7 +187,7 @@ def test_cpp_mesh_allreduce_bitexact_vs_jax(cpp_build, tmp_path, mesh):
         np.testing.assert_array_equal(jax_out, want)
         # ...and the C++ mesh produced the identical bits: checksum +
         # leading words on every node, nodes verified it internally too.
-        expect_checksum = _coll_checksum(want[0])
+        expect_checksum = coll_checksum(want[0])
         expect_head = [int(v) for v in want[0][:4]]
         for rep in results:
             assert rep["ok"] == 1, rep

@@ -121,6 +121,18 @@ struct CallCtx {
     std::atomic<int64_t>* bytes;
 };
 
+// Every mode runs over loopback with 10s timeouts: one failed call means
+// the path under test broke, and a rate computed around it would read as
+// a (slow) success. Counted here, checked before any result line.
+std::atomic<int64_t> g_failed_calls{0};
+
+bool AnyCallFailed() {
+    const int64_t n = g_failed_calls.load(std::memory_order_relaxed);
+    if (n == 0) return false;
+    fprintf(stderr, "%lld rpc(s) failed; no result\n", (long long)n);
+    return true;
+}
+
 void OnEchoDone(CallCtx* ctx) {
     if (!ctx->cntl.Failed()) {
         if (ctx->lat != nullptr) {
@@ -133,6 +145,7 @@ void OnEchoDone(CallCtx* ctx) {
         }
     } else {
         fprintf(stderr, "rpc failed: %s\n", ctx->cntl.ErrorText().c_str());
+        g_failed_calls.fetch_add(1, std::memory_order_relaxed);
     }
     ctx->pending->signal();
     delete ctx;
@@ -307,6 +320,8 @@ void* ScaleCaller(void* arg) {
         if (!cntl.Failed()) {
             *c->lat << (monotonic_time_us() - res.send_ts_us());
             c->calls->fetch_add(1, std::memory_order_relaxed);
+        } else {
+            g_failed_calls.fetch_add(1, std::memory_order_relaxed);
         }
     }
     return nullptr;
@@ -632,6 +647,7 @@ int main(int argc, char** argv) {
             return 1;
         }
         FLAGS_echo_slow_percent.set(0);
+        if (AnyCallFailed()) return 1;
         if (json) {
             printf("{\"tail_p50_us\": %lld, "
                    "\"tail_p99_nobackup_us\": %lld, "
@@ -668,6 +684,7 @@ int main(int argc, char** argv) {
         for (int i = 0; i < 4; ++i) {
             qps[i] = RunScaleLevel(stub, levels[i], 1500, &p99[i]);
         }
+        if (AnyCallFailed()) return 1;
         if (json) {
             printf("{\"scale_qps_1\": %.0f, \"scale_qps_4\": %.0f, "
                    "\"scale_qps_16\": %.0f, \"scale_qps_64\": %.0f, "
@@ -713,6 +730,7 @@ int main(int argc, char** argv) {
         fprintf(stderr, "wrote %d samples to %s\n", n, prof_path);
     }
 
+    if (AnyCallFailed()) return 1;
     if (json) {
         printf("{\"mbps\": %.1f, \"qps_4k\": %.0f, \"p50_us_4k\": %lld, "
                "\"p99_us_4k\": %lld}\n",

@@ -1,0 +1,391 @@
+// The benchmark's own load generator for the served cells: N caller fibers,
+// each a SYNC echo of a seeded attachment back to back (a closed loop:
+// in-flight work is bounded by N, so a stall makes replies late and can
+// make none fail), for a fixed wall-clock window.
+//
+//   echo_load --port P --callers N --bytes B --seed S --seconds T
+//             --sample-out FILE [--warm-ms W] [--timeout-ms D]
+//
+// Protocol with the parent (benchmark/drivers/served.py):
+//   1. connect (Channel::InitIci: TCP handshake, then the shm link), warm
+//      every caller for W ms, print "READY\n";
+//   2. wait for a line on stdin (the parent brackets the window with its
+//      counter scrapes and the profiler), then run the window;
+//   3. every operation STARTED inside the window is waited for (the
+//      per-call deadline bounds that drain) and its reply compared byte
+//      for byte with what was sent; latencies of all of them go to FILE as
+//      raw little-endian uint64 nanoseconds; one JSON line goes to stdout
+//      (with the fiber workers this process ran and the operations completed
+//      in each second of the window: a dip is told from a run that sat low).
+//
+// Payload of caller c, operation n (n counts from 1, warm-up included):
+//   bytes [0,8)  little-endian (c << 48) | n   -- a stale or crossed reply
+//                                                 cannot compare equal
+//   bytes [8,B)  little-endian words mix64(seed, c, j), see PayloadWord;
+//                benchmark/payload.py makes the same bytes in numpy, and the
+//                parent holds this file's digests to it.
+//
+//   echo_load --control-server K
+// is the CONTROL of the served cells (never run by the benchmark's own
+// runs): the plain echo handler put in the program's place, with one
+// guarantee broken -- every K-th reply has one bit flipped. It speaks the
+// same "PORT n" / stdin-EOF protocol as `echo_bench --ici-server`.
+#include <sys/prctl.h>
+#include <sys/resource.h>
+#include <signal.h>
+#include <time.h>
+#include <unistd.h>
+#include <zlib.h>
+
+#include <algorithm>
+#include <atomic>
+#include <cstdio>
+#include <cstdlib>
+#include <cstring>
+#include <map>
+#include <string>
+#include <vector>
+
+#include "bench_echo.pb.h"
+#include "tbase/endpoint.h"
+#include "tbase/flags.h"
+#include "tbase/iobuf.h"
+#include "tfiber/fiber.h"
+#include "tici/block_pool.h"
+#include "trpc/channel.h"
+#include "trpc/controller.h"
+#include "trpc/server.h"
+
+using namespace tpurpc;
+
+DECLARE_int32(socket_send_buffer_size);
+DECLARE_int32(socket_recv_buffer_size);
+
+namespace {
+
+int64_t NowNs() {
+    timespec ts;
+    clock_gettime(CLOCK_MONOTONIC, &ts);
+    return (int64_t)ts.tv_sec * 1000000000LL + ts.tv_nsec;
+}
+
+uint64_t Mix64(uint64_t z) {
+    z = (z ^ (z >> 30)) * 0xBF58476D1CE4E5B9ULL;
+    z = (z ^ (z >> 27)) * 0x94D049BB133111EBULL;
+    return z ^ (z >> 31);
+}
+
+// Word j of stream `stream` under `seed` (counter-based, so numpy can make
+// the same words in one vector expression).
+uint64_t PayloadWord(uint64_t seed, uint64_t stream, uint64_t j) {
+    return Mix64(seed * 0x9E3779B97F4A7C15ULL +
+                 stream * 0xD1B54A32D192ED03ULL +
+                 (j + 1) * 0x9E3779B97F4A7C15ULL);
+}
+
+std::string MakeBody(uint64_t seed, uint64_t stream, size_t nbytes) {
+    std::string out(nbytes, '\0');
+    for (size_t off = 0, j = 0; off < nbytes; off += 8, ++j) {
+        const uint64_t w = PayloadWord(seed, stream, j);
+        memcpy(&out[off], &w, std::min<size_t>(8, nbytes - off));
+    }
+    return out;
+}
+
+// got == tag(8 bytes) + body, compared in place block by block.
+bool SameBytes(const IOBuf& got, const char* tag, const std::string& body) {
+    if (got.size() != 8 + body.size()) return false;
+    size_t pos = 0;
+    for (size_t i = 0; i < got.backing_block_num(); ++i) {
+        size_t len = 0;
+        const char* p = got.backing_block_data(i, &len);
+        while (len > 0) {
+            if (pos < 8) {
+                const size_t n = std::min(len, 8 - pos);
+                if (memcmp(p, tag + pos, n) != 0) return false;
+                p += n, len -= n, pos += n;
+            } else {
+                if (memcmp(p, body.data() + (pos - 8), len) != 0) return false;
+                pos += len, len = 0;
+            }
+        }
+    }
+    return pos == 8 + body.size();
+}
+
+uint32_t Crc32Of(const IOBuf& buf) {
+    uint32_t crc = (uint32_t)crc32(0L, Z_NULL, 0);
+    for (size_t i = 0; i < buf.backing_block_num(); ++i) {
+        size_t len = 0;
+        const char* p = buf.backing_block_data(i, &len);
+        crc = (uint32_t)crc32(crc, (const Bytef*)p, (uInt)len);
+    }
+    return crc;
+}
+
+struct Shared {
+    benchpb::EchoService_Stub* stub;
+    int64_t timeout_ms;
+    int64_t t_start_ns;  // the window's start (per-second counts)
+    int64_t t_end_ns;    // no operation starts at or after this
+    bool record;       // false during the warm-up
+};
+
+struct Caller {
+    Shared* shared = nullptr;
+    uint64_t idx = 0;
+    std::string body;  // bytes [8,B)
+    IOBuf body_buf;    // the same, appended by reference to each request
+    uint64_t seq = 0;
+    int64_t attempted = 0, ok = 0, rpc_failed = 0, mismatched = 0;
+    int64_t last_done_ns = 0;
+    uint32_t last_reply_crc = 0;
+    std::vector<uint64_t> lat_ns;
+    std::vector<int64_t> per_s;  // completions in second i of the window
+    std::map<int, int64_t> errors;
+};
+
+void* CallerLoop(void* arg) {
+    Caller* c = (Caller*)arg;
+    Shared* s = c->shared;
+    for (;;) {
+        const int64_t t0 = NowNs();
+        if (t0 >= s->t_end_ns) break;
+        const uint64_t tag = (c->idx << 48) | ++c->seq;
+        Controller cntl;
+        cntl.set_timeout_ms(s->timeout_ms);
+        cntl.set_max_retry(0);
+        benchpb::EchoRequest req;
+        benchpb::EchoResponse res;
+        req.set_send_ts_us(t0 / 1000);
+        cntl.request_attachment().append(&tag, 8);
+        cntl.request_attachment().append(c->body_buf);
+        s->stub->Echo(&cntl, &req, &res, nullptr);
+        const int64_t t1 = NowNs();
+        if (!s->record) continue;
+        ++c->attempted;
+        c->last_done_ns = t1;
+        c->lat_ns.push_back((uint64_t)(t1 - t0));
+        const size_t sec = (size_t)((t1 - s->t_start_ns) / 1000000000LL);
+        if (sec >= c->per_s.size()) c->per_s.resize(sec + 1, 0);
+        ++c->per_s[sec];
+        if (cntl.Failed()) {
+            ++c->rpc_failed;
+            if (++c->errors[cntl.ErrorCode()] == 1) {
+                fprintf(stderr, "echo_load: caller %llu rpc failed (%d): %s\n",
+                        (unsigned long long)c->idx, cntl.ErrorCode(),
+                        cntl.ErrorText().c_str());
+            }
+        } else if (!SameBytes(cntl.response_attachment(), (const char*)&tag,
+                              c->body)) {
+            ++c->mismatched;
+        } else {
+            ++c->ok;
+        }
+        c->last_reply_crc = Crc32Of(cntl.response_attachment());
+    }
+    return nullptr;
+}
+
+void RunCallers(std::vector<Caller>& callers) {
+    std::vector<fiber_t> tids(callers.size());
+    for (size_t i = 0; i < callers.size(); ++i) {
+        fiber_start_background(&tids[i], nullptr, CallerLoop, &callers[i]);
+    }
+    for (fiber_t tid : tids) fiber_join(tid, nullptr);
+}
+
+double CpuSeconds() {
+    rusage ru;
+    getrusage(RUSAGE_SELF, &ru);
+    return ru.ru_utime.tv_sec + ru.ru_stime.tv_sec +
+           (ru.ru_utime.tv_usec + ru.ru_stime.tv_usec) / 1e6;
+}
+
+// ---- the control: a plain echo with every K-th reply altered ----------
+
+class FlippingEcho : public benchpb::EchoService {
+public:
+    explicit FlippingEcho(int64_t every) : every_(every) {}
+    void Echo(google::protobuf::RpcController* cntl_base,
+              const benchpb::EchoRequest* request,
+              benchpb::EchoResponse* response,
+              google::protobuf::Closure* done) override {
+        Controller* cntl = static_cast<Controller*>(cntl_base);
+        response->set_send_ts_us(request->send_ts_us());
+        if (every_ > 0 && calls_.fetch_add(1) % every_ == every_ - 1 &&
+            cntl->request_attachment().size() > 8) {
+            std::string bytes = cntl->request_attachment().to_string();
+            bytes[bytes.size() / 2] ^= 0x10;
+            cntl->response_attachment().append(bytes);
+        } else {
+            cntl->response_attachment().append(cntl->request_attachment());
+        }
+        done->Run();
+    }
+
+private:
+    const int64_t every_;
+    std::atomic<int64_t> calls_{0};
+};
+
+int RunControlServer(int64_t flip_every) {
+    prctl(PR_SET_PDEATHSIG, SIGKILL);
+    FLAGS_socket_send_buffer_size.set(1 << 20);
+    FLAGS_socket_recv_buffer_size.set(1 << 20);
+    if (IciBlockPool::Init() != 0) return 1;
+    static FlippingEcho service(flip_every);
+    static Server server;
+    if (server.AddService(&service) != 0) return 1;
+    server.SetMethodInlineSafe("benchpb.EchoService", "Echo");
+    EndPoint listen;
+    str2endpoint("127.0.0.1:0", &listen);
+    if (server.Start(listen, nullptr) != 0) return 1;
+    printf("PORT %d\n", server.listened_port());
+    fflush(stdout);
+    char buf[16];
+    while (read(0, buf, sizeof(buf)) > 0) {
+    }
+    server.Stop();
+    server.Join();
+    fflush(nullptr);
+    _exit(0);
+}
+
+}  // namespace
+
+int main(int argc, char** argv) {
+    int port = 0, ncallers = 0;
+    size_t nbytes = 0;
+    uint64_t seed = 0;
+    double seconds = 0;
+    int64_t warm_ms = 500, timeout_ms = 10000, control_every = -1;
+
+    const char* sample_out = nullptr;
+    for (int i = 1; i + 1 < argc; i += 2) {
+        const std::string k = argv[i];
+        const char* v = argv[i + 1];
+        if (k == "--port") port = atoi(v);
+        else if (k == "--callers") ncallers = atoi(v);
+        else if (k == "--bytes") nbytes = strtoull(v, nullptr, 10);
+        else if (k == "--seed") seed = strtoull(v, nullptr, 10);
+        else if (k == "--seconds") seconds = atof(v);
+        else if (k == "--warm-ms") warm_ms = atoll(v);
+        else if (k == "--timeout-ms") timeout_ms = atoll(v);
+        else if (k == "--sample-out") sample_out = v;
+        else if (k == "--control-server") control_every = atoll(v);
+        else {
+            fprintf(stderr, "echo_load: unknown option %s\n", k.c_str());
+            return 2;
+        }
+    }
+    if (control_every >= 0) return RunControlServer(control_every);
+    if (port <= 0 || ncallers <= 0 || ncallers > 4096 || nbytes <= 8 ||
+        seconds <= 0 || sample_out == nullptr) {
+        fprintf(stderr, "echo_load: --port --callers --bytes (>8) --seed "
+                        "--seconds --sample-out are required\n");
+        return 2;
+    }
+    prctl(PR_SET_PDEATHSIG, SIGKILL);  // never outlive the harness
+
+    // As tools/echo_bench.cc sets them on both sides of its --xproc round.
+    FLAGS_socket_send_buffer_size.set(1 << 20);
+    FLAGS_socket_recv_buffer_size.set(1 << 20);
+    if (IciBlockPool::Init() != 0) return 1;
+    Channel channel;
+    ChannelOptions copts;
+    copts.timeout_ms = timeout_ms;
+    copts.max_retry = 0;
+    EndPoint ep;
+    str2endpoint("127.0.0.1", port, &ep);
+    if (channel.InitIci(ep, &copts) != 0) {
+        fprintf(stderr, "echo_load: InitIci to port %d failed\n", port);
+        return 1;
+    }
+    benchpb::EchoService_Stub stub(&channel);
+
+    Shared shared{&stub, timeout_ms, 0, 0, false};
+    std::vector<Caller> callers((size_t)ncallers);
+    uint32_t body_crc = (uint32_t)crc32(0L, Z_NULL, 0);
+    for (int i = 0; i < ncallers; ++i) {
+        Caller& c = callers[(size_t)i];
+        c.shared = &shared;
+        c.idx = (uint64_t)i;
+        c.body = MakeBody(seed, (uint64_t)i, nbytes - 8);
+        c.body_buf.append(c.body);
+        body_crc = (uint32_t)crc32(body_crc, (const Bytef*)c.body.data(),
+                                   (uInt)c.body.size());
+    }
+
+    shared.t_end_ns = NowNs() + warm_ms * 1000000LL;
+    RunCallers(callers);
+    for (Caller& c : callers) {
+        c.lat_ns.reserve((size_t)(seconds * 200000.0 / ncallers) + 1024);
+        c.per_s.reserve((size_t)seconds + 64);
+    }
+
+    printf("READY\n");
+    fflush(stdout);
+    char go[8];
+    if (read(0, go, sizeof(go)) <= 0) return 1;  // parent went away
+
+    shared.record = true;
+    const double cpu0 = CpuSeconds();
+    const int64_t t0 = NowNs();
+    shared.t_start_ns = t0;
+    shared.t_end_ns = t0 + (int64_t)(seconds * 1e9);
+    RunCallers(callers);
+    const double cpu1 = CpuSeconds();
+
+    int64_t attempted = 0, ok = 0, rpc_failed = 0, mismatched = 0;
+    int64_t t_last = t0;
+    std::map<int, int64_t> errors;
+    FILE* f = fopen(sample_out, "wb");
+    if (f == nullptr) {
+        fprintf(stderr, "echo_load: cannot write %s\n", sample_out);
+        return 1;
+    }
+    std::string seqs, crcs;
+    std::vector<int64_t> per_s;
+    for (Caller& c : callers) {
+        if (c.per_s.size() > per_s.size()) per_s.resize(c.per_s.size(), 0);
+        for (size_t i = 0; i < c.per_s.size(); ++i) per_s[i] += c.per_s[i];
+        attempted += c.attempted;
+        ok += c.ok;
+        rpc_failed += c.rpc_failed;
+        mismatched += c.mismatched;
+        t_last = std::max(t_last, c.last_done_ns);
+        for (auto& kv : c.errors) errors[kv.first] += kv.second;
+        if (!c.lat_ns.empty() &&
+            fwrite(c.lat_ns.data(), 8, c.lat_ns.size(), f) !=
+                c.lat_ns.size()) {
+            fprintf(stderr, "echo_load: short write to %s\n", sample_out);
+            return 1;
+        }
+        seqs += (seqs.empty() ? "" : ",") + std::to_string(c.seq);
+        crcs += (crcs.empty() ? "" : ",") + std::to_string(c.last_reply_crc);
+    }
+    fclose(f);
+    std::string secs;
+    for (int64_t n : per_s) {
+        secs += (secs.empty() ? "" : ",") + std::to_string(n);
+    }
+    std::string errs;
+    for (auto& kv : errors) {
+        errs += (errs.empty() ? "\"" : ",\"") + std::to_string(kv.first) +
+                "\":" + std::to_string(kv.second);
+    }
+    // window_s runs from the first start to the LAST completion: operations
+    // in flight when the window closes are drained inside it.
+    printf("{\"attempted\":%lld,\"ok\":%lld,\"rpc_failed\":%lld,"
+           "\"mismatched\":%lld,\"window_s\":%.9f,\"client_cpu_s\":%.6f,"
+           "\"bytes_each\":%zu,\"body_crc32\":%u,\"last_seq\":[%s],"
+           "\"last_reply_crc32\":[%s],\"errors\":{%s},\"workers\":%d,"
+           "\"per_s\":[%s]}\n",
+           (long long)attempted, (long long)ok, (long long)rpc_failed,
+           (long long)mismatched, (double)(t_last - t0) / 1e9, cpu1 - cpu0,
+           nbytes, body_crc, seqs.c_str(), crcs.c_str(), errs.c_str(),
+           fiber_get_worker_count(), secs.c_str());
+    fflush(stdout);
+    _exit(0);  // as the program's tools: no static teardown under live threads
+}

@@ -34,6 +34,7 @@ import numpy as np
 
 # In-place frame headroom per slot (importing brpc_tpu.native does NOT
 # load the shared library — that happens lazily at the first call).
+from brpc_tpu import spans
 from brpc_tpu.native import IN_PLACE_HEADROOM as HEADROOM
 
 
@@ -135,6 +136,16 @@ class _ChunkPipeline:
         self.crcs = [0] * ring.depth  # staged crc per in-flight slot
         self.ok = True
         self.dev_checks = []
+        self.passes = 0  # passes begun: the `pass` of a span's request
+
+    # Spans (brpc_tpu/spans.py), request = (pass, chunk): per pass one
+    # `ring.pass` (its self time is this loop's own), per chunk one
+    # `ring.launch` with children ring.acquire (waiting for a free slot),
+    # ring.stage (the copy into the slot), ring.frame (in-place framing),
+    # ring.h2d, ring.kernel_dispatch (the jitted pass + the async D2H
+    # request), and one `ring.retire` with children ring.d2h_wait (blocks
+    # until the device is done), ring.verify (crc32c), ring.complete.
+    # PERF.md section 3 names the metric that reads each.
 
     # Never park forever on the ring (ISSUE 10c): a wedged device stream
     # (lost completion, dead driver) must surface as an error, not a hung
@@ -145,75 +156,101 @@ class _ChunkPipeline:
     def _launch(self, k):
         import jax
         from brpc_tpu import native
-        try:
-            slot = self.ring.acquire(self.ACQUIRE_TIMEOUT_US)
-        except TimeoutError:
-            self.ring.abort()
-            raise RuntimeError(
-                "staging-ring acquire timed out (lost completion or "
-                "wedged device stream); ring aborted") from None
-        sa = self.ring.slots[slot]
-        clen = self.chunk_bytes
-        if self.copy_mode:
-            # Old path: frame() memcpys the external payload into the
-            # staging buffer, then device_put copies it again.
-            fr = native.frame(k + 1, self.chunks[k], out=sa)
-            foff, flen = 0, len(fr)
-            poff = flen - clen
-            x = jax.device_put(sa[poff:poff + clen].view(np.uint32),
-                               self.dev)
-        else:
-            # Ring path: stage the chunk payload once, frame in place
-            # (no payload memcpy — ISSUE 9 satellite), import zero-copy.
-            poff = HEADROOM
-            np.copyto(sa[poff:poff + clen].view(np.uint32),
-                      self.chunks[k])
-            foff, flen, crc = native.frame_in_place(k + 1, sa, poff, clen)
-            self.crcs[slot] = crc
-            x = _h2d(sa[poff:poff + clen].view(np.uint32), self.dev)
-        y, chk = self.touch(x)
-        if not self.copy_mode and hasattr(y, "copy_to_host_async"):
-            y.copy_to_host_async()
-        return (k, slot, foff, flen, poff, y, chk)
+        req = (self.passes, k)
+        with spans.span("ring.launch", req):
+            with spans.span("ring.acquire", req):
+                try:
+                    slot = self.ring.acquire(self.ACQUIRE_TIMEOUT_US)
+                except TimeoutError:
+                    self.ring.abort()
+                    raise RuntimeError(
+                        "staging-ring acquire timed out (lost completion "
+                        "or wedged device stream); ring aborted") from None
+            sa = self.ring.slots[slot]
+            clen = self.chunk_bytes
+            if self.copy_mode:
+                # Old path: frame() memcpys the external payload into the
+                # staging buffer, then device_put copies it again.
+                with spans.span("ring.frame", req):
+                    fr = native.frame(k + 1, self.chunks[k], out=sa)
+                foff, flen = 0, len(fr)
+                poff = flen - clen
+                with spans.span("ring.h2d", req):
+                    x = jax.device_put(
+                        sa[poff:poff + clen].view(np.uint32), self.dev)
+            else:
+                # Ring path: stage the chunk payload once, frame in place
+                # (no payload memcpy — ISSUE 9 satellite), import
+                # zero-copy.
+                poff = HEADROOM
+                with spans.span("ring.stage", req):
+                    np.copyto(sa[poff:poff + clen].view(np.uint32),
+                              self.chunks[k])
+                with spans.span("ring.frame", req):
+                    foff, flen, crc = native.frame_in_place(k + 1, sa, poff,
+                                                            clen)
+                self.crcs[slot] = crc
+                with spans.span("ring.h2d", req):
+                    x = _h2d(sa[poff:poff + clen].view(np.uint32), self.dev)
+            with spans.span("ring.kernel_dispatch", req):
+                y, chk = self.touch(x)
+                if not self.copy_mode and hasattr(y, "copy_to_host_async"):
+                    y.copy_to_host_async()
+        return (req, slot, foff, flen, poff, y, chk)
 
     def _retire(self, item):
         from brpc_tpu import native
-        k, slot, foff, flen, poff, y, chk = item
-        sa = self.ring.slots[slot]
-        if self.copy_mode:
-            # Old path: block, MATERIALIZE a fresh ndarray, copy back
-            # into staging, then have the framework re-parse + crc32c-
-            # verify the whole frame around the returned payload.
-            back = np.array(y)
-            np.copyto(sa[poff:poff + self.chunk_bytes].view(np.uint32),
-                      back)
-            cid, _, _ = native.unframe(sa[foff:foff + flen])
-            self.ok = self.ok and cid == k + 1
-        else:
-            # Ring path: the D2H buffer is verified DIRECTLY against the
-            # crc32c the C++ framework embedded at frame time — per-chunk
-            # integrity with no copy-back and no re-parse (the parse path
-            # is exercised by the serial baseline and the native tests).
-            back = np.asarray(y)  # blocks until the device is done
-            self.ok = (self.ok and
-                       native.crc32c(back) == self.crcs[slot])
-        self.dev_checks.append(int(chk))
-        self.ring.complete(slot)
+        req, slot, foff, flen, poff, y, chk = item
+        k = req[1]
+        with spans.span("ring.retire", req):
+            sa = self.ring.slots[slot]
+            with spans.span("ring.d2h_wait", req):
+                # Blocks until the device is done; the old path MATERIALIZES
+                # a fresh ndarray. Then its integrity word comes back.
+                back = np.array(y) if self.copy_mode else np.asarray(y)
+                word = int(chk)
+            with spans.span("ring.verify", req):
+                if self.copy_mode:
+                    # Old path: copy back into staging, then have the
+                    # framework re-parse + crc32c-verify the whole frame
+                    # around the returned payload.
+                    np.copyto(
+                        sa[poff:poff + self.chunk_bytes].view(np.uint32),
+                        back)
+                    cid, _, _ = native.unframe(sa[foff:foff + flen])
+                    good = cid == k + 1
+                else:
+                    # Ring path: the D2H buffer is verified DIRECTLY
+                    # against the crc32c the C++ framework embedded at
+                    # frame time — per-chunk integrity with no copy-back
+                    # and no re-parse (the parse path is exercised by the
+                    # serial baseline and the native tests).
+                    good = native.crc32c(back) == self.crcs[slot]
+            self.ok = self.ok and good
+            self.dev_checks.append(word)
+            with spans.span("ring.complete", req):
+                self.ring.complete(slot)
 
     def run(self, reps):
         t0 = time.monotonic()
         inflight = deque()
         for _ in range(reps):
-            for k in range(len(self.chunks)):
-                inflight.append(self._launch(k))
-                # Serial (depth=1): drain immediately — nothing overlaps.
-                # Pipelined: keep `depth` chunks in flight; retiring the
-                # oldest overlaps its D2H/verify with the younger chunks'
-                # H2D + compute.
-                while len(inflight) >= self.depth:
-                    self._retire(inflight.popleft())
-        while inflight:
-            self._retire(inflight.popleft())
+            self.passes += 1
+            # The pass's span ends with the pass's last launch; the chunks
+            # still in flight then retire under the next pass's span, or
+            # under the drain's below.
+            with spans.span("ring.pass", (self.passes, None)):
+                for k in range(len(self.chunks)):
+                    inflight.append(self._launch(k))
+                    # Serial (depth=1): drain immediately — nothing
+                    # overlaps. Pipelined: keep `depth` chunks in flight;
+                    # retiring the oldest overlaps its D2H/verify with the
+                    # younger chunks' H2D + compute.
+                    while len(inflight) >= self.depth:
+                        self._retire(inflight.popleft())
+        with spans.span("ring.pass", (self.passes, None)):
+            while inflight:
+                self._retire(inflight.popleft())
         return time.monotonic() - t0
 
 

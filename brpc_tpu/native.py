@@ -140,6 +140,8 @@ def lib() -> ctypes.CDLL:
             ctypes.POINTER(ctypes.c_size_t),
             ctypes.POINTER(ctypes.c_size_t),
         ]
+        L.tpurpc_stage_dump.restype = ctypes.c_long
+        L.tpurpc_stage_dump.argtypes = [ctypes.c_char_p, ctypes.c_size_t]
         if L.tpurpc_global_init() != 0:
             raise RuntimeError("tpurpc_global_init failed")
         _LIB = L
@@ -151,6 +153,21 @@ def crc32c(data: bytes | np.ndarray, init: int = 0) -> int:
         data, np.ndarray) else np.frombuffer(data, dtype=np.uint8)
     return int(lib().tpurpc_crc32c(
         init, buf.ctypes.data_as(ctypes.c_void_p), buf.nbytes))
+
+
+def stage_dump() -> dict:
+    """This process's stage-clock table (cpp/tvar/stage_recorder.h): the
+    object `/status?format=json` carries under "stages", cumulative since
+    the library was loaded, for a process that serves no portal."""
+    import json
+
+    cap = 1 << 16
+    while True:
+        buf = ctypes.create_string_buffer(cap)
+        n = lib().tpurpc_stage_dump(buf, cap)
+        if n < cap:
+            return json.loads(buf.value)
+        cap = n + 1
 
 
 class PoolBuffer:

@@ -9,6 +9,7 @@
 #include "echo.pb.h"
 #include "tbase/endpoint.h"
 #include "tbase/errno.h"
+#include "tbase/time.h"
 #include "tfiber/fiber.h"
 #include "trpc/auth.h"
 #include "trpc/channel.h"
@@ -51,9 +52,18 @@ private:
 
 class AuthEchoImpl : public test::EchoService {
 public:
-    void Echo(google::protobuf::RpcController*,
+    // The budget (us) the call named "budget" still had as its handler
+    // was entered, by the deadline its caller sent.
+    std::atomic<int64_t> budget_seen_us{-1};
+
+    void Echo(google::protobuf::RpcController* c,
               const test::EchoRequest* request, test::EchoResponse* response,
               google::protobuf::Closure* done) override {
+        if (request->message() == "budget") {
+            budget_seen_us.store(
+                static_cast<Controller*>(c)->server_deadline_us() -
+                monotonic_time_us());
+        }
         if (request->sleep_us() > 0) fiber_usleep(request->sleep_us());
         response->set_message(request->message());
         done->Run();
@@ -180,6 +190,52 @@ TEST(Auth, ConcurrentFirstWritesAuthenticateExactlyOnce) {
     EXPECT_EQ(client_auth.generated(), 1);
     EXPECT_EQ(server_auth.verified(), 1);
     EXPECT_EQ(ts.server.acceptor()->accepted_count(), 1);
+}
+
+TEST(Auth, BudgetSentAfterTheAuthWaitIsWhatIsLeftOfIt) {
+    // The first caller wins the auth fight and its handler sleeps 400 ms,
+    // so the connection stays unauthenticated that long. A second caller
+    // with a 1000 ms timeout waits the fight out inside IssueRPC
+    // (WaitAuthenticated) and only then builds its request: the
+    // timeout_ms it sends is what is LEFT of its budget at that moment,
+    // not what it had when CallMethod was entered -- a server must not be
+    // told it may work for time the caller has already spent waiting.
+    CountingAuth server_auth("s3cret");
+    CountingAuth client_auth("s3cret");
+    AuthServer ts;
+    ASSERT_TRUE(ts.start(&server_auth));
+    Channel ch;
+    ChannelOptions opts;
+    opts.auth = &client_auth;
+    opts.timeout_ms = 1000;
+    opts.max_retry = 0;
+    ASSERT_EQ(0, ch.Init(ts.ep, &opts));
+    fiber_t winner;
+    fiber_start_background(
+        &winner, nullptr,
+        [](void* arg) -> void* {
+            test::EchoService_Stub stub((Channel*)arg);
+            Controller cntl;
+            test::EchoRequest req;
+            req.set_message("winner");
+            req.set_sleep_us(400 * 1000);
+            test::EchoResponse res;
+            stub.Echo(&cntl, &req, &res, nullptr);
+            return nullptr;
+        },
+        &ch);
+    fiber_usleep(50 * 1000);  // the winner's request is out by now
+    const int64_t t0 = monotonic_time_us();
+    ASSERT_EQ(0, DoEcho(&ch, "budget"));
+    const int64_t waited_us = monotonic_time_us() - t0;
+    fiber_join(winner, nullptr);
+    EXPECT_EQ(client_auth.generated(), 1);  // it waited, it did not fight
+    EXPECT_GE(waited_us, 250 * 1000);
+    const int64_t seen_us = ts.service.budget_seen_us.load();
+    // ~1000 - 350 ms were left; ms rounding and the trip to the handler
+    // take a few more. Sending the budget as of CallMethod reads ~1000.
+    EXPECT_GT(seen_us, 300 * 1000);
+    EXPECT_LT(seen_us, 800 * 1000);
 }
 
 TEST(AuthGrpc, HeaderVerifiedPerCall) {

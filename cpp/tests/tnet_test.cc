@@ -83,9 +83,18 @@ void frame(IOBuf* out, const IOBuf& payload) {
     out->append(payload);
 }
 
+// Where a test asks for them: each served message's stage-clock origin
+// (InputMessageBase::consumed_us), in the order they were processed.
+std::mutex g_consumed_mu;
+std::vector<int64_t>* g_consumed = nullptr;
+
 // Server side: echo the payload back.
 void server_process(InputMessageBase* raw) {
     TestMsg* msg = (TestMsg*)raw;
+    {
+        std::lock_guard<std::mutex> g(g_consumed_mu);
+        if (g_consumed != nullptr) g_consumed->push_back(msg->consumed_us);
+    }
     SocketUniquePtr s;
     if (Socket::AddressSocket(msg->socket_id, &s) == 0) {
         IOBuf out;
@@ -326,6 +335,56 @@ TEST(Net, PeekFastPathSplitHeaders) {
         EXPECT_EQ(sink.responses[1], body);
     }
     g_sink = nullptr;
+}
+
+// Stage clock: a message's clock starts at the read that brought ITS first
+// bytes. A's head arrives alone; 150 ms later one write brings A's tail and
+// B's head, so the buffer is not empty when B's bytes land; 150 ms later B's
+// tail. B must not inherit A's stamp (it would read as consumed 150 ms
+// before its sender wrote it).
+TEST(Net, AMessageLeftBehindByACutStartsItsClockAtItsOwnBytes) {
+    ClientSink sink;
+    g_sink = &sink;
+    std::vector<int64_t> consumed;
+    {
+        std::lock_guard<std::mutex> g(g_consumed_mu);
+        g_consumed = &consumed;
+    }
+    EchoFixture fx;
+    ASSERT_TRUE(fx.Start());
+    SocketUniquePtr cs;
+    ASSERT_EQ(Socket::AddressSocket(fx.client_id, &cs), 0);
+    std::string wire;
+    for (const char* body : {"message-a-body", "message-b-body"}) {
+        IOBuf payload, framed;
+        payload.append(body);
+        frame(&framed, payload);
+        wire += framed.to_string();
+    }
+    const size_t one = wire.size() / 2;  // both frames are the same size
+    const size_t cuts[] = {0, one / 2, one + one / 2, wire.size()};
+    sink.pending.reset(2);
+    int64_t wrote_us[3];
+    for (int i = 0; i < 3; ++i) {
+        if (i > 0) usleep(150 * 1000);
+        IOBuf piece;
+        piece.append(wire.data() + cuts[i], cuts[i + 1] - cuts[i]);
+        wrote_us[i] = monotonic_time_us();
+        ASSERT_EQ(cs->Write(&piece), 0);
+    }
+    ASSERT_EQ(sink.pending.wait(), 0);
+    {
+        std::lock_guard<std::mutex> g(g_consumed_mu);
+        g_consumed = nullptr;
+    }
+    g_sink = nullptr;
+    ASSERT_EQ(consumed.size(), 2u);
+    // Each origin lies at or after the write that carried the message's
+    // first byte, and before the next write.
+    EXPECT_GE(consumed[0], wrote_us[0]);
+    EXPECT_LT(consumed[0], wrote_us[1]);
+    EXPECT_GE(consumed[1], wrote_us[1]);
+    EXPECT_LT(consumed[1], wrote_us[2]);
 }
 
 // A sticky socket whose next bytes are NOT the sticky protocol's resets
@@ -606,7 +665,8 @@ public:
     ~DrainTransport() override { close(efd_); }
     int event_fd() const override { return efd_; }
     bool Established() const override { return true; }
-    ssize_t CutFromIOBufList(IOBuf* const* pieces, size_t count) override {
+    ssize_t CutFromIOBufList(IOBuf* const* pieces, size_t count,
+                             int64_t*) override {
         if (inside_.exchange(true, std::memory_order_acq_rel)) {
             overlaps.fetch_add(1, std::memory_order_relaxed);
         }
@@ -626,7 +686,7 @@ public:
         return n;
     }
     int WaitWritable(int64_t) override { return 0; }
-    ssize_t Pump(IOPortal*) override {
+    ssize_t Pump(IOPortal*, PumpStamps*) override {
         errno = EAGAIN;
         return -1;
     }

@@ -6,6 +6,9 @@
 // seed + same allocation sequence -> stable stack set), scheduler
 // counters, dispatcher telemetry, and per-tuple series fields of
 // labelled families.
+// Stage clock (ISSUE 25): StageRecorder is cumulative, two dumps difference
+// to exactly what was added between them, its quantile lands within one
+// bucket, and a sample costs nanoseconds with every thread writing.
 #include <unistd.h>
 
 #include <atomic>
@@ -13,6 +16,7 @@
 #include <cstring>
 #include <functional>
 #include <string>
+#include <thread>
 #include <vector>
 
 #include "echo.pb.h"
@@ -35,6 +39,7 @@
 #include "tvar/multi_dimension.h"
 #include "tvar/reducer.h"
 #include "tvar/series.h"
+#include "tvar/stage_recorder.h"
 #include "tvar/variable.h"
 
 using namespace tpurpc;
@@ -575,4 +580,159 @@ TEST(SpanAnnotations, ExpiredDownstreamShedAnnotatedOnClientSpan) {
                           3000));
     server.Stop();
     server.Join();
+}
+
+// ---------------- stage clock (ISSUE 25) ----------------
+
+namespace {
+int64_t thread_cpu_ns() {
+    timespec ts;
+    clock_gettime(CLOCK_THREAD_CPUTIME_ID, &ts);
+    return ts.tv_sec * 1000000000L + ts.tv_nsec;
+}
+
+// The tests sample the stage the dumps never show, so nothing else in
+// this process (earlier tests' RPCs, their fibers) writes beside them.
+stage::Snapshot TestStage() { return stage::SnapshotOf(stage::kTestOnly); }
+}  // namespace
+
+TEST(StageRecorder, TheTableIsTheEnumAndTheTestStageIsNotPublished) {
+    const auto all = stage::SnapshotAll();
+    ASSERT_EQ((int)all.size(), (int)stage::kPublished);
+    EXPECT_EQ(all[stage::kLinkHandoff].name, "tici.link_handoff");
+    EXPECT_EQ(all[stage::kWriteQueue].name, "tnet.write_queue");
+    EXPECT_EQ(all[stage::kCallerWake].name, "trpc.caller_wake");
+    EXPECT_EQ(all[stage::kWakeToRun].name, "tfiber.wake_to_run");
+    EXPECT_EQ(TestStage().name, "test.only");
+    EXPECT_TRUE(stage::DumpJson().find("test.only") == std::string::npos);
+    // An id outside the table is dropped, not written somewhere.
+    stage::Add(stage::kCount, 1);
+    stage::Add(-1, 1);
+    EXPECT_EQ(stage::SnapshotOf(stage::kCount).count, 0u);
+}
+
+TEST(StageRecorder, CumulativeAndTwoDumpsDifferenceToWhatWasAdded) {
+    const int id = stage::kTestOnly;
+    stage::Add(id, 5);
+    stage::Add(id, 700);
+    const stage::Snapshot before = TestStage();
+    EXPECT_GE(before.count, 2u);
+    // Added between the dumps, from this thread and from one that exits
+    // (its cell folds into the table): 100 samples of 40 us, one of
+    // 9,000,000 (above anything another test adds here).
+    for (int i = 0; i < 50; ++i) stage::Add(id, 40);
+    std::thread([id] {
+        for (int i = 0; i < 50; ++i) stage::Add(id, 40);
+        stage::Add(id, 9000000);
+    }).join();
+    stage::Add(id, -3);  // a negative duration counts as 0
+    stage::Snapshot after = TestStage();
+    EXPECT_EQ(after.count - before.count, 102u);
+    EXPECT_EQ(after.sum_us - before.sum_us, 100 * 40 + 9000000);
+    EXPECT_EQ(after.max_us, 9000000);
+    after.hist.subtract(before.hist);
+    EXPECT_EQ(after.hist.total(), 102u);
+    EXPECT_EQ(after.hist.buckets[PercentileHistogram::bucket_of(40)], 100u);
+    EXPECT_EQ(after.hist.buckets[PercentileHistogram::bucket_of(9000000)],
+              1u);
+    EXPECT_EQ(after.hist.buckets[0], 1u);
+    // Nothing is ever reset: a third dump still holds the first two.
+    EXPECT_EQ(TestStage().count, before.count + 102u);
+}
+
+TEST(StageRecorder, QuantileWithinOneBucketAndDumpsAgree) {
+    stage::Snapshot before = TestStage();
+    for (int v = 1; v <= 10000; ++v) stage::Add(stage::kTestOnly, v);
+    stage::Snapshot s = TestStage();
+    s.hist.subtract(before.hist);
+    for (double q : {0.5, 0.9, 0.99}) {
+        const int64_t want = (int64_t)(q * 10000);
+        const int got_bucket =
+            PercentileHistogram::bucket_of(s.hist.quantile(q));
+        const int want_bucket = PercentileHistogram::bucket_of(want);
+        EXPECT_LE(std::abs(got_bucket - want_bucket), 1)
+            << "q=" << q << " got " << s.hist.quantile(q);
+    }
+    // The three published forms come from the one table. trpc.caller_wake
+    // is sampled only as a synchronous call returns, and none is in
+    // flight here: give it the values 1..2000 on top of what earlier
+    // tests' calls left, and look for its snapshot in each form.
+    for (int v = 1; v <= 2000; ++v) stage::Add(stage::kCallerWake, v);
+    const stage::Snapshot w = stage::SnapshotOf(stage::kCallerWake);
+    ASSERT_GE(w.count, 2000u);
+    const std::string n = std::to_string(w.count);
+    const std::string sum = std::to_string(w.sum_us);
+    const std::string json = stage::DumpJson();
+    EXPECT_TRUE(json.find("\"trpc.caller_wake\":{\"count\":" + n +
+                          ",\"sum_us\":" + sum + ",\"max_us\":" +
+                          std::to_string(w.max_us) + ",\"buckets\":[[") !=
+                std::string::npos)
+        << json.substr(0, 400);
+    EXPECT_TRUE(stage::DumpText().find("trpc.caller_wake") !=
+                std::string::npos);
+    std::string prom;
+    stage::DumpPrometheus(&prom);
+    EXPECT_TRUE(prom.find("# TYPE rpc_stage_us histogram\n") == 0) << prom;
+    EXPECT_TRUE(prom.find("rpc_stage_us_bucket{stage=\"trpc.caller_wake\","
+                          "le=\"+Inf\"} " + n + "\n") != std::string::npos);
+    // le="1023" holds exactly the samples under 1024: bucket indexes
+    // below 8 * 10.
+    uint64_t under_1024 = 0;
+    for (int i = 0; i < 8 * 10; ++i) under_1024 += w.hist.buckets[i];
+    EXPECT_GE(under_1024, 1023u);
+    EXPECT_TRUE(prom.find("rpc_stage_us_bucket{stage=\"trpc.caller_wake\","
+                          "le=\"1023\"} " + std::to_string(under_1024) +
+                          "\n") != std::string::npos);
+    EXPECT_TRUE(prom.find("rpc_stage_us_count{stage=\"trpc.caller_wake\"} " +
+                          n + "\n") != std::string::npos);
+    // /metrics carries it through the one render path.
+    EXPECT_TRUE(Variable::dump_prometheus().find(
+                    "rpc_stage_us_sum{stage=\"trpc.caller_wake\"} " + sum) !=
+                std::string::npos);
+}
+
+TEST(StageRecorder, SixteenWritersDoNotSerialise) {
+    const int id = stage::kTestOnly;
+    const uint64_t count_before = TestStage().count;
+    constexpr int kThreads = 16;
+    constexpr int kPerThread = 2000000;
+    std::atomic<bool> go{false};
+    std::vector<std::thread> threads;
+    std::vector<int64_t> ns((size_t)kThreads, 0);
+    for (int t = 0; t < kThreads; ++t) {
+        threads.emplace_back([&, t] {
+            stage::Add(id, 1);  // the cell exists before the clock starts
+            while (!go.load(std::memory_order_acquire)) {
+            }
+            // The thread's own CPU time: 16 writers on fewer cores spend
+            // wall time descheduled, which is not what an add costs.
+            const int64_t t0 = thread_cpu_ns();
+            for (int i = 0; i < kPerThread; ++i) stage::Add(id, i & 1023);
+            ns[(size_t)t] = thread_cpu_ns() - t0;
+        });
+    }
+    go.store(true, std::memory_order_release);
+    for (auto& th : threads) th.join();
+    int64_t worst = 0;
+    for (int64_t v : ns) worst = std::max(worst, v);
+    const double ns_per_add = (double)worst / kPerThread;
+    // Stamp + add, as a seam pays it, on one thread.
+    const int64_t t0 = monotonic_time_ns();
+    int64_t last = stage::now_us();
+    for (int i = 0; i < 1000000; ++i) {
+        const int64_t now = stage::now_us();
+        stage::Add(id, now - last);
+        last = now;
+    }
+    const double ns_per_seam = (double)(monotonic_time_ns() - t0) / 1e6;
+    printf("StageRecorder: %.1f ns of cpu per add at %d threads (slowest "
+           "thread, %d cores), %.1f ns per stamp + add\n",
+           ns_per_add, kThreads, (int)std::thread::hardware_concurrency(),
+           ns_per_seam);
+    EXPECT_EQ(TestStage().count - count_before,
+              (uint64_t)kThreads * (kPerThread + 1) + 1000000u);
+    // Per-thread cells: under the 30 ns a sample the issue allows shared
+    // atomics, with room for a loaded host; the printed number is the
+    // record.
+    EXPECT_LT(ns_per_add, 60.0);
 }

@@ -25,10 +25,13 @@ public:
         futex_wake_private(&pending_signal_, num_task);
     }
 
-    // Park until signalled (or 100ms safety timeout).
-    void wait(const State& expected) {
+    // Park until signalled (or 100ms safety timeout). True when it was the
+    // timeout that ended the park: the caller counts those, and whether a
+    // runnable fiber was waiting behind one (a wake-up that never came).
+    bool wait(const State& expected) {
         timespec ts{0, 100 * 1000 * 1000};
-        futex_wait_private(&pending_signal_, expected.val, &ts);
+        return futex_wait_private(&pending_signal_, expected.val, &ts) != 0 &&
+               errno == ETIMEDOUT;
     }
 
     void stop() {

@@ -19,6 +19,7 @@
 #include "tfiber/timer_thread.h"
 #include "tvar/multi_dimension.h"
 #include "tvar/reducer.h"
+#include "tvar/stage_recorder.h"
 
 // 0 = auto: hardware_concurrency + 1, min 4 (the reference defaults to
 // cores+1 via FLAGS_bthread_concurrency; a fixed count would cap
@@ -55,6 +56,13 @@ LabelledMetric<IntCell>* sched_rq_highwater() {
         "rpc_scheduler_runqueue_highwater", {"pool"});
     return m;
 }
+
+// Safety-net counters (plain cumulative /vars integers): how often the
+// worker park's 100 ms timeout, and no signal, ended a park -- and how
+// often the worker then found a runnable fiber, i.e. a wake-up was lost.
+LazyAdder g_park_timeouts("rpc_scheduler_park_timeouts");
+LazyAdder g_park_timeouts_found_work(
+    "rpc_scheduler_park_timeouts_found_work");
 }  // namespace
 
 TaskGroup* TaskGroup::tls_group() { return tls_task_group; }
@@ -147,6 +155,11 @@ void TaskGroup::run_main_task() {
 }
 
 TaskMeta* TaskGroup::wait_task() {
+    // The park's 100 ms timeout is a safety net: when it, and no signal,
+    // is what ended the park and a runnable fiber is then found, that
+    // fiber's wake-up was lost (or went to a worker that lost the race
+    // for it, a window of microseconds in 100 ms).
+    bool park_timed_out = false;
     while (true) {
         // Urgent handoff runs before any queue: run_urgent parked its
         // caller with `next_meta_` armed; the requeue hook has already
@@ -158,21 +171,29 @@ TaskMeta* TaskGroup::wait_task() {
         }
         if (control_->stopped()) return nullptr;
         TaskMeta* m = nullptr;
-        if (rq_.pop(&m)) return m;
-        if (control_->pop_remote(&m)) return m;
-        if (control_->steal_task(&m, &steal_seed_, index_)) return m;
+        if (rq_.pop(&m) || control_->pop_remote(&m) ||
+            control_->steal_task(&m, &steal_seed_, index_)) {
+            if (park_timed_out) *g_park_timeouts_found_work << 1;
+            return m;
+        }
         const ParkingLot::State st = control_->parking_lot().get_state();
         // Re-check after reading the state so a concurrent signal is never
         // missed (the futex value would have changed).
         if (rq_.pop(&m) || control_->pop_remote(&m) ||
             control_->steal_task(&m, &steal_seed_, index_)) {
+            if (park_timed_out) *g_park_timeouts_found_work << 1;
             return m;
         }
-        control_->parking_lot().wait(st);
+        park_timed_out = control_->parking_lot().wait(st);
+        if (park_timed_out) *g_park_timeouts << 1;
     }
 }
 
 void TaskGroup::sched_to(TaskMeta* next) {
+    if (next->ready_us != 0) {
+        stage::Add(stage::kWakeToRun, stage::now_us() - next->ready_us);
+        next->ready_us = 0;
+    }
     cur_meta_ = next;
     cur_ended_ = false;
     asan_before_jump(&worker_asan_fake_, next->stack.base,
@@ -423,6 +444,8 @@ void TaskControl::ensure_started() {
     remote_overflow_cell_ = sched_remote_overflows()->get_stats({pool});
     urgent_cell_ = sched_urgent()->get_stats({pool});
     rq_highwater_cell_ = sched_rq_highwater()->get_stats({pool});
+    *g_park_timeouts << 0;  // on /vars from the first scrape
+    *g_park_timeouts_found_work << 0;
     add_workers_locked(concurrency);
     started_.store(true, std::memory_order_release);
 }
@@ -474,6 +497,7 @@ void TaskControl::set_concurrency(int n) {
 }
 
 void TaskControl::ready_to_run(TaskMeta* m) {
+    m->ready_us = stage::now_us();
     TaskGroup* g = tls_task_group;
     // The local-queue shortcut is only valid on a worker of THIS pool: a
     // tagged fiber woken from another pool's worker (or a plain pthread)
@@ -616,6 +640,7 @@ static int start_fiber_impl(fiber_t* tid, const FiberAttr* attr,
     // Stale handle from the slot's previous tenant would hand ASan a freed
     // fake stack on this fiber's first switch-in.
     m->asan_fake = nullptr;
+    m->ready_us = 0;
     m->stack_type = attr ? attr->stack_type : STACK_TYPE_NORMAL;
     m->control = c;
     m->tid = ((fiber_t)m->version << 32) | (fiber_t)(slot + 1);

@@ -41,6 +41,12 @@ struct TaskMeta {
 
     bool about_to_quit = false;
 
+    // Stage clock (tvar/stage_recorder.h): when this fiber was last made
+    // runnable (TaskControl::ready_to_run); sched_to turns it into one
+    // tfiber.wake_to_run sample and clears it. 0 = no stamp (an urgent
+    // start runs at once and is not stamped).
+    int64_t ready_us = 0;
+
     // ASan fake-stack handle saved when this fiber switches out (fiber
     // annotations in task_group.cc; unused in non-ASan builds).
     void* asan_fake = nullptr;

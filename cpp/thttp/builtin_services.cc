@@ -39,6 +39,7 @@
 #include "trpc/server.h"
 #include "trpc/span.h"
 #include "tvar/series.h"
+#include "tvar/stage_recorder.h"
 #include "tvar/variable.h"
 
 DECLARE_bool(chaos_enabled);
@@ -445,7 +446,9 @@ void HandleStatus(Server* server, const HttpRequest& req,
                << ",\"p999\":" << st.latency.latency_percentile(0.999)
                << ",\"max\":" << st.latency.max_latency() << "}}";
         }
-        os << "}}";
+        // The stage clock's table (tvar/stage_recorder.h): cumulative
+        // per-stage histograms; two scrapes difference to a window.
+        os << "},\"stages\":" << stage::DumpJson() << "}";
         res->Append(os.str());
         return;
     }
@@ -477,6 +480,7 @@ void HandleStatus(Server* server, const HttpRequest& req,
                  (long long)st.latency.max_latency());
         res->Append(line);
     }
+    res->Append("\n" + stage::DumpText());
 }
 
 void HandleVars(Server*, const HttpRequest& req, HttpResponse* res) {

@@ -78,7 +78,8 @@ void IciEndpoint::ReleaseCompleted() {
     p->releasing.store(false, std::memory_order_release);
 }
 
-ssize_t IciEndpoint::CutFromIOBufList(IOBuf* const* pieces, size_t count) {
+ssize_t IciEndpoint::CutFromIOBufList(IOBuf* const* pieces, size_t count,
+                                      int64_t*) {
     if (out_->closed.load(std::memory_order_acquire) ||
         in_->closed.load(std::memory_order_acquire)) {
         errno = EPIPE;
@@ -155,7 +156,7 @@ int IciEndpoint::WaitWritable(int64_t abstime_us) {
     return Established() ? 0 : -1;
 }
 
-ssize_t IciEndpoint::Pump(IOPortal* dst) {
+ssize_t IciEndpoint::Pump(IOPortal* dst, PumpStamps*) {
     // Drain our doorbell so the edge re-arms at the eventfd level.
     uint64_t junk;
     while (read(evfd_, &junk, sizeof(junk)) > 0) {

@@ -25,6 +25,8 @@
 #include "tici/block_pool.h"
 #include "tnet/fault_injection.h"
 #include "tnet/input_messenger.h"
+#include "tvar/reducer.h"
+#include "tvar/stage_recorder.h"
 
 namespace tpurpc {
 
@@ -154,6 +156,15 @@ void ReleasePeerPool(const char* name) {
 
 // ---------------- endpoint ----------------
 
+namespace {
+// Safety-net pair of the writer's credit wait (WaitWritable): cumulative
+// /vars integers. The pump itself has no timed re-check: it runs on the
+// doorbell's epoll edge only.
+LazyAdder g_credit_wait_timeouts("rpc_link_credit_wait_timeouts");
+LazyAdder g_credit_wait_timeouts_found_work(
+    "rpc_link_credit_wait_timeouts_found_work");
+}  // namespace
+
 ShmIciEndpoint* ShmIciEndpoint::Create(int tcp_fd, void* ctrl_mapping,
                                        size_t ctrl_size, bool is_client,
                                        const char* peer_pool_name,
@@ -171,6 +182,8 @@ ShmIciEndpoint* ShmIciEndpoint::Create(int tcp_fd, void* ctrl_mapping,
     e->peer_base_ = peer_pool.base;
     e->peer_size_ = peer_pool.size;
     e->writable_butex_ = butex_create();
+    *g_credit_wait_timeouts << 0;  // on /vars from the first scrape
+    *g_credit_wait_timeouts_found_work << 0;
     return e;
 }
 
@@ -238,7 +251,8 @@ void ShmIciEndpoint::ReleaseCompleted() {
     releasing_.store(false, std::memory_order_release);
 }
 
-ssize_t ShmIciEndpoint::CutFromIOBufList(IOBuf* const* pieces, size_t count) {
+ssize_t ShmIciEndpoint::CutFromIOBufList(IOBuf* const* pieces, size_t count,
+                                         int64_t* posted_us) {
     if (!Established()) {
         errno = EPIPE;
         return -1;
@@ -371,6 +385,18 @@ ssize_t ShmIciEndpoint::CutFromIOBufList(IOBuf* const* pieces, size_t count) {
         errno = EAGAIN;  // window full: real back-pressure
         return -1;
     }
+    // Stage clock: one read for the whole post, taken as the descriptors
+    // are published (bounce copies above are the writer's own work, not
+    // the hand-off's). Its low 32 bits ride each descriptor's spare word;
+    // the peer's pump takes tici.link_handoff from them, the socket's
+    // writer takes tnet.write_queue from *posted_us.
+    const int64_t now_us = stage::now_us();
+    if (posted_us != nullptr) *posted_us = now_us;
+    const uint32_t post_stamp = stage::Low32(now_us);
+    for (uint64_t i = p->head.load(std::memory_order_relaxed); i < head;
+         ++i) {
+        p->ring[i % ShmPipe::kDepth].pad = post_stamp;
+    }
     p->head.store(head, std::memory_order_release);
     if (p->rx_armed.exchange(0, std::memory_order_acq_rel) != 0) {
         SendDoorbell();
@@ -395,14 +421,25 @@ int ShmIciEndpoint::WaitWritable(int64_t abstime_us) {
         p->tx_waiting.store(0, std::memory_order_release);
         return Established() ? 0 : -1;
     }
-    butex_wait(writable_butex_, expected, &abstime_us);
+    if (butex_wait(writable_butex_, expected, &abstime_us) == ETIMEDOUT) {
+        // Safety net: the caller's timed re-check, not the peer's
+        // doorbell, ended the credit wait; credits free by now mean the
+        // wake-up was lost.
+        *g_credit_wait_timeouts << 1;
+        ReleaseCompleted();
+        if (p->head.load(std::memory_order_relaxed) -
+                released_.load(std::memory_order_acquire) <
+            ShmPipe::kDepth) {
+            *g_credit_wait_timeouts_found_work << 1;
+        }
+    }
     p->tx_waiting.store(0, std::memory_order_release);
     // Timeout is not fatal (same contract as WaitEpollOut): the caller
     // re-checks and re-arms. Only a dead link is an error.
     return Established() ? 0 : -1;
 }
 
-ssize_t ShmIciEndpoint::Pump(IOPortal* dst) {
+ssize_t ShmIciEndpoint::Pump(IOPortal* dst, PumpStamps* stamps) {
     // 1. Drain doorbell bytes off the TCP connection; EOF/RST here is the
     //    failure detector (peer process died or closed).
     char tbuf[512];
@@ -460,8 +497,18 @@ ssize_t ShmIciEndpoint::Pump(IOPortal* dst) {
             errno = EAGAIN;
             return -1;
         }
+        // Stage clock: one read per batch of descriptors found posted;
+        // the messenger starts a message's clock at the batch that
+        // brought its first bytes.
+        const int64_t consume_us = stage::now_us();
+        if (stamps != nullptr) {
+            if (received == 0) stamps->first_us = consume_us;
+            stamps->last_us = consume_us;
+        }
         while (tail != head) {
             const ShmPipe::Desc d = p->ring[tail % ShmPipe::kDepth];
+            stage::Add(stage::kLinkHandoff,
+                       stage::Elapsed32(consume_us, d.pad));
             // Bounds-check against the mapped peer region: a corrupt or
             // hostile descriptor must not read out of the mapping.
             if (d.off > peer_size_ || d.len > peer_size_ - d.off) {

@@ -145,9 +145,10 @@ class ShmIciEndpoint : public TransportEndpoint {
 public:
     int event_fd() const override { return tcp_fd_; }
     bool Established() const override;
-    ssize_t CutFromIOBufList(IOBuf* const* pieces, size_t count) override;
+    ssize_t CutFromIOBufList(IOBuf* const* pieces, size_t count,
+                             int64_t* posted_us = nullptr) override;
     int WaitWritable(int64_t abstime_us) override;
-    ssize_t Pump(IOPortal* dst) override;
+    ssize_t Pump(IOPortal* dst, PumpStamps* stamps = nullptr) override;
     void Close() override;
     void Release() override;
     int tier() const override { return TierShmXproc(); }

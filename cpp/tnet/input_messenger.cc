@@ -14,6 +14,7 @@
 #include "tnet/fault_injection.h"
 #include "tnet/transport.h"
 #include "tvar/reducer.h"
+#include "tvar/stage_recorder.h"
 
 // Run-to-completion dispatch (ISSUE 7): up to this many small messages
 // per readiness burst process ON the input fiber (no spawn, no switch);
@@ -281,14 +282,24 @@ void InputMessenger::OnNewMessages(Socket* s) {
     // fiber, and sched_park flushes + detaches both scopes safely.
     WakeBatcher wake_batch;
     WriteCoalesceScope write_scope;
+    // Stage clock: when the latest successful read first / last found
+    // bytes. A message's clock starts at the read that brought its first
+    // bytes: `first_us` for bytes read into an empty buffer, `last_us`
+    // for what a cut leaves behind (the head of the next message came
+    // with the tail of this one).
+    PumpStamps read_stamps;
     while (!s->Failed()) {
         if (!read_eof) {
             // ICI transport sockets pump their completion queue (identical
             // nr semantics); fd sockets readv (reference
             // input_messenger.cpp:416 checks _rdma_state the same way).
             ssize_t nr;
+            // Bytes that begin a message (nothing of it was buffered)
+            // start its stage clock at this read's stamp.
+            const bool begins_message = s->read_buf.empty();
             if (s->transport() != nullptr) {
-                nr = s->transport()->Pump(&s->read_buf);
+                read_stamps = PumpStamps();
+                nr = s->transport()->Pump(&s->read_buf, &read_stamps);
             } else if (__builtin_expect(fault_injection_enabled(), 0)) {
                 nr = ChaosReadFromFd(s);
             } else {
@@ -296,6 +307,12 @@ void InputMessenger::OnNewMessages(Socket* s) {
                                                              kReadBurst);
             }
             if (nr > 0) {
+                if (s->transport() == nullptr || read_stamps.first_us == 0) {
+                    // An fd read, or an endpoint that stamps nothing.
+                    read_stamps.first_us = read_stamps.last_us =
+                        stage::now_us();
+                }
+                if (begins_message) s->consumed_us = read_stamps.first_us;
                 s->add_bytes_read(nr);
                 // Per-tier byte attribution (the Transport seam).
                 transport_stats::AddIn(s->transport_tier(), nr);
@@ -341,6 +358,8 @@ void InputMessenger::OnNewMessages(Socket* s) {
             ParseResult r = CutInputMessage(s, m->protocols_, read_eof);
             if (r.error == ParseError::OK) {
                 r.msg->socket_id = s->id();
+                r.msg->consumed_us = s->consumed_us;
+                s->consumed_us = read_stamps.last_us;
                 const Protocol* p = GetProtocol(r.msg->protocol_index);
                 if (p->process_in_order) {
                     // No correlation ids on this protocol: responses must

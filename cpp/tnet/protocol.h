@@ -47,6 +47,11 @@ public:
     // run-to-completion dispatcher uses it as the "small message" gate
     // (-inline_dispatch_max_bytes); 0 = unknown (never inlined).
     size_t byte_size = 0;
+    // Stage clock (tvar/stage_recorder.h): when the read that brought
+    // this message's first bytes returned (the link's consume stamp on a
+    // shm link), set by the messenger; the protocol's process() takes
+    // tnet.consume_to_cut from it.
+    int64_t consumed_us = 0;
 };
 
 struct Protocol {

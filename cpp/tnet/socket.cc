@@ -24,6 +24,7 @@
 #include "tnet/transport.h"
 #include "tvar/latency_recorder.h"
 #include "tvar/reducer.h"
+#include "tvar/stage_recorder.h"
 
 DEFINE_int64(socket_max_unwritten_bytes, 64 * 1024 * 1024,
              "write backlog limit before EOVERCROWDED back-pressure");
@@ -55,6 +56,11 @@ static LazyAdder g_hc_revives("rpc_health_check_revives");
 // biggest write backlog any connection reached. Per-connection views
 // live on /connections.
 static LazyAdder g_eovercrowded("rpc_socket_eovercrowded");
+// Safety-net pair of the KeepWrite fiber's EPOLLOUT wait (see
+// WaitEpollOut): cumulative /vars integers.
+static LazyAdder g_epollout_timeouts("rpc_socket_epollout_timeouts");
+static LazyAdder g_epollout_timeouts_found_work(
+    "rpc_socket_epollout_timeouts_found_work");
 
 static LatencyRecorder* write_batch_recorder() {
     static LatencyRecorder* r = [] {
@@ -128,6 +134,9 @@ int Socket::Create(const SocketOptions& options, SocketId* id) {
     s->read_buf.clear();
     s->preferred_protocol_index = -1;
     s->pending_frame_bytes = 0;
+    s->consumed_us = 0;
+    *g_epollout_timeouts << 0;  // on /vars from the first scrape
+    *g_epollout_timeouts_found_work << 0;
     s->health_check_interval_ms_ = options.health_check_interval_ms;
     s->tls_ = options.tls;
     s->tls_alpn_ = options.tls_alpn;
@@ -336,6 +345,7 @@ int Socket::ReviveAfterHealthCheck() {
     read_buf.clear();
     preferred_protocol_index = -1;
     pending_frame_bytes = 0;
+    consumed_us = 0;
     error_code_.store(0, std::memory_order_relaxed);
     connecting_.store(false, std::memory_order_relaxed);
     local_side_ = EndPoint();
@@ -452,7 +462,7 @@ int Socket::SetFailedWithError(int error_code) {
 
 // ---------------- write path ----------------
 
-int Socket::Write(IOBuf* data, uint64_t notify_id) {
+int Socket::Write(IOBuf* data, uint64_t notify_id, int64_t enqueued_us) {
     if (Failed()) {
         errno = TERR_FAILED_SOCKET;
         return -1;
@@ -467,6 +477,7 @@ int Socket::Write(IOBuf* data, uint64_t notify_id) {
     }
     WriteRequest* req = new WriteRequest;
     req->notify_id = notify_id;
+    req->enqueued_us = enqueued_us;
     req->data.swap(*data);
     req->next.store(WriteRequest::unlinked(), std::memory_order_relaxed);
     const int64_t queued =
@@ -808,9 +819,13 @@ bool Socket::FlushOnce(bool allow_block) {
         // Data plane: ICI queue pair when plugged (the RdmaEndpoint
         // bypass — reference socket.cpp checks _rdma_state on the write
         // path), else the fd.
+        // Stage clock: the stamp the link took as it posted, if it
+        // takes one.
+        int64_t posted_us = 0;
         if (!fault_io) {
             nw = transport_ != nullptr
-                     ? transport_->CutFromIOBufList(pieces, npieces)
+                     ? transport_->CutFromIOBufList(pieces, npieces,
+                                                    &posted_us)
                      : IOBuf::cut_multiple_into_file_descriptor(fd(), pieces,
                                                                 npieces);
         }
@@ -850,9 +865,17 @@ bool Socket::FlushOnce(bool allow_block) {
             }
             *write_batch_recorder() << nw;
         }
-        // Drop fully-written requests.
+        // Drop fully-written requests; a stamped one ends its
+        // tnet.write_queue here, on the link's post stamp, else on one
+        // clock read for the whole round.
         while (inflight_index_ < inflight_batch_.size() &&
                inflight_batch_[inflight_index_]->data.empty()) {
+            const int64_t enqueued_us =
+                inflight_batch_[inflight_index_]->enqueued_us;
+            if (enqueued_us != 0) {
+                if (posted_us == 0) posted_us = stage::now_us();
+                stage::Add(stage::kWriteQueue, posted_us - enqueued_us);
+            }
             delete inflight_batch_[inflight_index_];
             ++inflight_index_;
             ++consumed;
@@ -888,7 +911,15 @@ int Socket::WaitEpollOut() {
     EventDispatcher& d = EventDispatcher::GetGlobalDispatcher(the_fd);
     if (d.RegisterEpollOut(id(), the_fd, true) != 0) return -1;
     const int64_t abstime = monotonic_time_us() + 2 * 1000 * 1000;
-    butex_wait(epollout_butex_, expected, &abstime);
+    if (butex_wait(epollout_butex_, expected, &abstime) == ETIMEDOUT) {
+        // Safety net: the 2 s re-check, not an EPOLLOUT event, ended the
+        // wait; an fd writable by now means the event was lost.
+        *g_epollout_timeouts << 1;
+        pollfd pfd{the_fd, POLLOUT, 0};
+        if (poll(&pfd, 1, 0) > 0 && (pfd.revents & POLLOUT) != 0) {
+            *g_epollout_timeouts_found_work << 1;
+        }
+    }
     d.UnregisterEpollOut(id(), the_fd, true);
     return Failed() ? -1 : 0;
 }

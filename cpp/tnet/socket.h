@@ -103,7 +103,10 @@ public:
     // is error-notified if the request is dropped by a write failure —
     // how in-flight RPCs learn their connection died (the reference passes
     // Controller ids through WriteRequest, socket.cpp Write w/ id_wait).
-    int Write(IOBuf* data, uint64_t notify_id = 0);
+    // `enqueued_us`: the caller's stage-clock read as it enqueues the
+    // frame (tvar/stage_recorder.h); the writer adds tnet.write_queue as
+    // it posts the frame's last byte. 0 = not sampled.
+    int Write(IOBuf* data, uint64_t notify_id = 0, int64_t enqueued_us = 0);
 
     // ---- read path (called by EventDispatcher) ----
     static void OnInputEventById(SocketId id);
@@ -187,6 +190,9 @@ public:
     // the messenger skips parse entirely until the whole frame arrived —
     // no re-peek, no re-parse per partial read. Input-fiber-only.
     int64_t pending_frame_bytes = 0;
+    // Stage clock: when the read that began the bytes now at the front
+    // of read_buf returned (input-fiber-owned, like read_buf).
+    int64_t consumed_us = 0;
     // Protocol-private per-connection state (e.g. the HTTP/2 session:
     // HPACK context + stream table). Owned by the socket once set; the
     // deleter runs at recycle. Set from the input fiber only.
@@ -408,6 +414,7 @@ private:
         std::atomic<WriteRequest*> next{nullptr};
         IOBuf data;
         uint64_t notify_id = 0;
+        int64_t enqueued_us = 0;  // stage clock, see Write
         static WriteRequest* unlinked() { return (WriteRequest*)0x1; }
     };
 

@@ -197,7 +197,8 @@ public:
                             : std::string();
     }
 
-    ssize_t CutFromIOBufList(IOBuf* const* pieces, size_t count) override {
+    ssize_t CutFromIOBufList(IOBuf* const* pieces, size_t count,
+                             int64_t*) override {
         // Chaos: faults on the PLAINTEXT side of the record layer, so a
         // corrupt byte arrives MAC-valid and only the application-level
         // crc32c can catch it (exactly the property under test).
@@ -296,7 +297,7 @@ public:
         return ::poll(&p, 1, timeout_ms) >= 0 ? 0 : -1;
     }
 
-    ssize_t Pump(IOPortal* dst) override {
+    ssize_t Pump(IOPortal* dst, PumpStamps*) override {
         // Chaos: inbound faults on the decrypted plaintext. Decided (and
         // slept) BEFORE ssl_mu_ — see CutFromIOBufList.
         FaultAction fault;

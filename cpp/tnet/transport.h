@@ -169,6 +169,15 @@ void ExposeVars();
 
 // ---- the per-socket data-plane endpoint ----
 
+// Stage clock (tvar/stage_recorder.h): when one Pump call first and last
+// found bytes posted. A long pump of pipelined messages brings the head
+// of the next message with its last batch, the first bytes into an empty
+// buffer with its first.
+struct PumpStamps {
+    int64_t first_us = 0;
+    int64_t last_us = 0;
+};
+
 class TransportEndpoint {
 public:
     virtual ~TransportEndpoint() = default;
@@ -185,7 +194,12 @@ public:
     // block references are held by the queue until the remote side
     // completes them. Returns bytes posted (pieces are pop_front'd);
     // -1/EAGAIN when out of window credits; -1/other errno on failure.
-    virtual ssize_t CutFromIOBufList(IOBuf* const* pieces, size_t count) = 0;
+    // Stage clock (tvar/stage_recorder.h): an endpoint that stamps its
+    // posts sets *posted_us to the stamp it took as it published them, so
+    // the socket's writer reuses that clock read; one that does not
+    // leaves it alone (the caller passes 0 and reads the clock itself).
+    virtual ssize_t CutFromIOBufList(IOBuf* const* pieces, size_t count,
+                                     int64_t* posted_us = nullptr) = 0;
 
     // Block the calling fiber until credits may be available (woken by the
     // pump when the peer consumes). Returns 0, or -1 on timeout/failure.
@@ -194,8 +208,8 @@ public:
     // Drain the completion queue: move received bytes into *dst, release
     // send-side refs completed by the peer, wake writable waiters.
     // fd-read semantics: >0 bytes appended; 0 = peer closed (EOF);
-    // -1/EAGAIN = nothing pending.
-    virtual ssize_t Pump(IOPortal* dst) = 0;
+    // -1/EAGAIN = nothing pending. *stamps: as *posted_us above.
+    virtual ssize_t Pump(IOPortal* dst, PumpStamps* stamps = nullptr) = 0;
 
     // Half-close: peer's next drained Pump returns EOF. Idempotent.
     virtual void Close() = 0;

@@ -12,6 +12,7 @@
 #include "tnet/transport.h"
 #include "trpc/pb_compat.h"
 #include "trpc/policy_tpu_std.h"
+#include "tvar/stage_recorder.h"
 
 namespace {
 constexpr char kMagic[4] = {'T', 'R', 'P', 'C'};
@@ -181,6 +182,18 @@ uint32_t tpurpc_ring_depth(void* ring) {
 
 int tpurpc_ring_registered(void* ring) {
     return ((tpurpc::DeviceStagingRing*)ring)->registered() ? 1 : 0;
+}
+
+// The stage clock's table as the JSON object /status?format=json embeds
+// under "stages" (tvar/stage_recorder.h), for a process with no portal.
+// Returns the object's length; it is copied (NUL-terminated) only when it
+// fits in `cap`, so a caller that got >= cap asks again with more room.
+long tpurpc_stage_dump(char* out, size_t cap) {
+    const std::string json = tpurpc::stage::DumpJson();
+    if (out != nullptr && json.size() < cap) {
+        memcpy(out, json.c_str(), json.size() + 1);
+    }
+    return (long)json.size();
 }
 
 uint64_t tpurpc_ring_inflight_highwater(void* ring) {

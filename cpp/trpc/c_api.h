@@ -61,6 +61,10 @@ uint32_t tpurpc_ring_depth(void* ring);
 int tpurpc_ring_registered(void* ring);
 uint64_t tpurpc_ring_inflight_highwater(void* ring);
 
+// The stage clock's table (tvar/stage_recorder.h) as JSON; returns its
+// length, copied into `out` only when it fits in `cap` with its NUL.
+long tpurpc_stage_dump(char* out, size_t cap);
+
 // ---- block leases (ISSUE 10a) ----
 // Crash-safety counters of the pinned-block lease registry
 // (tici/block_lease.h): live pins, expiry-reaped pins, and the local

@@ -20,6 +20,7 @@
 #include "trpc/server_call.h"
 #include "trpc/span.h"
 #include "trpc/stream.h"
+#include "tvar/stage_recorder.h"
 
 #include "tbase/flags.h"
 
@@ -204,7 +205,7 @@ void Channel::CallMethod(const google::protobuf::MethodDescriptor* method,
     cntl->method_ = method;
     cntl->response_ = response;
     cntl->done_ = done;
-    cntl->start_us_ = monotonic_time_us();
+    cntl->start_us_ = stage::now_us();  // trpc.issue starts here
 
     if (id_create(&cntl->correlation_id_, cntl,
                   &Controller::HandleErrorThunk) != 0) {
@@ -358,6 +359,11 @@ void Channel::CallMethod(const google::protobuf::MethodDescriptor* method,
         // Synchronous call: wait for destroy (works from fibers and plain
         // pthreads alike — butex handles both waiter kinds).
         id_join(cid);
+        // trpc.caller_wake: EndRPC's clock read (where trpc.match ends)
+        // -> this caller running again. A synchronous caller owns `cntl`
+        // until it returns, and every end passes through EndRPC.
+        stage::Add(stage::kCallerWake,
+                   stage::now_us() - (cntl->start_us_ + cntl->latency_us_));
     }
 }
 

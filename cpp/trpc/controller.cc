@@ -30,6 +30,7 @@
 #include "trpc/compress.h"
 #include "trpc/span.h"
 #include "trpc/stream.h"
+#include "tvar/stage_recorder.h"
 
 DEFINE_bool(rpc_checksum, false,
             "crc32c-protect tpu_std frame bodies (verified when present)");
@@ -239,6 +240,7 @@ void Controller::Reset() {
     single_server_id_ = INVALID_VREF_ID;
     current_server_id_ = INVALID_VREF_ID;
     try_start_us_ = 0;
+    reply_parsed_us_ = 0;
     request_code_ = 0;
     has_request_code_ = false;
     request_compress_type_ = 0;
@@ -658,11 +660,12 @@ void Controller::HandleBackoffThunk(void* arg) {
     id_unlock(cid);
 }
 
-void Controller::FeedbackToLB(int error) {
+void Controller::FeedbackToLB(int error, int64_t now_us) {
     if (channel_ == nullptr || current_server_id_ == INVALID_VREF_ID) return;
     LoadBalancerWithNaming* lb = channel_->lb();
-    const int64_t try_latency_us = monotonic_time_us() - try_start_us_;
     if (lb != nullptr) {
+        const int64_t try_latency_us =
+            (now_us != 0 ? now_us : stage::now_us()) - try_start_us_;
         LoadBalancer::CallInfo info;
         info.server_id = current_server_id_;
         // Per-try latency: charging earlier failed tries' time to the
@@ -688,7 +691,9 @@ void Controller::FeedbackToLB(int error) {
 }
 
 void Controller::IssueRPC() {
-    try_start_us_ = monotonic_time_us();
+    // Stage clock: the first try starts where CallMethod read the clock;
+    // a re-issue (retry, backup) reads it anew.
+    try_start_us_ = current_try_ == 0 ? start_us_ : stage::now_us();
     SocketUniquePtr s;
     if (channel_->lb() != nullptr) {
         // LB mode: pick a live server, excluding ones tried by earlier
@@ -1023,11 +1028,16 @@ void Controller::IssueRPC() {
     SerializePbToIOBuf(meta, &meta_buf);
     IOBuf frame;
     PackTpuStdFrame(&frame, meta_buf, request_buf_, *wire_att);
+    // The request is enqueued: one clock read ends trpc.issue, starts
+    // tnet.write_queue (the socket's writer ends it at the post) and is
+    // the rpcz sent phase.
+    const int64_t enqueued_us = stage::now_us();
+    stage::Add(stage::kIssue, enqueued_us - try_start_us_);
     if (span_ != nullptr) {
         span_->request_bytes = (int64_t)frame.size();
-        span_->sent_us = monotonic_time_us();
+        span_->sent_us = enqueued_us;
     }
-    if (s->Write(&frame, current_cid_) != 0) {
+    if (s->Write(&frame, current_cid_, enqueued_us) != 0) {
         // Queue full or failed socket: deliver the error (may retry).
         id_error(current_cid_, errno != 0 ? errno : TERR_FAILED_SOCKET);
     }
@@ -1130,7 +1140,16 @@ void Controller::ReleaseFlySockets() {
 }
 
 void Controller::EndRPC(CallId locked_id) {
-    latency_us_ = monotonic_time_us() - start_us_;
+    // One clock read for the completion seam: the call's latency, the
+    // rpcz end phase, the LB's per-try latency and trpc.match (reply
+    // parsed -> here; a synchronous caller is signalled at the end of
+    // this function, and CallMethod takes trpc.caller_wake from here).
+    const int64_t end_us = stage::now_us();
+    latency_us_ = end_us - start_us_;
+    if (reply_parsed_us_ != 0) {
+        stage::Add(stage::kMatch, end_us - reply_parsed_us_);
+        reply_parsed_us_ = 0;
+    }
     // One-sided completion (ISSUE 9/10): the response (or terminal
     // failure) means the peer will never again read our posted
     // descriptor — release the lease, returning the pinned block to the
@@ -1187,12 +1206,12 @@ void Controller::EndRPC(CallId locked_id) {
                 span_->Annotate("note: local chaos injection is enabled");
             }
         }
-        span_->end_us = monotonic_time_us();
+        span_->end_us = end_us;
         span_->error_code = error_code_;
         Collector::singleton()->submit(span_);
         span_ = nullptr;
     }
-    FeedbackToLB(error_code_);
+    FeedbackToLB(error_code_, end_us);
     // A client stream that never got bound to a connection must be failed
     // here — EndRPC is the single funnel every termination path (success
     // without stream settings, server error, timeout, socket failure)
@@ -1231,6 +1250,13 @@ void Controller::EndRPC(CallId locked_id) {
 
 void ProcessTpuStdResponse(TpuStdMessage* msg, const rpc::RpcMeta& meta) {
     const CallId cid = meta.correlation_id();
+    // The reply is cut and its meta parsed: one clock read ends
+    // tnet.consume_to_cut, starts trpc.match and is the rpcz received
+    // phase.
+    const int64_t parsed_us = stage::now_us();
+    if (msg->consumed_us != 0) {
+        stage::Add(stage::kConsumeToCut, parsed_us - msg->consumed_us);
+    }
     // A dropped response that carried a pool descriptor still acks: the
     // server pinned a block for us, and nobody will ever resolve this
     // copy of the reference — without the ack the pin would sit until
@@ -1273,8 +1299,9 @@ void ProcessTpuStdResponse(TpuStdMessage* msg, const rpc::RpcMeta& meta) {
         // call falls back to the still-live original).
         cntl->backup_won_ = true;
     }
+    cntl->reply_parsed_us_ = parsed_us;
     if (cntl->span_ != nullptr) {
-        cntl->span_->received_us = monotonic_time_us();
+        cntl->span_->received_us = parsed_us;
         cntl->span_->response_bytes = (int64_t)msg->body.size();
     }
     // Pooled/short: the connection that delivered THIS response is clean

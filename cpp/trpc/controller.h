@@ -383,7 +383,8 @@ private:
     static void HandleBackoffThunk(void* arg);  // arg = retry's CallId
     // Report the finished try to the LB (latency + error feed the
     // locality-aware policy; reference Call::OnComplete controller.cpp:780).
-    void FeedbackToLB(int error);
+    // `now_us`: the caller's clock read at this seam; 0 = read it here.
+    void FeedbackToLB(int error, int64_t now_us = 0);
     // Pool-return / close this RPC's pooled/short connections (EndRPC).
     void ReleaseFlySockets();
     // Exactly-once release of the pinned pool-attachment lease (see
@@ -469,6 +470,7 @@ private:
     bool backup_issued_;  // a backup try actually went out
     bool backup_won_;     // the backup try's response completed the RPC
     int64_t try_start_us_;        // start of the current try (LB feedback)
+    int64_t reply_parsed_us_;     // stage clock: the winning reply parsed
     uint64_t request_code_;
     bool has_request_code_;
     int request_compress_type_;

@@ -36,6 +36,7 @@
 #include "trpc/span.h"
 #include "trpc/stream.h"
 #include "tvar/reducer.h"
+#include "tvar/stage_recorder.h"
 
 DECLARE_bool(rpc_checksum);
 
@@ -416,11 +417,40 @@ public:
     void set_qos_counted() { qos_counted_ = true; }
     uint64_t wire_cid() const { return cid_; }
 
+    // Stage clock (tvar/stage_recorder.h). The call carries its last
+    // stamp here; each seam reads the clock once, adds the stage that
+    // ends there and moves the stamp on, so the five stages between the
+    // request's first consumed byte and its reply's post (the socket's
+    // writer adds the last, tnet.write_queue) telescope: their sum is
+    // the call's residence in this process. `consumed_us` is the
+    // messenger's stamp of the read that began the request, `parsed_us`
+    // the clock read at the top of ProcessTpuStdRequest.
+    void StartStages(int64_t consumed_us, int64_t parsed_us) {
+        stage::Add(stage::kConsumeToCut,
+                   consumed_us != 0 ? parsed_us - consumed_us : 0);
+        stage_last_us_ = parsed_us;
+    }
+    // The handler is entered at `now_us` (inline round or its own fiber).
+    void EnterHandler(int64_t now_us) {
+        stage::Add(stage::kDispatchToHandler, now_us - stage_last_us_);
+        stage_last_us_ = now_us;
+        handler_entered_ = true;
+        if (cntl_->span_ != nullptr) {
+            cntl_->span_->process_start_us = now_us;
+        }
+    }
+
     void Run() override {
         flight::Record(flight::kRpcHandlerOut, cid_,
                        (uint64_t)cntl_->ErrorCode());
+        const int64_t done_us = stage::now_us();
+        // A call answered without its handler (shed, parse failure)
+        // still passes every seam, with an empty handler stage.
+        if (!handler_entered_) EnterHandler(done_us);
+        stage::Add(stage::kHandler, done_us - stage_last_us_);
+        stage_last_us_ = done_us;
         if (cntl_->span_ != nullptr) {
-            cntl_->span_->process_end_us = monotonic_time_us();
+            cntl_->span_->process_end_us = done_us;
             // Annotated HERE, not in the cancel delivery path: the span is
             // owned by this strictly-sequential pipeline, and the cancel
             // thunk may race with span submission below.
@@ -536,8 +566,10 @@ public:
         IOBuf frame;
         PackTpuStdFrame(&frame, meta_buf, payload, att);
         int wrc = -1;
+        const int64_t enqueued_us = stage::now_us();
+        stage::Add(stage::kRespond, enqueued_us - stage_last_us_);
         if (have_sock) {
-            wrc = s->Write(&frame);
+            wrc = s->Write(&frame, 0, enqueued_us);
         }
         flight::Record(flight::kRpcWrite, cid_, payload.size());
         // Push-stream bind point (ISSUE 17): the accept echo is on the
@@ -558,7 +590,7 @@ public:
         }
         if (cntl_->span_ != nullptr) {
             cntl_->span_->response_bytes = (int64_t)payload.size();
-            cntl_->span_->end_us = monotonic_time_us();
+            cntl_->span_->end_us = enqueued_us;
             Collector::singleton()->submit(cntl_->span_);
             cntl_->span_ = nullptr;
         }
@@ -578,8 +610,7 @@ public:
             ci.method = &qos_method_;
             ci.logical_bytes = qos_bytes_;
             ci.peer = qos_peer_;
-            qos_->OnDone(qos_tenant_,
-                         monotonic_time_us() - qos_start_us_, ci);
+            qos_->OnDone(qos_tenant_, enqueued_us - qos_start_us_, ci);
         }
         // Stats + limiter + Join wakeup; Finish is the LAST touch of
         // Server memory (the Server may be destroyed right after).
@@ -603,6 +634,8 @@ private:
     QosDispatcher::TenantState* qos_tenant_ = nullptr;
     int64_t qos_start_us_ = 0;
     bool qos_counted_ = false;
+    int64_t stage_last_us_ = 0;    // the seam this call passed last
+    bool handler_entered_ = false;
     std::string qos_method_;   // cost-model key ("Service.Method")
     int64_t qos_bytes_ = 0;    // inline + descriptor-exempt payload
     EndPoint qos_peer_;        // chaos cost_inflate scoping
@@ -634,9 +667,10 @@ std::atomic<int64_t> g_usercode_default_inflight{0};
 // request waits for a handler fiber (queueing under overload is exactly
 // when budgets die). True = the caller must run `done` WITHOUT invoking
 // the service method.
-bool ShedIfExpired(Server::MethodProperty* mp, Controller* cntl) {
+bool ShedIfExpired(Server::MethodProperty* mp, Controller* cntl,
+                   int64_t now_us) {
     if (!cntl->has_server_deadline() ||
-        monotonic_time_us() < cntl->server_deadline_us()) {
+        now_us < cntl->server_deadline_us()) {
         return false;
     }
     mp->status->nexpired.fetch_add(1, std::memory_order_relaxed);
@@ -657,7 +691,14 @@ void CallUserMethod(Server::MethodProperty* mp, Controller* cntl,
                     google::protobuf::Message* req,
                     google::protobuf::Message* res,
                     google::protobuf::Closure* done) {
-    if (ShedIfExpired(mp, cntl)) {
+    // Within this protocol `done` is always the SendResponseClosure built
+    // in ProcessTpuStdRequest -- the holder of the wire cid and of the
+    // call's stage clock. One clock read for the handler-entry seam: the
+    // stage, the rpcz phase and the deadline check share it.
+    auto* closure = static_cast<SendResponseClosure*>(done);
+    const int64_t entered_us = stage::now_us();
+    closure->EnterHandler(entered_us);
+    if (ShedIfExpired(mp, cntl, entered_us)) {
         done->Run();
         return;
     }
@@ -684,10 +725,7 @@ void CallUserMethod(Server::MethodProperty* mp, Controller* cntl,
             fiber_usleep(fa.delay_us);
         }
     }
-    // Within this protocol `done` is always the SendResponseClosure built
-    // in ProcessTpuStdRequest — the only holder of the wire cid here.
-    const uint64_t wire_cid =
-        static_cast<SendResponseClosure*>(done)->wire_cid();
+    const uint64_t wire_cid = closure->wire_cid();
     flight::Record(flight::kRpcHandlerIn, wire_cid,
                    cntl->span_ != nullptr ? cntl->span_->trace_id : 0);
     ServerCallScope scope(cntl);
@@ -698,9 +736,6 @@ void CallUserMethod(Server::MethodProperty* mp, Controller* cntl,
 
 void* RunUserCall(void* arg) {
     auto* a = (UserCallArgs*)arg;
-    if (a->cntl->span_ != nullptr) {
-        a->cntl->span_->process_start_us = monotonic_time_us();
-    }
     const bool counted = a->counted_default;
     CallUserMethod(a->mp, a->cntl, a->req, a->res, a->done);
     delete a;
@@ -814,6 +849,10 @@ void ShedQueuedCall(void* arg, int64_t backoff_ms) {
 void ProcessTpuStdRequest(TpuStdMessage* msg, const rpc::RpcMeta& meta) {
     const SocketId sid = msg->socket_id;
     const uint64_t cid = meta.correlation_id();
+    // The message is cut and its meta parsed: the one clock read of this
+    // seam is the request's arrival for its deadline, the QoS bucket and
+    // the rpcz span, and ends tnet.consume_to_cut (StartStages below).
+    const int64_t arrival_us = stage::now_us();
     flight::Record(flight::kRpcDispatch, cid, msg->body.size());
     // rpc_dump: capture the raw meta+body of sampled requests (reference
     // rpc_dump.cpp via the bvar Collector; appending IOBufs only bumps
@@ -857,7 +896,6 @@ void ProcessTpuStdRequest(TpuStdMessage* msg, const rpc::RpcMeta& meta) {
     // caller that has already given up stamps <= 0). Shed expired
     // requests here — before admission, before parse, before a handler
     // fiber — executing them is pure waste the client will never read.
-    const int64_t arrival_us = monotonic_time_us();
     int64_t deadline_us = 0;
     if (req_meta.has_timeout_ms()) {
         if (req_meta.timeout_ms() <= 0) {
@@ -1075,7 +1113,6 @@ void ProcessTpuStdRequest(TpuStdMessage* msg, const rpc::RpcMeta& meta) {
                                    (int64_t)pd.length());
     }
 
-    const int64_t start_us = monotonic_time_us();
     auto* req = mp->service->GetRequestPrototype(mp->method).New();
     auto* res = mp->service->GetResponsePrototype(mp->method).New();
     auto* cntl = new Controller;
@@ -1124,7 +1161,7 @@ void ProcessTpuStdRequest(TpuStdMessage* msg, const rpc::RpcMeta& meta) {
         span->method =
             req_meta.service_name() + "." + req_meta.method_name();
         span->remote_side = s->remote_side();
-        span->start_us = start_us;
+        span->start_us = arrival_us;
         span->request_bytes = (int64_t)payload_size + att_size;
         cntl->span_ = span;
     }
@@ -1157,6 +1194,7 @@ void ProcessTpuStdRequest(TpuStdMessage* msg, const rpc::RpcMeta& meta) {
     }
     auto* done = new SendResponseClosure(server, guard, cntl, req, res, sid,
                                          cid);
+    done->StartStages(msg->consumed_us, arrival_us);
     if (qos_on) {
         // Logical payload = inline body + attachment + the descriptor-
         // exempt referenced bytes (they never rode the message path but

@@ -1,0 +1,106 @@
+"""Spans inside the staging-ring pass (ISSUE 25): a small pass on the CPU
+backend leaves, per chunk, one `ring.launch` and one `ring.retire` with the
+children the issue lists; their self times add up to the passes' wall time;
+the ring of records is bounded; a window cut out of it clips."""
+import time
+
+import numpy as np
+import pytest
+
+from brpc_tpu import spans
+
+LAUNCH_CHILDREN = {"ring.acquire", "ring.stage", "ring.frame", "ring.h2d",
+                   "ring.kernel_dispatch"}
+RETIRE_CHILDREN = {"ring.d2h_wait", "ring.verify", "ring.complete"}
+
+
+@pytest.fixture
+def pipeline(cpp_build):
+    import jax
+
+    from brpc_tpu import device_path, native
+
+    dev = jax.devices("cpu")[0]
+    chunk_bytes, n = 64 << 10, 6
+    words = np.arange(n * chunk_bytes // 4, dtype=np.uint32)
+    per = chunk_bytes // 4
+    chunks = [words[i * per:(i + 1) * per] for i in range(n)]
+    touch = device_path._touch_kernel(per, dev.platform)
+    ring = native.DeviceStagingRing(3, chunk_bytes + 1024)
+    pipe = device_path._ChunkPipeline(ring, chunks, dev, touch, 3, False)
+    pipe.run(1)  # compile, first transfers
+    spans.clear()
+    yield pipe
+    ring.close()
+
+
+def test_every_chunk_has_its_launch_and_retire_with_their_children(pipeline):
+    n = len(pipeline.chunks)
+    pipeline.run(2)
+    assert pipeline.ok
+    by_request = {}
+    for name, start, end, request, _ in spans.snapshot():
+        assert end >= start
+        by_request.setdefault(request, []).append((name, start, end))
+    chunk_requests = [r for r in by_request if r[1] is not None]
+    assert len(chunk_requests) == 2 * n  # two passes, every chunk
+    for request in chunk_requests:
+        got = by_request[request]
+        names = [name for name, _, _ in got]
+        assert names.count("ring.launch") == 1, (request, names)
+        assert names.count("ring.retire") == 1, (request, names)
+        # The children lie inside their parent's interval (nesting is in
+        # the times), and the launch inside its pass's ring.pass.
+        for parent, children in (("ring.launch", LAUNCH_CHILDREN),
+                                 ("ring.retire", RETIRE_CHILDREN)):
+            (p0, p1), = [(s, e) for nm, s, e in got if nm == parent]
+            assert {nm for nm, s, e in got
+                    if nm != parent and p0 <= s and e <= p1} == children
+        (l0, l1), = [(s, e) for nm, s, e in got if nm == "ring.launch"]
+        assert any(s <= l0 and l1 <= e
+                   for _, s, e in by_request[(request[0], None)])
+    # Two passes and the drain, each under a ring.pass of that pass.
+    passes = [r for r in by_request if r[1] is None]
+    assert {p[0] for p in passes} == {c[0] for c in chunk_requests}
+
+
+def test_self_times_and_remainder_add_up_to_the_wall_time(pipeline):
+    t0 = time.monotonic()
+    for _ in range(5):
+        pipeline.run(1)
+    t1 = time.monotonic()
+    records = spans.snapshot(t0, t1)
+    own = spans.self_times(records)
+    assert set(own) == (LAUNCH_CHILDREN | RETIRE_CHILDREN
+                        | {"ring.launch", "ring.retire", "ring.pass"})
+    assert all(v >= 0 for v in own.values()), own
+    # The listed stages plus the remainder (the loop's own: ring.pass,
+    # ring.launch, ring.retire self times) are the time inside the passes,
+    # which is all of [t0, t1] but the five calls' own overhead.
+    assert sum(own.values()) == pytest.approx(t1 - t0, rel=0.02)
+    # Self time never counts a moment twice: the top-level spans alone
+    # cover the same time.
+    top = sum(end - start for name, start, end, *_ in records
+              if name == "ring.pass")
+    assert sum(own.values()) == pytest.approx(top, rel=1e-9)
+
+
+def test_snapshot_clips_to_the_window_and_the_ring_is_bounded():
+    spans.clear()
+    with spans.span("outer", request=(1, None)):
+        t_in = time.monotonic()
+        with spans.span("inner", request=(1, 0)):
+            time.sleep(0.002)
+    t_out = time.monotonic()
+    cut = spans.snapshot(t_in, t_out)
+    assert {r[0] for r in cut} == {"outer", "inner"}
+    assert all(t_in <= r[1] <= r[2] <= t_out for r in cut)
+    assert spans.snapshot(t_out + 1.0, t_out + 2.0) == []
+    own = spans.self_times(spans.snapshot())
+    assert own["inner"] >= 0.002 and own["outer"] >= 0
+    for i in range(spans.CAPACITY + 100):
+        with spans.span("flood", request=i):
+            pass
+    assert spans.CAPACITY >= 1 << 16
+    assert len(spans.snapshot()) == spans.CAPACITY
+    spans.clear()

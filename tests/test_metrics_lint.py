@@ -59,9 +59,11 @@ def _lint_exposition(text):
         # TYPE must precede the sample, resolving summary suffixes.
         family = name
         if family not in families:
-            for suffix in ("_sum", "_count"):
+            for suffix, types in (("_sum", ("summary", "histogram")),
+                                  ("_count", ("summary", "histogram")),
+                                  ("_bucket", ("histogram",))):
                 stem = name[: -len(suffix)] if name.endswith(suffix) else None
-                if stem and families.get(stem) == "summary":
+                if stem and families.get(stem) in types:
                     family = stem
                     break
         if family not in families:
@@ -96,6 +98,44 @@ def _lint_exposition(text):
             if any(b < a for a, b in zip(vals, vals[1:])):
                 errors.append("summary %r quantiles not monotone: %r"
                               % (fam, qs))
+    # Histogram shape, per label set: cumulative buckets ending in +Inf,
+    # which equals _count; _sum present.
+    for fam, mtype in families.items():
+        if mtype != "histogram":
+            continue
+        groups = {}
+        for name, labels, value, i in samples:
+            key = tuple(sorted(
+                (k, v) for k, v in labels.items() if k != "le"))
+            g = groups.setdefault(key, {"buckets": [], "sum": None,
+                                        "count": None})
+            if name == fam + "_bucket" and "le" in labels:
+                g["buckets"].append((float(labels["le"].replace(
+                    "+Inf", "inf")), float(value)))
+            elif name == fam + "_sum":
+                g["sum"] = float(value)
+            elif name == fam + "_count":
+                g["count"] = float(value)
+        groups = {k: g for k, g in groups.items()
+                  if g["buckets"] or g["sum"] is not None
+                  or g["count"] is not None}
+        if not groups:
+            errors.append("histogram %r has no samples" % fam)
+        for key, g in groups.items():
+            g["buckets"].sort()
+            vals = [v for _, v in g["buckets"]]
+            if not g["buckets"] or g["buckets"][-1][0] != float("inf"):
+                errors.append("histogram %r %r has no +Inf bucket"
+                              % (fam, key))
+            elif g["count"] is None or g["sum"] is None:
+                errors.append("histogram %r %r missing _sum/_count"
+                              % (fam, key))
+            elif vals[-1] != g["count"]:
+                errors.append("histogram %r %r: +Inf bucket %r != _count "
+                              "%r" % (fam, key, vals[-1], g["count"]))
+            if any(b < a for a, b in zip(vals, vals[1:])):
+                errors.append("histogram %r %r buckets not cumulative"
+                              % (fam, key))
     return families, errors
 
 
@@ -140,6 +180,15 @@ def test_metrics_exposition_lint(cpp_build, tmp_path):
         assert families.get("rpc_scheduler_urgent_handoffs") == "gauge"
         assert families.get("rpc_scheduler_runqueue_highwater") == "gauge"
         assert families.get("rpc_socket_write_batch_bytes") == "summary"
+        # ISSUE 25 stage clock: one real histogram family, a series a
+        # stage, and the safety-net counters as plain gauges.
+        assert families.get("rpc_stage_us") == "histogram", sorted(families)
+        assert re.search(
+            r'^rpc_stage_us_count\{stage="tfiber.wake_to_run"\} [1-9]\d*$',
+            text, re.M), text[:500]
+        assert families.get("rpc_scheduler_park_timeouts") == "gauge"
+        assert families.get(
+            "rpc_scheduler_park_timeouts_found_work") == "gauge"
         assert re.search(
             r'^rpc_dispatcher_epoll_waits\{loop="0"\} \d+$', text, re.M), \
             text[:500]

@@ -36,6 +36,8 @@
 #include "trpc/redis.h"
 #include "trpc/server.h"
 #include "tvar/latency_recorder.h"
+#include "tvar/stage_recorder.h"
+#include "tvar/variable.h"
 
 using namespace tpurpc;
 
@@ -304,6 +306,7 @@ struct ScaleCtx {
     LatencyRecorder* lat;
     std::atomic<bool>* stop;
     std::atomic<int64_t>* calls;
+    std::atomic<int64_t>* caller_us;  // sum of the callers' own latencies
     IOBuf* filler;
 };
 
@@ -318,13 +321,33 @@ void* ScaleCaller(void* arg) {
         cntl.request_attachment().append(*c->filler);
         c->stub->Echo(&cntl, &req, &res, nullptr);
         if (!cntl.Failed()) {
-            *c->lat << (monotonic_time_us() - res.send_ts_us());
+            const int64_t us = monotonic_time_us() - res.send_ts_us();
+            *c->lat << us;
+            c->caller_us->fetch_add(us, std::memory_order_relaxed);
             c->calls->fetch_add(1, std::memory_order_relaxed);
         } else {
             g_failed_calls.fetch_add(1, std::memory_order_relaxed);
         }
     }
     return nullptr;
+}
+
+// One stderr line at each edge of a sweep level: this (client) process's
+// stage-clock table and lost-wake-up counters, cumulative, so that
+// tools/stage_closure.py can difference a level and set the stages'
+// sum beside the callers' own mean (calls, caller_sum_us). stdout keeps
+// its one result line.
+void PrintStageMark(const char* edge, int callers, int64_t calls,
+                    int64_t caller_sum_us) {
+    std::string rescued = "0";
+    Variable::describe_exposed("rpc_scheduler_park_timeouts_found_work",
+                               &rescued);
+    fprintf(stderr,
+            "STAGES %s callers=%d calls=%lld caller_sum_us=%lld "
+            "park_timeouts_found_work=%s stages=%s\n",
+            edge, callers, (long long)calls, (long long)caller_sum_us,
+            rescued.c_str(), stage::DumpJson().c_str());
+    fflush(stderr);
 }
 
 // Runs one sweep level; returns qps and fills *p99_us.
@@ -335,8 +358,10 @@ double RunScaleLevel(benchpb::EchoService_Stub& stub, int ncallers,
     LatencyRecorder lat;
     std::atomic<bool> stop{false};
     std::atomic<int64_t> calls{0};
-    ScaleCtx ctx{&stub, &lat, &stop, &calls, &filler};
+    std::atomic<int64_t> caller_us{0};
+    ScaleCtx ctx{&stub, &lat, &stop, &calls, &caller_us, &filler};
     std::vector<fiber_t> tids((size_t)ncallers);
+    PrintStageMark("begin", ncallers, 0, 0);
     const int64_t t0 = monotonic_time_us();
     for (auto& tid : tids) {
         fiber_start_background(&tid, nullptr, ScaleCaller, &ctx);
@@ -345,6 +370,7 @@ double RunScaleLevel(benchpb::EchoService_Stub& stub, int ncallers,
     stop.store(true, std::memory_order_relaxed);
     for (auto tid : tids) fiber_join(tid, nullptr);
     const double secs = (double)(monotonic_time_us() - t0) / 1e6;
+    PrintStageMark("end", ncallers, calls.load(), caller_us.load());
     *p99_us = (long long)lat.latency_percentile(0.99);
     return (double)calls.load() / secs;
 }
@@ -495,6 +521,9 @@ int main(int argc, char** argv) {
             fprintf(stderr, "failed to spawn --ici-server child\n");
             return 1;
         }
+        // The child's portal, for whoever wants its /status beside this
+        // process's STAGES lines (tools/stage_closure.py).
+        fprintf(stderr, "XPROC_SERVER_PORT %d\n", xproc_port);
     }
     // Windowed 1MB messages benefit from fixed large socket buffers on
     // loopback; production connections keep kernel autotuning (-1).

@@ -13,6 +13,14 @@ endpoint implements with ibv_post_send out of its registered block pool
 (rdma_endpoint.cpp CutFromIOBufList): device DMA reading straight from
 pool-registered frame bytes, several transfers in flight.
 
+Two threads drive a pipelined pass, as that endpoint's sender and its
+completion-queue poller do: the thread that calls `_ChunkPipeline.run`
+launches chunks (acquire, stage, frame, H2D, dispatch, and the request
+for both results' copies back), a completion thread that lives inside
+that call retires them in launch order (wait for the D2H, crc32c,
+complete). The serial baseline keeps nothing in flight, so it retires
+each chunk on the calling thread.
+
 The serial baseline (the retired `device_path_mbps` loop: device_put ->
 compute -> block -> copy-back per chunk, nothing in flight) runs over
 the same chunks; `device_path_overlap_eff` = pipelined / serial
@@ -25,9 +33,10 @@ That drives the first device; `run(..., device=d)` drives any one of
 """
 import json
 import os
+import queue
 import sys
+import threading
 import time
-from collections import deque
 from functools import lru_cache
 
 import numpy as np
@@ -123,7 +132,18 @@ class _ChunkPipeline:
     arrays with host memory, donated device buffers elsewhere, and
     depth-N chunks in flight so H2D/compute/D2H of neighboring chunks
     overlap. The gap between the two is exactly what the ISSUE-9 ring
-    buys: no per-RPC copies, no per-chunk sync."""
+    buys: no per-RPC copies, no per-chunk sync.
+
+    Who runs where: `run()`'s caller launches every chunk (`_launch`, so
+    `touch` is called in launch order on that thread). At depth 1 it also
+    retires each chunk before the next launch. At any other depth a
+    completion thread, started and joined inside `run()`, retires them
+    (`_retire`) in the order they were handed over, so `dev_checks` is in
+    launch order; it waits only for copies the launch already asked for.
+    A credit bounds the chunks launched and not yet completed to `depth`
+    whatever the ring's own depth. An error on either thread aborts the
+    ring and leaves `run()` as itself, after the other thread has
+    stopped."""
 
     def __init__(self, ring, chunks, dev, touch, depth, copy_mode):
         self.ring = ring
@@ -137,15 +157,19 @@ class _ChunkPipeline:
         self.ok = True
         self.dev_checks = []
         self.passes = 0  # passes begun: the `pass` of a span's request
+        self._credits = None  # one run()'s: `depth` less what is in flight
 
     # Spans (brpc_tpu/spans.py), request = (pass, chunk): per pass one
     # `ring.pass` (its self time is this loop's own), per chunk one
-    # `ring.launch` with children ring.acquire (waiting for a free slot),
-    # ring.stage (the copy into the slot), ring.frame (in-place framing),
-    # ring.h2d, ring.kernel_dispatch (the jitted pass + the async D2H
-    # request), and one `ring.retire` with children ring.d2h_wait (blocks
-    # until the device is done), ring.verify (crc32c), ring.complete.
-    # PERF.md section 3 names the metric that reads each.
+    # `ring.launch` with children ring.acquire (waiting for a credit and a
+    # free slot), ring.stage (the copy into the slot), ring.frame (in-place
+    # framing), ring.h2d, ring.kernel_dispatch (the jitted pass + the async
+    # D2H requests), and one `ring.retire` with children ring.d2h_wait
+    # (blocks until the device is done), ring.verify (crc32c),
+    # ring.complete. `ring.retire` is top-level on the completion thread
+    # (inside `ring.pass` at depth 1); the launcher's wait for the last
+    # retires is `ring.drain`, under the last `ring.pass`. Self times are
+    # per thread. PERF.md section 3 names the metric that reads each.
 
     # Never park forever on the ring (ISSUE 10c): a wedged device stream
     # (lost completion, dead driver) must surface as an error, not a hung
@@ -153,19 +177,25 @@ class _ChunkPipeline:
     # ring is poisoned so every OTHER thread parked on it unblocks too.
     ACQUIRE_TIMEOUT_US = 30_000_000
 
+    def _acquire(self):
+        """A credit, then the ring's next slot; both within the timeout."""
+        if self._credits.acquire(timeout=self.ACQUIRE_TIMEOUT_US / 1e6):
+            try:
+                return self.ring.acquire(self.ACQUIRE_TIMEOUT_US)
+            except TimeoutError:
+                pass
+        self.ring.abort()
+        raise RuntimeError(
+            "staging-ring acquire timed out (lost completion or wedged "
+            "device stream); ring aborted")
+
     def _launch(self, k):
         import jax
         from brpc_tpu import native
         req = (self.passes, k)
         with spans.span("ring.launch", req):
             with spans.span("ring.acquire", req):
-                try:
-                    slot = self.ring.acquire(self.ACQUIRE_TIMEOUT_US)
-                except TimeoutError:
-                    self.ring.abort()
-                    raise RuntimeError(
-                        "staging-ring acquire timed out (lost completion "
-                        "or wedged device stream); ring aborted") from None
+                slot = self._acquire()
             sa = self.ring.slots[slot]
             clen = self.chunk_bytes
             if self.copy_mode:
@@ -194,8 +224,11 @@ class _ChunkPipeline:
                     x = _h2d(sa[poff:poff + clen].view(np.uint32), self.dev)
             with spans.span("ring.kernel_dispatch", req):
                 y, chk = self.touch(x)
-                if not self.copy_mode and hasattr(y, "copy_to_host_async"):
-                    y.copy_to_host_async()
+                if not self.copy_mode:
+                    # Everything `_retire` will wait for is asked for here.
+                    for out in (y, chk):
+                        if hasattr(out, "copy_to_host_async"):
+                            out.copy_to_host_async()
         return (req, slot, foff, flen, poff, y, chk)
 
     def _retire(self, item):
@@ -230,27 +263,64 @@ class _ChunkPipeline:
             self.dev_checks.append(word)
             with spans.span("ring.complete", req):
                 self.ring.complete(slot)
+        self._credits.release()
+
+    def _retire_handed_over(self, handoff, failure):
+        """The completion thread's body: retire what the launcher hands
+        over, in that order, until its `None`. An error here aborts the
+        ring, which is what unblocks a launcher parked in `_acquire`, and
+        is left in `failure` for `run()` to raise."""
+        try:
+            for item in iter(handoff.get, None):
+                self._retire(item)
+        except BaseException as e:  # re-raised by run(), on its thread
+            failure.append(e)
+            self.ring.abort()
+            self._credits.release()  # the failed chunk's
 
     def run(self, reps):
+        """`reps` passes over the chunks; every chunk launched here is
+        retired when this returns. Seconds taken."""
         t0 = time.monotonic()
-        inflight = deque()
-        for _ in range(reps):
-            self.passes += 1
-            # The pass's span ends with the pass's last launch; the chunks
-            # still in flight then retire under the next pass's span, or
-            # under the drain's below.
-            with spans.span("ring.pass", (self.passes, None)):
-                for k in range(len(self.chunks)):
-                    inflight.append(self._launch(k))
-                    # Serial (depth=1): drain immediately — nothing
-                    # overlaps. Pipelined: keep `depth` chunks in flight;
-                    # retiring the oldest overlaps its D2H/verify with the
-                    # younger chunks' H2D + compute.
-                    while len(inflight) >= self.depth:
-                        self._retire(inflight.popleft())
-        with spans.span("ring.pass", (self.passes, None)):
-            while inflight:
-                self._retire(inflight.popleft())
+        # Nothing is in flight between calls, so every call starts with all
+        # of its credits, an earlier one's error notwithstanding.
+        self._credits = threading.Semaphore(self.depth)
+        failure = []
+        handoff = queue.SimpleQueue()
+        # Serial (depth 1): nothing in flight, so nothing a second thread
+        # could overlap; each chunk retires right after its launch.
+        hand_over = self._retire if self.depth == 1 else handoff.put
+        completions = None
+        try:
+            for _ in range(reps):
+                self.passes += 1
+                # The pass's span ends with the pass's last launch; the
+                # chunks still in flight then retire beside the next
+                # pass's launches, or during the drain below.
+                with spans.span("ring.pass", (self.passes, None)):
+                    if completions is None and self.depth > 1:
+                        # Under the first pass's span, so that the
+                        # launcher's self times cover all of its time.
+                        completions = threading.Thread(
+                            target=self._retire_handed_over,
+                            args=(handoff, failure), name="ring.completions")
+                        completions.start()
+                    for k in range(len(self.chunks)):
+                        hand_over(self._launch(k))
+        except BaseException:
+            self.ring.abort()  # a launch that failed never frees its slot
+            raise
+        finally:
+            if completions is not None:
+                req = (self.passes, None)
+                with spans.span("ring.pass", req), \
+                        spans.span("ring.drain", req):
+                    handoff.put(None)
+                    completions.join()
+            if failure:
+                # What the launcher met after that (RingAbortedError out
+                # of `_acquire`) is only its echo.
+                raise failure[0]
         return time.monotonic() - t0
 
 

@@ -1,7 +1,10 @@
-"""Spans inside the staging-ring pass (ISSUE 25): a small pass on the CPU
-backend leaves, per chunk, one `ring.launch` and one `ring.retire` with the
-children the issue lists; their self times add up to the passes' wall time;
-the ring of records is bounded; a window cut out of it clips."""
+"""Spans inside the staging-ring pass (ISSUE 25, 26): a small pass on the
+CPU backend leaves, per chunk, one `ring.launch` on the calling thread and
+one `ring.retire` on the completion thread, with the children the issues
+list; the launcher's self times add up to the passes' wall time and the
+completion thread's stay inside it; the ring of records is bounded; a
+window cut out of it clips."""
+import threading
 import time
 
 import numpy as np
@@ -38,10 +41,19 @@ def test_every_chunk_has_its_launch_and_retire_with_their_children(pipeline):
     n = len(pipeline.chunks)
     pipeline.run(2)
     assert pipeline.ok
-    by_request = {}
-    for name, start, end, request, _ in spans.snapshot():
+    by_request, threads = {}, {}
+    for name, start, end, request, thread in spans.snapshot():
         assert end >= start
         by_request.setdefault(request, []).append((name, start, end))
+        threads.setdefault(name, set()).add(thread)
+    # The launch side is the caller's, the retire side one other thread's.
+    me = threading.get_ident()
+    for name in LAUNCH_CHILDREN | {"ring.launch", "ring.pass", "ring.drain"}:
+        assert threads[name] == {me}, name
+    (completions,) = threads["ring.retire"]
+    assert completions != me
+    for name in RETIRE_CHILDREN:
+        assert threads[name] == {completions}, name
     chunk_requests = [r for r in by_request if r[1] is not None]
     assert len(chunk_requests) == 2 * n  # two passes, every chunk
     for request in chunk_requests:
@@ -59,9 +71,15 @@ def test_every_chunk_has_its_launch_and_retire_with_their_children(pipeline):
         (l0, l1), = [(s, e) for nm, s, e in got if nm == "ring.launch"]
         assert any(s <= l0 and l1 <= e
                    for _, s, e in by_request[(request[0], None)])
-    # Two passes and the drain, each under a ring.pass of that pass.
+    # Two passes and the drain, each under a ring.pass of that pass; the
+    # drain is the last pass's and lies inside a ring.pass of its own.
     passes = [r for r in by_request if r[1] is None]
     assert {p[0] for p in passes} == {c[0] for c in chunk_requests}
+    last = by_request[max(passes)]
+    (d0, d1), = [(s, e) for nm, s, e in last if nm == "ring.drain"]
+    assert any(nm == "ring.pass" and s <= d0 and d1 <= e for nm, s, e in last)
+    assert all(nm != "ring.drain" for p in passes if p != max(passes)
+               for nm, _, _ in by_request[p])
 
 
 def test_self_times_and_remainder_add_up_to_the_wall_time(pipeline):
@@ -70,19 +88,31 @@ def test_self_times_and_remainder_add_up_to_the_wall_time(pipeline):
         pipeline.run(1)
     t1 = time.monotonic()
     records = spans.snapshot(t0, t1)
-    own = spans.self_times(records)
-    assert set(own) == (LAUNCH_CHILDREN | RETIRE_CHILDREN
-                        | {"ring.launch", "ring.retire", "ring.pass"})
+    me = threading.get_ident()
+    launcher = [r for r in records if r[4] == me]
+    completions = [r for r in records if r[4] != me]
+    own = spans.self_times(launcher)
+    assert set(own) == LAUNCH_CHILDREN | {"ring.launch", "ring.pass",
+                                          "ring.drain"}
     assert all(v >= 0 for v in own.values()), own
-    # The listed stages plus the remainder (the loop's own: ring.pass,
-    # ring.launch, ring.retire self times) are the time inside the passes,
-    # which is all of [t0, t1] but the five calls' own overhead.
+    # The launcher's stages plus its remainder (the loop's own: ring.pass
+    # and ring.launch self times) and its wait for the last retires are
+    # the time inside the passes, which is all of [t0, t1] but the five
+    # calls' own overhead.
     assert sum(own.values()) == pytest.approx(t1 - t0, rel=0.02)
     # Self time never counts a moment twice: the top-level spans alone
     # cover the same time.
-    top = sum(end - start for name, start, end, *_ in records
+    top = sum(end - start for name, start, end, *_ in launcher
               if name == "ring.pass")
     assert sum(own.values()) == pytest.approx(top, rel=1e-9)
+    # The completion thread's self times are its own clock: beside the
+    # launcher's, never more than the wall time, and reduced per thread
+    # (over all the records the two threads' sums simply add).
+    beside = spans.self_times(completions)
+    assert set(beside) == RETIRE_CHILDREN | {"ring.retire"}
+    assert 0 < sum(beside.values()) <= t1 - t0
+    assert sum(spans.self_times(records).values()) == pytest.approx(
+        sum(own.values()) + sum(beside.values()), rel=1e-9)
 
 
 def test_snapshot_clips_to_the_window_and_the_ring_is_bounded():

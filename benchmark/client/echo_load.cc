@@ -17,6 +17,13 @@
 //      raw little-endian uint64 nanoseconds; one JSON line goes to stdout
 //      (with the fiber workers this process ran and the operations completed
 //      in each second of the window: a dip is told from a run that sat low).
+//      The line also carries THIS process's half of a served call, which no
+//      portal shows: the library's cumulative stage table and its
+//      `*_timeouts` / `*_timeouts_found_work` counters, dumped once after the
+//      warm-up before the first timed operation (`client_before`) and once
+//      after the drain (`client_after`), each in the shape of one scrape of
+//      the server's portal. Both dumps lie outside `window_s`, the latency
+//      sample and `client_cpu_s`; benchmark/stages.py takes after - before.
 //
 // Payload of caller c, operation n (n counts from 1, warm-up included):
 //   bytes [0,8)  little-endian (c << 48) | n   -- a stale or crossed reply
@@ -55,6 +62,8 @@
 #include "trpc/channel.h"
 #include "trpc/controller.h"
 #include "trpc/server.h"
+#include "tvar/stage_recorder.h"
+#include "tvar/variable.h"
 
 using namespace tpurpc;
 
@@ -202,6 +211,29 @@ double CpuSeconds() {
            (ru.ru_utime.tv_usec + ru.ru_stime.tv_usec) / 1e6;
 }
 
+bool EndsWith(const std::string& s, const char* suffix) {
+    const size_t n = strlen(suffix);
+    return s.size() >= n && s.compare(s.size() - n, n, suffix) == 0;
+}
+
+// This process's stage table and safety-net counters, all cumulative, as
+// {"status":{"stages":{...}},"vars":{"<name>":N,...}}: what a scrape of the
+// server's portal gives the harness for the server (served.py `scrape`).
+std::string ProcessDump() {
+    std::string vars;
+    for (const std::string& name : Variable::list_exposed()) {
+        std::string value;
+        if ((EndsWith(name, "_timeouts") ||
+             EndsWith(name, "_timeouts_found_work")) &&
+            Variable::describe_exposed(name, &value) &&
+            IsNumericLiteral(value)) {
+            vars += (vars.empty() ? "\"" : ",\"") + name + "\":" + value;
+        }
+    }
+    return "{\"status\":{\"stages\":" + stage::DumpJson() +
+           "},\"vars\":{" + vars + "}}";
+}
+
 // ---- the control: a plain echo with every K-th reply altered ----------
 
 class FlippingEcho : public benchpb::EchoService {
@@ -329,6 +361,7 @@ int main(int argc, char** argv) {
     char go[8];
     if (read(0, go, sizeof(go)) <= 0) return 1;  // parent went away
 
+    const std::string dump_before = ProcessDump();
     shared.record = true;
     const double cpu0 = CpuSeconds();
     const int64_t t0 = NowNs();
@@ -336,6 +369,7 @@ int main(int argc, char** argv) {
     shared.t_end_ns = t0 + (int64_t)(seconds * 1e9);
     RunCallers(callers);
     const double cpu1 = CpuSeconds();
+    const std::string dump_after = ProcessDump();
 
     int64_t attempted = 0, ok = 0, rpc_failed = 0, mismatched = 0;
     int64_t t_last = t0;
@@ -381,11 +415,12 @@ int main(int argc, char** argv) {
            "\"mismatched\":%lld,\"window_s\":%.9f,\"client_cpu_s\":%.6f,"
            "\"bytes_each\":%zu,\"body_crc32\":%u,\"last_seq\":[%s],"
            "\"last_reply_crc32\":[%s],\"errors\":{%s},\"workers\":%d,"
-           "\"per_s\":[%s]}\n",
+           "\"per_s\":[%s],\"client_before\":%s,\"client_after\":%s}\n",
            (long long)attempted, (long long)ok, (long long)rpc_failed,
            (long long)mismatched, (double)(t_last - t0) / 1e9, cpu1 - cpu0,
            nbytes, body_crc, seqs.c_str(), crcs.c_str(), errs.c_str(),
-           fiber_get_worker_count(), secs.c_str());
+           fiber_get_worker_count(), secs.c_str(), dump_before.c_str(),
+           dump_after.c_str());
     fflush(stdout);
     _exit(0);  // as the program's tools: no static teardown under live threads
 }

@@ -62,21 +62,24 @@ class Children:
 
 
 def read_line(proc, timeout: float, what: str) -> str:
-    """One line of the child's stdout, or RuntimeError after `timeout`."""
+    """One line of the child's stdout, or RuntimeError after `timeout`.
+    Read in blocks (the client's report is tens of KB); what a block holds
+    beyond the line waits on `proc.unread` for the next call."""
     deadline = time.monotonic() + timeout
-    buf = b""
+    buf = getattr(proc, "unread", b"")
     fd = proc.stdout.fileno()
-    while not buf.endswith(b"\n"):
+    while b"\n" not in buf:
         left = deadline - time.monotonic()
         if left <= 0 or not select.select([fd], [], [], left)[0]:
             raise RuntimeError(f"{what}: nothing after {timeout:.0f} s "
-                               f"(got {buf!r})")
-        byte = os.read(fd, 1)
-        if not byte:
+                               f"(got {buf[-200:]!r})")
+        block = os.read(fd, 1 << 16)
+        if not block:
             raise RuntimeError(f"{what}: child ended (rc {proc.poll()}) "
-                               f"after {buf!r}")
-        buf += byte
-    return buf.decode().strip()
+                               f"after {buf[-200:]!r}")
+        buf += block
+    line, _, proc.unread = buf.partition(b"\n")
+    return line.decode().strip()
 
 
 def build_client(build_dir: Path) -> Path:
@@ -298,4 +301,9 @@ def run(run) -> dict:
         "ops": report["ok"], "payload_bytes": report["ok"] * nbytes,
         "client_cpu_s": report["client_cpu_s"],
         "server_cpu_s": cpu1 - cpu0, "before": before, "after": after,
+        # The client process's own stage table and counters at the window's
+        # two edges, in a scrape's shape; None (never an empty table) from a
+        # client that sends none.
+        "client_before": report.get("client_before"),
+        "client_after": report.get("client_after"),
     }

@@ -1,6 +1,7 @@
-"""Waiting for a free staging-ring slot: share of the window, self time of the
-program's spans (brpc_tpu.spans) ring.acquire. The five ring_*_share and the
-loop's remainder sum to 100."""
+"""Waiting for a credit and a free staging-ring slot: share of the window, self
+time of the program's spans (brpc_tpu.spans) ring.acquire. One of the
+launcher's four shares, which add up to the window (see
+ring_launcher_rest_share)."""
 from benchmark import stages
 
 LAYER = "staging ring (cpp/tici DeviceStagingRing + brpc_tpu/device_path.py)"

@@ -1,5 +1,7 @@
 """crc32c of the returned bytes against the framer's: share of the window, self
-time of the program's spans (brpc_tpu.spans) ring.verify."""
+time of the program's spans (brpc_tpu.spans) ring.verify. Since PR 26 the
+completion thread's work, beside the launcher's four shares and not one of
+them."""
 from benchmark import stages
 
 LAYER = "staging ring (cpp/tici DeviceStagingRing + brpc_tpu/device_path.py)"

@@ -3,7 +3,9 @@
 A cell names a configuration and a traffic mix; a configuration names its
 file (and in it, its driver); a traffic mix is `traffic/<name>.json`; a
 per-layer metric is `layer_metrics/<name>.py`. A later PR adds entries and
-files and edits none.
+files and edits none. Every function takes the `root` of the checkout it
+is to read (the default is this one), so a test can rehearse an addition
+on a copy.
 """
 import importlib.util
 import json
@@ -42,12 +44,12 @@ def config(man: dict, cell_: dict, root: Path = ROOT) -> dict:
     return json.loads((root / entry["file"]).read_text())
 
 
-def traffic_path(name: str) -> Path:
-    return HERE / "traffic" / f"{name}.json"
+def traffic_path(name: str, root: Path = ROOT) -> Path:
+    return root / HERE.name / "traffic" / f"{name}.json"
 
 
-def traffic(cell_: dict) -> dict:
-    return json.loads(traffic_path(cell_["traffic"]).read_text())
+def traffic(cell_: dict, root: Path = ROOT) -> dict:
+    return json.loads(traffic_path(cell_["traffic"], root).read_text())
 
 
 def metrics_of(man: dict, kind: str, cell_name: str) -> list:
@@ -57,14 +59,14 @@ def metrics_of(man: dict, kind: str, cell_name: str) -> list:
             if cell_name in m.get("workloads", [cell_name])]
 
 
-def reader_path(name: str) -> Path:
-    return HERE / "layer_metrics" / f"{name}.py"
+def reader_path(name: str, root: Path = ROOT) -> Path:
+    return root / HERE.name / "layer_metrics" / f"{name}.py"
 
 
-def reader(name: str):
+def reader(name: str, root: Path = ROOT):
     """The per-layer metric's module: LAYER, UNIT, MOVES, SOURCE and
     `read(obs) -> float | None` (None: nothing to read in this run)."""
-    path = reader_path(name)
+    path = reader_path(name, root)
     spec = importlib.util.spec_from_file_location(
         "layer_metric_" + re.sub(r"\W", "_", name), path)
     if spec is None or not path.exists():
@@ -112,8 +114,8 @@ def problems(man: dict, root: Path = ROOT) -> list:
                            f"configuration says {cfg.get('chips')}")
         except (ManifestError, OSError, ValueError) as e:
             bad.append(f"{c['name']}: configuration: {e}")
-        if not traffic_path(c["traffic"]).exists():
-            bad.append(f"{c['name']}: no {traffic_path(c['traffic'])}")
+        if not traffic_path(c["traffic"], root).exists():
+            bad.append(f"{c['name']}: no {traffic_path(c['traffic'], root)}")
         mine = metrics_of(man, "end_to_end", c["name"])
         if "setup_s" not in [m["name"] for m in mine] or len(mine) < 2:
             bad.append(f"{c['name']}: needs setup_s and one more "
@@ -127,6 +129,9 @@ def problems(man: dict, root: Path = ROOT) -> list:
             bad.append(f"config {c['name']} is used by no cell")
         if not any(c["file"].startswith(p + "/") for p in man["paths"]):
             bad.append(f"config file {c['file']} lies outside paths")
+    pairs = [(c["config"], c["traffic"]) for c in cells.values()]
+    for pair in {p for p in pairs if pairs.count(p) > 1}:
+        bad.append(f"configuration and traffic {pair} make two cells")
     if sum(c["chips"] == 4 for c in cells.values()) > max(1, len(cells) // 2):
         bad.append("more than half of the cells ask for 4 chips")
     for m in man["per_layer"]:
@@ -134,7 +139,7 @@ def problems(man: dict, root: Path = ROOT) -> list:
             bad.append(f"{m['name']} moves unknown {m['moves']!r}")
             continue
         try:
-            mod = reader(m["name"])
+            mod = reader(m["name"], root)
         except (ManifestError, OSError, SyntaxError) as e:
             bad.append(str(e))
             continue

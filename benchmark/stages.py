@@ -3,11 +3,16 @@
 The server publishes cumulative per-stage histograms under "stages" in
 `/status?format=json` (cpp/tvar/stage_recorder.h): `after - before` of two
 scrapes is exactly what happened between them, so these numbers are the
-window's and hold nothing of the warm-up. The ring pass leaves spans in
-`brpc_tpu.spans`, in the harness's own process, cut here to the window.
-Every function returns None where there is nothing to read: an empty
-window, a program without the stage clock (the parent of the PR that
-brought it), an `obs` of another driver.
+window's and hold nothing of the warm-up. The client process has the same
+table and the same cumulative counters of its own; the load generator
+dumps both after its warm-up and after its drain, and the driver hands
+them on as `client_before` / `client_after` in a scrape's shape. `side`
+says whose pair is read: one process's table never stands in for the
+other's. The ring pass leaves spans in `brpc_tpu.spans`, in the harness's
+own process, cut here to the window. Every function returns None where
+there is nothing to read: an empty window, a program without the stage
+clock (the parent of the PR that brought it), a client that sent no
+table, an `obs` of another driver.
 """
 
 SUB = 8  # sub-buckets an octave: PercentileHistogram's layout
@@ -17,6 +22,9 @@ SUB = 8  # sub-buckets an octave: PercentileHistogram's layout
 # their means add up to the call's residence in the server.
 RESIDENCE = ("tnet.consume_to_cut", "tfiber.dispatch_to_handler",
              "trpc.handler", "trpc.respond", "tnet.write_queue")
+# side -> the observation's keys of that process's two dumps
+SIDES = {"server": ("before", "after"),
+         "client": ("client_before", "client_after")}
 
 
 def bucket_value(index: int) -> int:
@@ -30,12 +38,13 @@ def bucket_value(index: int) -> int:
     return base + (base // 8) * sub + base // 16
 
 
-def window(obs: dict, stage: str):
-    """The stage's samples inside the window: {"count", "sum_us",
-    "buckets": {index: count}}, or None."""
+def window(obs: dict, stage: str, side: str = "server"):
+    """The stage's samples inside the window, in the `side` process:
+    {"count", "sum_us", "buckets": {index: count}}, or None."""
+    first, last = SIDES[side]
     try:
-        before = obs["before"]["status"]["stages"][stage]
-        after = obs["after"]["status"]["stages"][stage]
+        before = obs[first]["status"]["stages"][stage]
+        after = obs[last]["status"]["stages"][stage]
     except (KeyError, TypeError):
         return None
     count = after["count"] - before["count"]
@@ -51,8 +60,8 @@ def window(obs: dict, stage: str):
             "buckets": buckets}
 
 
-def mean_us(obs: dict, stage: str):
-    w = window(obs, stage)
+def mean_us(obs: dict, stage: str, side: str = "server"):
+    w = window(obs, stage, side)
     return None if w is None else w["sum_us"] / w["count"]
 
 
@@ -63,10 +72,10 @@ def residence_mean_us(obs: dict):
     return None if None in means else sum(means)
 
 
-def quantile_us(obs: dict, stage: str, q: float):
+def quantile_us(obs: dict, stage: str, q: float, side: str = "server"):
     """As HistogramSnapshot::quantile: the bucket holding the sample of
     rank floor(q * count), by its representative value."""
-    w = window(obs, stage)
+    w = window(obs, stage, side)
     if w is None or not w["buckets"]:
         return None
     total = sum(w["buckets"].values())
@@ -79,11 +88,12 @@ def quantile_us(obs: dict, stage: str, q: float):
     return None
 
 
-def counters_delta(obs: dict, suffix: str):
-    """Sum over the window of every cumulative /vars integer whose name
-    ends in `suffix`; None where the program has none."""
+def counters_delta(obs: dict, suffix: str, side: str = "server"):
+    """Sum over the window of every cumulative /vars integer of the `side`
+    process whose name ends in `suffix`; None where it has none."""
+    first, last = SIDES[side]
     try:
-        before, after = obs["before"]["vars"], obs["after"]["vars"]
+        before, after = obs[first]["vars"], obs[last]["vars"]
     except (KeyError, TypeError):
         return None
     names = [n for n in after if n.endswith(suffix)]

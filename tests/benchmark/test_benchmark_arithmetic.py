@@ -364,21 +364,13 @@ def test_portal_pages_parse():
 # ---------------------------------------------------- readers on fixed obs
 
 _SCRAPE0 = {"vars": {}, "status": {"methods": {"benchpb.EchoService.Echo": {
-    "count": 1000, "latency_us": {"p99": 3}}}},
-    "loops": {"loops": [{"loop": 0, "events": 100, "wake_to_dispatch_us":
-                         {"p50": 1, "p99": 2, "max": 3}}],
-              "pools": [], "counters": {"coalesced_writes": 100}}}
+    "count": 1000}}},
+    "loops": {"loops": [], "pools": [],
+              "counters": {"coalesced_writes": 100}}}
 _SCRAPE1 = {"vars": {"rpc_pool_descriptor_send_bytes": 2.5e8},
             "status": {"methods": {"benchpb.EchoService.Echo": {
-                "count": 11000, "latency_us": {"p99": 14}}}},
-            "loops": {"loops": [{"loop": 0, "events": 50100,
-                                 "wake_to_dispatch_us":
-                                 {"p50": 9, "p99": 41, "max": 380}},
-                                {"loop": 1, "events": 0,
-                                 "wake_to_dispatch_us":
-                                 {"p50": 992, "p99": 992, "max": 1016}}],
-                      "pools": [{"runq_highwater": 24},
-                                {"runq_highwater": 3}],
+                "count": 11000}}},
+            "loops": {"loops": [], "pools": [],
                       "counters": {"coalesced_writes": 4100}}}
 _TRACE = xplane.reduce_events([
     ("/host:CPU", "py", "bench:window", 0.0, 1.0),
@@ -397,10 +389,8 @@ OBS = {"ops": 10000, "payload_bytes": 1e9, "client_cpu_s": 0.2,
 
 @pytest.mark.parametrize("name,want", [
     ("client_cpu_us_per_op", 20.0), ("server_cpu_us_per_op", 30.0),
-    ("server_cpu_us_per_mb", 300.0), ("trpc_server_p99_us", 14.0),
-    ("tnet_wake_to_dispatch_us", 41.0),
-    ("tnet_coalesced_write_share", 40.0),
-    ("tfiber_runqueue_highwater", 24.0), ("tici_desc_share", 25.0),
+    ("server_cpu_us_per_mb", 300.0),
+    ("tnet_coalesced_write_share", 40.0), ("tici_desc_share", 25.0),
     ("ring_vs_raw_ratio", 0.5),
     ("touch_kernel_roofline", 100 * (1044480 / 819e9) / 4e-6),
     ("device_idle_share.ring", 100 * (1 - (8e-6 + 0.5)))])
@@ -451,13 +441,15 @@ def test_last_line_has_the_contracts_keys_and_the_cells_metrics():
     assert list(line)[:5] == ["correct", "attempted", "failed", "metrics",
                               "device"]
     assert list(line)[-1] == "compared"
-    assert set(line["metrics"]) == {"goodput_gbps", "setup_s"}
+    assert {"goodput_gbps", "setup_s"} <= set(line["metrics"])
     assert line["metrics"]["goodput_gbps"] == {"value": 0.7, "unit": "GB/s"}
     assert line["correct"] is True and "breakdown" not in line
     traced = _line(trace=True)
-    assert set(traced["metrics"]) == {"ring_vs_raw_ratio",
-                                      "touch_kernel_roofline",
-                                      "device_idle_share.ring"}
+    # what this observation gives a reader something to read for; the
+    # span readers find no window in it and are left out of the line
+    assert {"ring_vs_raw_ratio", "touch_kernel_roofline",
+            "device_idle_share.ring"} <= set(traced["metrics"])
+    assert "ring_launcher_rest_share" not in traced["metrics"]
     assert len(traced["breakdown"]["device_ops"]) <= 10
     json.dumps(traced)
 

@@ -1,5 +1,8 @@
 """BENCHMARK.json resolves to files by name, and keeps the contract's
-naming rules. Host-only: no chip, no build/, no jax."""
+naming rules. Every assertion here holds an entry by its name or a property
+of every entry, never a position, a whole list or a count: a later PR
+appends entries, and these tests must still pass (test_benchmark_additions.py
+rehearses that). Host-only: no chip, no build/, no jax."""
 import copy
 import importlib
 import json
@@ -34,7 +37,12 @@ def test_cell_resolves_to_config_traffic_and_driver(name):
     assert traffic["loop"] == "closed"  # no cell offers load faster than replies return
     driver = importlib.import_module("benchmark.drivers." + cfg["driver"])
     assert callable(driver.run)
-    assert cfg["guarantees"] and "assumed" in cfg and cfg["reduced"] == []
+    assert cfg["guarantees"] and "assumed" in cfg
+    # every cut of scale is listed, in the file and in its entry alike
+    (entry,) = [c for c in MAN["configs"] if c["name"] == cell["config"]]
+    assert isinstance(cfg["reduced"], list)
+    assert all(isinstance(k, str) and k for k in cfg["reduced"])
+    assert cfg["reduced"] == entry["reduced"]
     reported = {m["name"] for m in
                 manifest.metrics_of(MAN, "end_to_end", name)}
     assert "setup_s" in reported and len(reported) >= 2
@@ -52,38 +60,56 @@ def test_layer_metric_has_a_reader_that_agrees_with_the_manifest(name):
                           "moves", "workloads"}
 
 
+def _named(entries, name):
+    (entry,) = [e for e in entries if e["name"] == name]
+    return entry
+
+
 def _mutations():
     def unknown_moves(m):
-        m["per_layer"][0]["moves"] = "nothing"
+        _named(m["per_layer"], "client_cpu_us_per_op")["moves"] = "nothing"
 
     def bad_unit(m):
-        m["end_to_end"][0]["unit"] = "us per op"
+        _named(m["end_to_end"], "p99_us")["unit"] = "us per op"
 
     def bad_name(m):
-        m["workloads"][0]["name"] = "echo 4k"
+        _named(m["workloads"], "echo_4k_c16")["name"] = "echo 4k"
 
     def missing_traffic(m):
-        m["workloads"][0]["traffic"] = "no_such_mix"
+        _named(m["workloads"], "echo_4k_c16")["traffic"] = "no_such_mix"
 
     def duplicate(m):
-        m["per_layer"][1]["name"] = m["per_layer"][0]["name"]
+        _named(m["per_layer"], "server_cpu_us_per_op")["name"] = \
+            "client_cpu_us_per_op"
 
     def too_many_four_chip_cells(m):
         for c in m["workloads"]:
             c["chips"] = 4
 
     def moves_a_metric_the_cell_lacks(m):
-        m["per_layer"][0]["moves"] = "goodput_gbps"
+        # echo_4k_c16 reports qps and p99_us, no goodput_gbps
+        _named(m["per_layer"], "client_cpu_us_per_op")["moves"] = \
+            "goodput_gbps"
 
     def loose_bound(m):
-        m["end_to_end"][1]["bound"] = 0.5
+        _named(m["end_to_end"], "qps")["bound"] = 0.5
 
     def unused_config(m):
-        m["configs"].append(dict(m["configs"][0], name="spare"))
+        m["configs"].append(dict(_named(m["configs"], "brpc_echo_shm"),
+                                 name="spare"))
+
+    def one_pair_two_cells(m):
+        m["workloads"].append(dict(_named(m["workloads"], "echo_1m_c4"),
+                                   name="echo_1m_c4_again"))
+
+    def no_reader_file(m):
+        m["per_layer"].append(dict(
+            _named(m["per_layer"], "client_cpu_us_per_op"),
+            name="a_metric_without_a_file"))
 
     return [unknown_moves, bad_unit, bad_name, missing_traffic, duplicate,
             too_many_four_chip_cells, moves_a_metric_the_cell_lacks,
-            loose_bound, unused_config]
+            loose_bound, unused_config, one_pair_two_cells, no_reader_file]
 
 
 @pytest.mark.parametrize("mutate", _mutations(), ids=lambda f: f.__name__)

@@ -20,6 +20,15 @@ TINY = {
                    "warm_ms=100"],
 }
 
+# The client's half of a served call, which only the client's own report
+# can give: a traced line of these cells carries them.
+CLIENT_HALF = {
+    "echo_4k_c16": {"trpc_issue_mean_us", "tici_reply_handoff_mean_us",
+                    "tnet_client_cut_mean_us", "trpc_caller_wake_mean_us"},
+    "echo_1m_c4": {"tici_reply_handoff_1m_mean_us",
+                   "tnet_client_cut_1m_mean_us"},
+}
+
 
 @pytest.fixture(scope="module")
 def built(request):
@@ -70,6 +79,8 @@ def test_rehearsal_prints_the_contracts_last_line(built, workload,
         assert {"busy_s", "window_s"} <= set(line["device"])
         assert "setup_s" not in line["metrics"]
         assert "breakdown" in line
+        for name in CLIENT_HALF.get(workload, ()):
+            assert line["metrics"][name]["value"] > 0
     else:
         assert line["metrics"]["setup_s"]["value"] > 0
         assert len(line["metrics"]) >= 2

@@ -27,7 +27,6 @@ RING_READERS = {
     "ring_acquire_wait_share": ("ring.acquire",),
     "ring_stage_frame_share": ("ring.stage", "ring.frame"),
     "ring_h2d_dispatch_share": ("ring.h2d", "ring.kernel_dispatch"),
-    "ring_d2h_wait_share": ("ring.d2h_wait",),
     "ring_verify_share": ("ring.verify",),
 }
 B100, B1000 = 52, 79  # PercentileHistogram::bucket_of(100), (1000)

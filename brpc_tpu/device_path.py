@@ -13,12 +13,15 @@ endpoint implements with ibv_post_send out of its registered block pool
 (rdma_endpoint.cpp CutFromIOBufList): device DMA reading straight from
 pool-registered frame bytes, several transfers in flight.
 
-Two threads drive a pipelined pass, as that endpoint's sender and its
-completion-queue poller do: the thread that calls `_ChunkPipeline.run`
-launches chunks (acquire, stage, frame, H2D, dispatch, and the request
-for both results' copies back), a completion thread that lives inside
-that call retires them in launch order (wait for the D2H, crc32c,
-complete). The serial baseline keeps nothing in flight, so it retires
+Two threads drive a `DeviceLane`, as that endpoint's sender and its
+completion-queue poller do: the thread that calls `submit` launches
+chunks (acquire, stage, frame, H2D, dispatch, and the request for both
+results' copies back), the lane's completion thread retires them in
+launch order (wait for the D2H, crc32c, complete). The lane is long-lived
+and takes independent requests: `_ChunkPipeline.run` is a loop over
+`submit` on a lane that lives inside that call, and the served handler
+(brpc_tpu/tensor_service.py) submits each call to one that lives as long
+as the server. The serial baseline keeps nothing in flight, so it retires
 each chunk on the calling thread.
 
 The serial baseline (the retired `device_path_mbps` loop: device_put ->
@@ -45,6 +48,10 @@ import numpy as np
 # load the shared library — that happens lazily at the first call).
 from brpc_tpu import spans
 from brpc_tpu.native import IN_PLACE_HEADROOM as HEADROOM
+
+# Never park forever on the ring (ISSUE 10c): 30s >> any sane per-chunk
+# latency. Covers the credit and the slot.
+ACQUIRE_TIMEOUT_US = 30_000_000
 
 
 def _integrity_word(words):
@@ -106,6 +113,31 @@ def _touch_kernel(chunk_words: int, platform: str):
     return jax.jit(touch, donate_argnums=0)
 
 
+@lru_cache(maxsize=4)
+def _tensor_step_kernel(key: int, platform: str):
+    """`_touch_kernel`'s sibling for the served call tensor.Step (ISSUE 29;
+    brpc_tpu/tensor_service.py): over the request as uint32 words x,
+    returns (y, w) with y[j] = x[j] ^ key for j >= 2, y[0], y[1] = x[0],
+    x[1] (caller and sequence number stay readable), and w the integrity
+    word of x -- both made on the device by this one jitted function from
+    the bytes that were DMA'd there. Not the identity, so an answer that
+    never crossed the chip cannot compare equal. Traced as module
+    `jit_tensor_step`; it compiles once a shape, so the service calls it
+    with a few fixed sizes only (tensor_service.buckets). Donation as
+    `_touch_kernel`."""
+    import jax
+    import jax.numpy as jnp
+
+    def tensor_step(x):
+        idx = jnp.arange(x.shape[-1], dtype=jnp.uint32)
+        y = jnp.where(idx >= 2, x ^ jnp.uint32(key), x)
+        return y, _integrity_word(x)
+
+    if platform == "cpu":
+        return jax.jit(tensor_step)
+    return jax.jit(tensor_step, donate_argnums=0)
+
+
 def _h2d(view: np.ndarray, dev):
     """Import one staged slot view onto the device: dlpack zero-copy on
     host-backed platforms (the registered slot IS the device buffer), a
@@ -120,30 +152,186 @@ def _h2d(view: np.ndarray, dev):
     return jax.device_put(view, dev)
 
 
+class DeviceLane:
+    """A long-lived lane through the staging ring: independent requests
+    are submitted one at a time and each is answered when its D2H is back.
+
+    `submit(fill, nbytes, token)` runs on the caller's thread (the
+    launcher): a credit and a ring slot, `fill(view)` stages the bytes
+    into the slot, the C++ framer frames them in place, H2D, the jitted
+    `kernel(x) -> (y, word)`, and the request for both results' copies
+    back; then the chunk is handed to the lane's completion thread, which
+    waits for the D2H, checks crc32c of the returned bytes against the
+    framer's where `verify` is set (a kernel that is the identity), calls
+    `on_done(token, host_bytes, word, good)` and completes the slot, in
+    the order of the submits. `host_bytes` is the device's answer on the
+    host, not the slot. That is the RDMA endpoint's sender and its
+    completion-queue poller. `close()` drains what is in flight and joins
+    the thread; a lane lives through any number of submits before it.
+
+    At depth 1 there is no second thread: nothing is in flight that it
+    could overlap, so `submit` retires its chunk before it returns.
+
+    An error on either thread aborts the ring: the launcher's leaves
+    `submit` as itself; the completion thread's is kept in `failure`, the
+    chunks behind it are given to `on_abandon(token)` instead of
+    `on_done`, and the next `submit` raises RingAbortedError. Never
+    parked forever (ISSUE 10c): an acquire that outlasts
+    ACQUIRE_TIMEOUT_US aborts the ring too.
+
+    Spans (brpc_tpu/spans.py), request = token: per submit one
+    `ring.launch` with children ring.acquire (waiting for a credit and a
+    free slot), whatever `fill` opens around its copy into the slot
+    (ring.stage in the ring pass, tensor.fill in a served call),
+    ring.frame, ring.h2d,
+    ring.kernel_dispatch (the jitted function + the async D2H requests),
+    and one `ring.retire` with children ring.d2h_wait (blocks until the
+    device is done), ring.verify (crc32c, where `verify`), ring.complete.
+    `ring.retire` is top-level on the completion thread. Self times are
+    per thread. PERF.md section 3 names the metric that reads each."""
+
+    def __init__(self, ring, dev, kernel, depth, on_done, *, verify=True,
+                 on_abandon=None):
+        self.ring = ring
+        self.dev = dev
+        self.kernel = kernel
+        self.on_done = on_done
+        self.on_abandon = on_abandon
+        self.verify = verify
+        self.failure = None  # the completion thread's first error
+        # `depth` less what is in flight, whatever the ring's own depth.
+        self._credits = threading.Semaphore(depth)
+        self._handoff = queue.SimpleQueue()
+        self._completions = None
+        if depth > 1:
+            self._completions = threading.Thread(
+                target=self._retire_handed_over, name="ring.completions")
+            self._completions.start()
+
+    def _acquire(self):
+        """A credit, then the ring's next slot; both within the timeout."""
+        if self._credits.acquire(timeout=ACQUIRE_TIMEOUT_US / 1e6):
+            try:
+                return self.ring.acquire(ACQUIRE_TIMEOUT_US)
+            except TimeoutError:
+                pass
+            except BaseException:
+                self._credits.release()  # an aborted ring: nothing launched
+                raise
+        self.ring.abort()
+        raise RuntimeError(
+            "staging-ring acquire timed out (lost completion or wedged "
+            "device stream); ring aborted")
+
+    def submit(self, fill, nbytes, token, correlation_id=1):
+        from brpc_tpu import native
+        try:
+            with spans.span("ring.launch", token):
+                with spans.span("ring.acquire", token):
+                    slot = self._acquire()
+                sa = self.ring.slots[slot]
+                view = sa[HEADROOM:HEADROOM + nbytes]
+                # Staged once, framed in place (no payload memcpy -- ISSUE
+                # 9 satellite), imported zero-copy where the platform
+                # backs arrays with host memory.
+                fill(view)
+                with spans.span("ring.frame", token):
+                    _, _, crc = native.frame_in_place(correlation_id, sa,
+                                                      HEADROOM, nbytes)
+                with spans.span("ring.h2d", token):
+                    x = _h2d(view.view(np.uint32), self.dev)
+                with spans.span("ring.kernel_dispatch", token):
+                    y, word = self.kernel(x)
+                    # Everything `_retire` will wait for is asked for here.
+                    for out in (y, word):
+                        if hasattr(out, "copy_to_host_async"):
+                            out.copy_to_host_async()
+        except BaseException:
+            self.ring.abort()  # a launch that failed never frees its slot
+            raise
+        item = (token, slot, crc, y, word)
+        if self._completions is None:
+            self._retire(item)
+        else:
+            self._handoff.put(item)
+
+    def _retire(self, item):
+        from brpc_tpu import native
+        token, slot, crc, y, word = item
+        with spans.span("ring.retire", token):
+            with spans.span("ring.d2h_wait", token):
+                # Blocks until the device is done; then its word comes back.
+                back = np.asarray(y)
+                word = int(word)
+            good = True
+            if self.verify:
+                with spans.span("ring.verify", token):
+                    # The D2H buffer against the crc32c the C++ framework
+                    # embedded at frame time: per-chunk integrity with no
+                    # copy-back and no re-parse.
+                    good = native.crc32c(back) == crc
+            self.on_done(token, back, word, good)
+            with spans.span("ring.complete", token):
+                self.ring.complete(slot)
+        self._credits.release()
+
+    def _retire_handed_over(self):
+        """The completion thread's body: retire what the launcher hands
+        over, in that order, until its `None`. An error here aborts the
+        ring, which is what unblocks a launcher parked in `_acquire`."""
+        for item in iter(self._handoff.get, None):
+            if self.failure is None:
+                try:
+                    self._retire(item)
+                    continue
+                except BaseException as e:
+                    self.failure = e
+                    self.ring.abort()
+            # The failed chunk's credit and each one's behind it: the ring
+            # is aborted, so the launcher that takes one meets that at
+            # once and never waits the timeout out for a credit.
+            self._credits.release()
+            if self.on_abandon is not None:
+                self.on_abandon(item[0])
+
+    def close(self):
+        """Everything submitted is retired (or abandoned) and the
+        completion thread is gone when this returns; `failure` says how."""
+        if self._completions is not None:
+            self._handoff.put(None)
+            self._completions.join()
+            self._completions = None
+
+
 class _ChunkPipeline:
-    """Drives the staging ring at a given depth.
+    """Drives the staging ring at a given depth over a fixed list of
+    chunks, pass after pass.
 
     copy_mode=True reproduces the RETIRED device_path_mbps loop shape
     per chunk — frame() with the payload memcpy, device_put (always a
     copy), full sync, fresh ndarray materialization, copy-back — run at
-    depth 1 with nothing in flight. copy_mode=False is the ring path:
-    payload staged once into the registered slot, framed IN PLACE
-    (header+crc only), dlpack zero-copy import where the platform backs
-    arrays with host memory, donated device buffers elsewhere, and
-    depth-N chunks in flight so H2D/compute/D2H of neighboring chunks
-    overlap. The gap between the two is exactly what the ISSUE-9 ring
-    buys: no per-RPC copies, no per-chunk sync.
+    depth 1 with nothing in flight. copy_mode=False is the ring path: a
+    loop over `DeviceLane.submit` (payload staged once into the
+    registered slot, framed IN PLACE, dlpack zero-copy import where the
+    platform backs arrays with host memory, donated device buffers
+    elsewhere, depth-N chunks in flight so H2D/compute/D2H of neighboring
+    chunks overlap) -- the same lane a served handler submits to
+    (brpc_tpu/tensor_service.py). The gap between the two is exactly what
+    the ISSUE-9 ring buys: no per-RPC copies, no per-chunk sync.
 
-    Who runs where: `run()`'s caller launches every chunk (`_launch`, so
-    `touch` is called in launch order on that thread). At depth 1 it also
-    retires each chunk before the next launch. At any other depth a
-    completion thread, started and joined inside `run()`, retires them
-    (`_retire`) in the order they were handed over, so `dev_checks` is in
-    launch order; it waits only for copies the launch already asked for.
-    A credit bounds the chunks launched and not yet completed to `depth`
-    whatever the ring's own depth. An error on either thread aborts the
-    ring and leaves `run()` as itself, after the other thread has
-    stopped."""
+    Who runs where: `run()`'s caller launches every chunk, so `touch` is
+    called in launch order on that thread. At depth 1 it also retires
+    each chunk before the next launch. At any other depth the lane's
+    completion thread, started and joined inside `run()`, retires them in
+    launch order, so `dev_checks` is in launch order. A credit bounds the
+    chunks launched and not yet completed to `depth` whatever the ring's
+    own depth. An error on either thread aborts the ring and leaves
+    `run()` as itself, after the other thread has stopped.
+
+    Spans, request = (pass, chunk): per pass one `ring.pass` (its self
+    time is this loop's own), the lane's per chunk (DeviceLane), and the
+    launcher's wait for the last retires, `ring.drain`, under the last
+    `ring.pass`."""
 
     def __init__(self, ring, chunks, dev, touch, depth, copy_mode):
         self.ring = ring
@@ -153,144 +341,66 @@ class _ChunkPipeline:
         self.depth = depth
         self.copy_mode = copy_mode
         self.chunk_bytes = chunks[0].nbytes
-        self.crcs = [0] * ring.depth  # staged crc per in-flight slot
         self.ok = True
         self.dev_checks = []
         self.passes = 0  # passes begun: the `pass` of a span's request
-        self._credits = None  # one run()'s: `depth` less what is in flight
 
-    # Spans (brpc_tpu/spans.py), request = (pass, chunk): per pass one
-    # `ring.pass` (its self time is this loop's own), per chunk one
-    # `ring.launch` with children ring.acquire (waiting for a credit and a
-    # free slot), ring.stage (the copy into the slot), ring.frame (in-place
-    # framing), ring.h2d, ring.kernel_dispatch (the jitted pass + the async
-    # D2H requests), and one `ring.retire` with children ring.d2h_wait
-    # (blocks until the device is done), ring.verify (crc32c),
-    # ring.complete. `ring.retire` is top-level on the completion thread
-    # (inside `ring.pass` at depth 1); the launcher's wait for the last
-    # retires is `ring.drain`, under the last `ring.pass`. Self times are
-    # per thread. PERF.md section 3 names the metric that reads each.
+    def _retired(self, token, back, word, good):
+        self.ok = self.ok and good
+        self.dev_checks.append(word)
 
-    # Never park forever on the ring (ISSUE 10c): a wedged device stream
-    # (lost completion, dead driver) must surface as an error, not a hung
-    # Python thread. 30s >> any sane per-chunk latency; on timeout the
-    # ring is poisoned so every OTHER thread parked on it unblocks too.
-    ACQUIRE_TIMEOUT_US = 30_000_000
+    def _submit(self, lane, k):
+        req = (self.passes, k)
 
-    def _acquire(self):
-        """A credit, then the ring's next slot; both within the timeout."""
-        if self._credits.acquire(timeout=self.ACQUIRE_TIMEOUT_US / 1e6):
-            try:
-                return self.ring.acquire(self.ACQUIRE_TIMEOUT_US)
-            except TimeoutError:
-                pass
-        self.ring.abort()
-        raise RuntimeError(
-            "staging-ring acquire timed out (lost completion or wedged "
-            "device stream); ring aborted")
+        def stage(view):
+            with spans.span("ring.stage", req):
+                np.copyto(view.view(np.uint32), self.chunks[k])
 
-    def _launch(self, k):
+        lane.submit(stage, self.chunk_bytes, req, k + 1)
+
+    def _copy_chunk(self, k):
+        """The old path, one chunk start to end on this thread: frame()
+        memcpys the external payload into the staging buffer, device_put
+        copies it again, the answer is MATERIALIZED as a fresh ndarray,
+        copied back into staging, and the framework re-parses and
+        crc32c-verifies the whole frame around it."""
         import jax
         from brpc_tpu import native
         req = (self.passes, k)
+        clen = self.chunk_bytes
         with spans.span("ring.launch", req):
             with spans.span("ring.acquire", req):
-                slot = self._acquire()
+                try:
+                    slot = self.ring.acquire(ACQUIRE_TIMEOUT_US)
+                except TimeoutError:
+                    self.ring.abort()
+                    raise RuntimeError("staging-ring acquire timed out; "
+                                       "ring aborted") from None
             sa = self.ring.slots[slot]
-            clen = self.chunk_bytes
-            if self.copy_mode:
-                # Old path: frame() memcpys the external payload into the
-                # staging buffer, then device_put copies it again.
-                with spans.span("ring.frame", req):
-                    fr = native.frame(k + 1, self.chunks[k], out=sa)
-                foff, flen = 0, len(fr)
-                poff = flen - clen
-                with spans.span("ring.h2d", req):
-                    x = jax.device_put(
-                        sa[poff:poff + clen].view(np.uint32), self.dev)
-            else:
-                # Ring path: stage the chunk payload once, frame in place
-                # (no payload memcpy — ISSUE 9 satellite), import
-                # zero-copy.
-                poff = HEADROOM
-                with spans.span("ring.stage", req):
-                    np.copyto(sa[poff:poff + clen].view(np.uint32),
-                              self.chunks[k])
-                with spans.span("ring.frame", req):
-                    foff, flen, crc = native.frame_in_place(k + 1, sa, poff,
-                                                            clen)
-                self.crcs[slot] = crc
-                with spans.span("ring.h2d", req):
-                    x = _h2d(sa[poff:poff + clen].view(np.uint32), self.dev)
+            with spans.span("ring.frame", req):
+                flen = len(native.frame(k + 1, self.chunks[k], out=sa))
+            poff = flen - clen
+            with spans.span("ring.h2d", req):
+                x = jax.device_put(sa[poff:poff + clen].view(np.uint32),
+                                   self.dev)
             with spans.span("ring.kernel_dispatch", req):
                 y, chk = self.touch(x)
-                if not self.copy_mode:
-                    # Everything `_retire` will wait for is asked for here.
-                    for out in (y, chk):
-                        if hasattr(out, "copy_to_host_async"):
-                            out.copy_to_host_async()
-        return (req, slot, foff, flen, poff, y, chk)
-
-    def _retire(self, item):
-        from brpc_tpu import native
-        req, slot, foff, flen, poff, y, chk = item
-        k = req[1]
         with spans.span("ring.retire", req):
-            sa = self.ring.slots[slot]
             with spans.span("ring.d2h_wait", req):
-                # Blocks until the device is done; the old path MATERIALIZES
-                # a fresh ndarray. Then its integrity word comes back.
-                back = np.array(y) if self.copy_mode else np.asarray(y)
+                back = np.array(y)
                 word = int(chk)
             with spans.span("ring.verify", req):
-                if self.copy_mode:
-                    # Old path: copy back into staging, then have the
-                    # framework re-parse + crc32c-verify the whole frame
-                    # around the returned payload.
-                    np.copyto(
-                        sa[poff:poff + self.chunk_bytes].view(np.uint32),
-                        back)
-                    cid, _, _ = native.unframe(sa[foff:foff + flen])
-                    good = cid == k + 1
-                else:
-                    # Ring path: the D2H buffer is verified DIRECTLY
-                    # against the crc32c the C++ framework embedded at
-                    # frame time — per-chunk integrity with no copy-back
-                    # and no re-parse (the parse path is exercised by the
-                    # serial baseline and the native tests).
-                    good = native.crc32c(back) == self.crcs[slot]
-            self.ok = self.ok and good
-            self.dev_checks.append(word)
+                np.copyto(sa[poff:poff + clen].view(np.uint32), back)
+                cid, _, _ = native.unframe(sa[:flen])
+            self._retired(req, back, word, cid == k + 1)
             with spans.span("ring.complete", req):
                 self.ring.complete(slot)
-        self._credits.release()
-
-    def _retire_handed_over(self, handoff, failure):
-        """The completion thread's body: retire what the launcher hands
-        over, in that order, until its `None`. An error here aborts the
-        ring, which is what unblocks a launcher parked in `_acquire`, and
-        is left in `failure` for `run()` to raise."""
-        try:
-            for item in iter(handoff.get, None):
-                self._retire(item)
-        except BaseException as e:  # re-raised by run(), on its thread
-            failure.append(e)
-            self.ring.abort()
-            self._credits.release()  # the failed chunk's
 
     def run(self, reps):
         """`reps` passes over the chunks; every chunk launched here is
         retired when this returns. Seconds taken."""
         t0 = time.monotonic()
-        # Nothing is in flight between calls, so every call starts with all
-        # of its credits, an earlier one's error notwithstanding.
-        self._credits = threading.Semaphore(self.depth)
-        failure = []
-        handoff = queue.SimpleQueue()
-        # Serial (depth 1): nothing in flight, so nothing a second thread
-        # could overlap; each chunk retires right after its launch.
-        hand_over = self._retire if self.depth == 1 else handoff.put
-        completions = None
+        lane = None
         try:
             for _ in range(reps):
                 self.passes += 1
@@ -298,29 +408,31 @@ class _ChunkPipeline:
                 # chunks still in flight then retire beside the next
                 # pass's launches, or during the drain below.
                 with spans.span("ring.pass", (self.passes, None)):
-                    if completions is None and self.depth > 1:
+                    if self.copy_mode:
+                        for k in range(len(self.chunks)):
+                            self._copy_chunk(k)
+                        continue
+                    if lane is None:
                         # Under the first pass's span, so that the
                         # launcher's self times cover all of its time.
-                        completions = threading.Thread(
-                            target=self._retire_handed_over,
-                            args=(handoff, failure), name="ring.completions")
-                        completions.start()
+                        lane = DeviceLane(self.ring, self.dev, self.touch,
+                                          self.depth, self._retired)
                     for k in range(len(self.chunks)):
-                        hand_over(self._launch(k))
+                        self._submit(lane, k)
         except BaseException:
             self.ring.abort()  # a launch that failed never frees its slot
             raise
         finally:
-            if completions is not None:
-                req = (self.passes, None)
-                with spans.span("ring.pass", req), \
-                        spans.span("ring.drain", req):
-                    handoff.put(None)
-                    completions.join()
-            if failure:
-                # What the launcher met after that (RingAbortedError out
-                # of `_acquire`) is only its echo.
-                raise failure[0]
+            if lane is not None:
+                if self.depth > 1:
+                    req = (self.passes, None)
+                    with spans.span("ring.pass", req), \
+                            spans.span("ring.drain", req):
+                        lane.close()
+                if lane.failure is not None:
+                    # What the launcher met after that (RingAbortedError
+                    # out of `_acquire`) is only its echo.
+                    raise lane.failure
         return time.monotonic() - t0
 
 

@@ -142,6 +142,41 @@ def lib() -> ctypes.CDLL:
         ]
         L.tpurpc_stage_dump.restype = ctypes.c_long
         L.tpurpc_stage_dump.argtypes = [ctypes.c_char_p, ctypes.c_size_t]
+        L.tpurpc_server_start.restype = ctypes.c_void_p
+        L.tpurpc_server_start.argtypes = [ctypes.c_int]
+        L.tpurpc_server_port.restype = ctypes.c_int
+        L.tpurpc_server_port.argtypes = [ctypes.c_void_p]
+        L.tpurpc_server_take.restype = ctypes.c_void_p
+        L.tpurpc_server_take.argtypes = [
+            ctypes.c_void_p, ctypes.c_long, ctypes.POINTER(ctypes.c_size_t),
+            ctypes.POINTER(ctypes.c_int)]
+        L.tpurpc_server_close_queue.argtypes = [ctypes.c_void_p,
+                                                ctypes.c_int]
+        L.tpurpc_server_stop.argtypes = [ctypes.c_void_p]
+        L.tpurpc_call_copy_out.restype = ctypes.c_long
+        L.tpurpc_call_copy_out.argtypes = [ctypes.c_void_p, ctypes.c_void_p,
+                                           ctypes.c_size_t]
+        L.tpurpc_call_reply.restype = ctypes.c_int
+        L.tpurpc_call_reply.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t,
+            ctypes.c_void_p, ctypes.c_size_t]
+        L.tpurpc_tensor_step_answered.restype = None
+        L.tpurpc_tensor_step_answered.argtypes = []
+        L.tpurpc_flag_set.restype = ctypes.c_int
+        L.tpurpc_flag_set.argtypes = [ctypes.c_char_p, ctypes.c_char_p]
+        L.tpurpc_call_fail.restype = ctypes.c_int
+        L.tpurpc_call_fail.argtypes = [ctypes.c_void_p, ctypes.c_int,
+                                       ctypes.c_char_p]
+        L.tpurpc_channel_open.restype = ctypes.c_void_p
+        L.tpurpc_channel_open.argtypes = [ctypes.c_char_p, ctypes.c_int,
+                                          ctypes.c_int, ctypes.c_long]
+        L.tpurpc_channel_call.restype = ctypes.c_int
+        L.tpurpc_channel_call.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t,
+            ctypes.c_void_p, ctypes.c_size_t,
+            ctypes.POINTER(ctypes.c_size_t), ctypes.c_long, ctypes.c_char_p,
+            ctypes.c_size_t]
+        L.tpurpc_channel_close.argtypes = [ctypes.c_void_p]
         if L.tpurpc_global_init() != 0:
             raise RuntimeError("tpurpc_global_init failed")
         _LIB = L
@@ -315,6 +350,155 @@ class DeviceStagingRing:
             lib().tpurpc_ring_destroy(self._ptr)
             self._ptr = None
             self.slots = []
+
+
+# Error codes of cpp/tbase/errno.h that the Python side answers with.
+TERR_REQUEST = 4005
+TERR_CLOSE = 4009
+TERR_INTERNAL = 4010
+
+
+def set_flag(name: str, value) -> None:
+    """One of the framework's flags (what the portal's /flags lists), for
+    the embedding process to choose before it serves or calls."""
+    if lib().tpurpc_flag_set(name.encode(), str(value).encode()) != 0:
+        raise ValueError(f"flag {name!r} is unknown or refuses {value!r}")
+
+
+def tensor_step_answered() -> None:
+    """/vars rpc_tensor_calls += 1: the D2H of one step's result is back
+    (brpc_tpu/tensor_service.py counts there, and nowhere else)."""
+    lib().tpurpc_tensor_step_answered()
+
+
+class ServerClosedError(RuntimeError):
+    """The pull server's queue was closed: `take` has nothing more."""
+
+
+class RpcError(RuntimeError):
+    """A client call failed; `.code` is the call's error code."""
+
+    def __init__(self, code: int, text: str):
+        super().__init__(f"rpc failed ({code}): {text}")
+        self.code = code
+
+
+def _address(array: np.ndarray) -> ctypes.c_void_p:
+    return ctypes.c_void_p(array.ctypes.data)
+
+
+class ParkedCall:
+    """One call of tensorpb.Tensor/Step between the C++ handler that
+    parked it and its answer. Exactly one of `reply` / `fail` ends it."""
+
+    __slots__ = ("_ptr", "nbytes")
+
+    def __init__(self, ptr: int, nbytes: int):
+        self._ptr = ptr
+        self.nbytes = nbytes
+
+    def copy_into(self, view: np.ndarray) -> None:
+        """The request attachment into `view` (uint8, at least `nbytes`),
+        in one pass."""
+        got = lib().tpurpc_call_copy_out(self._ptr, _address(view),
+                                         view.nbytes)
+        if got != self.nbytes:
+            raise ValueError(f"copied {got} of {self.nbytes} bytes")
+
+    def reply(self, body: np.ndarray,
+              tail: np.ndarray | None = None) -> None:
+        ptr, self._ptr = self._ptr, None
+        if not ptr:
+            raise ValueError("the call has been answered already")
+        body = body.reshape(-1).view(np.uint8)
+        lib().tpurpc_call_reply(
+            ptr, _address(body), body.nbytes,
+            None if tail is None else _address(tail),
+            0 if tail is None else tail.nbytes)
+
+    def fail(self, code: int, text: str) -> None:
+        ptr, self._ptr = self._ptr, None
+        if ptr:
+            lib().tpurpc_call_fail(ptr, code, text.encode())
+
+    def __del__(self):
+        # A call dropped unanswered would hold the server's Join forever.
+        self.fail(TERR_INTERNAL, "the call was dropped unanswered")
+
+
+class PullServer:
+    """The C API's pull server (cpp/trpc/c_api.h): tensorpb.Tensor/Step on
+    a Server inside this process, listening on 127.0.0.1 (TCP, the shm
+    link after its handshake, and the builtin portal). `take` blocks in
+    C++ with the interpreter lock released."""
+
+    def __init__(self, port: int = 0):
+        self._ptr = lib().tpurpc_server_start(port)
+        if not self._ptr:
+            raise RuntimeError(f"tpurpc_server_start({port}) failed")
+        self.port = int(lib().tpurpc_server_port(self._ptr))
+
+    def take(self, timeout_us: int = -1) -> ParkedCall | None:
+        """The next parked call; None on timeout; ServerClosedError once
+        the queue is closed."""
+        nbytes, status = ctypes.c_size_t(), ctypes.c_int()
+        ptr = lib().tpurpc_server_take(self._ptr, timeout_us,
+                                       ctypes.byref(nbytes),
+                                       ctypes.byref(status))
+        if ptr:
+            return ParkedCall(ptr, int(nbytes.value))
+        if status.value == -2:
+            raise ServerClosedError("the pull server's queue is closed")
+        return None
+
+    def close_queue(self, code: int = TERR_CLOSE) -> None:
+        """Fail what is parked and what arrives from now on with `code`;
+        unblock every `take`."""
+        if self._ptr:
+            lib().tpurpc_server_close_queue(self._ptr, code)
+
+    def stop(self) -> None:
+        """close_queue, Stop, Join (waits until every taken call has been
+        answered), free."""
+        ptr, self._ptr = self._ptr, None
+        if ptr:
+            lib().tpurpc_server_stop(ptr)
+
+
+class StepChannel:
+    """One blocking client of tensorpb.Tensor/Step (tests, the rehearsal,
+    chip_smoke.py): attachment in, attachment out, no retry."""
+
+    def __init__(self, port: int, ici: bool = False,
+                 timeout_ms: int = 10000, host: str = "127.0.0.1"):
+        self.timeout_ms = timeout_ms
+        self._ptr = lib().tpurpc_channel_open(host.encode(), port, int(ici),
+                                              timeout_ms)
+        if not self._ptr:
+            raise RuntimeError(f"no channel to {host}:{port} (ici={ici})")
+
+    def call(self, request: np.ndarray, reply_cap: int | None = None,
+             timeout_ms: int | None = None) -> np.ndarray:
+        request = np.ascontiguousarray(request).reshape(-1).view(np.uint8)
+        cap = request.nbytes + 64 if reply_cap is None else reply_cap
+        out = np.empty(cap, dtype=np.uint8)
+        got = ctypes.c_size_t()
+        err = ctypes.create_string_buffer(256)
+        rc = lib().tpurpc_channel_call(
+            self._ptr, _address(request), request.nbytes, _address(out),
+            cap, ctypes.byref(got),
+            self.timeout_ms if timeout_ms is None else timeout_ms, err,
+            len(err))
+        if rc != 0:
+            raise RpcError(rc, err.value.decode("utf-8", "replace"))
+        if got.value > cap:
+            raise ValueError(f"reply of {got.value} bytes, room for {cap}")
+        return out[:got.value]
+
+    def close(self) -> None:
+        ptr, self._ptr = self._ptr, None
+        if ptr:
+            lib().tpurpc_channel_close(ptr)
 
 
 def _within(buf: np.ndarray, payload: np.ndarray) -> bool:

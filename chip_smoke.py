@@ -5,7 +5,7 @@
 
 Drives the main path once through the entry points a user would call, at
 the sizes BASELINE.json's configs name, on every chip `jax.devices()`
-reports. Four legs, one JSON line each (every line names the platform,
+reports. Five legs, one JSON line each (every line names the platform,
 device kind and device count it ran beside), then one last line:
 
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
@@ -21,6 +21,10 @@ device kind and device count it ran beside), then one last line:
               staging ring in 1 MiB-class chunks (>= 8 passes) and in 4
               MiB-class chunks; every chunk's crc32c against the C++
               framer's, every on-device integrity word against numpy.
+- tensor_echo brpc_tpu.tensor_service served in this process on device 0:
+              16 calls of tensor.Step at 1 MiB over the shm link, each
+              reply (made on the chip) against brpc_tpu.tensor_reference
+              (`tensor_echo_ok`).
 - collective  __graft_entry__.mesh_data_plane over Mesh(jax.devices()):
               fan-out rows of 4 KiB and 1 MiB, partition shards, all-reduce
               / all-gather / all-to-all at 4 MiB and 64 MiB per rank, framed
@@ -320,6 +324,59 @@ def leg_device() -> dict:
     return {"chips": chips, "note": "GB/s are observations"}
 
 
+def leg_tensor_echo() -> dict:
+    """tensor.Step served in-process on device 0 (ISSUE 29): 16 calls of
+    1 MiB from 4 caller threads over the shm link, every reply held to the
+    plain reference. The device leg of a served call, from a bare
+    checkout."""
+    import threading
+
+    import jax
+    import numpy as np
+
+    from brpc_tpu import native, tensor_reference, tensor_service
+
+    key, nbytes, callers, each = 0x5EED1E57, 1 << 20, 4, 4
+    dev = jax.devices()[0]
+    service = tensor_service.serve(dev, depth=4, max_bytes=nbytes, key=key)
+    wrong, errors = [], []
+
+    def caller(c):
+        try:
+            channel = native.StepChannel(service.port, ici=True)
+            try:
+                rng = np.random.default_rng(1000 + c)
+                for n in range(each):
+                    x = rng.integers(0, 256, nbytes, dtype=np.uint8)
+                    got = channel.call(x).tobytes()
+                    if got != tensor_reference.step(x, key):
+                        wrong.append((c, n))
+            finally:
+                channel.close()
+        except Exception as e:  # reported by the leg, on its thread
+            errors.append(f"caller {c}: {type(e).__name__}: {e}")
+
+    t0 = time.monotonic()
+    try:
+        threads = [threading.Thread(target=caller, args=(c,))
+                   for c in range(callers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    finally:
+        service.close()
+    if errors or wrong or service.failure is not None:
+        raise LegFailed(f"tensor.Step on {dev}: errors {errors}, replies "
+                        f"that differ from the reference {wrong}, service "
+                        f"failure {service.failure!r}")
+    if dev.platform != "tpu":
+        raise LegFailed(f"tensor.Step ran on {dev}")
+    return {"tensor_echo_ok": True, "calls": callers * each,
+            "bytes_each": nbytes, "device": str(dev),
+            "seconds_calls": round(time.monotonic() - t0, 2)}
+
+
 def leg_collective() -> dict:
     import jax
     import numpy as np
@@ -357,7 +414,8 @@ def leg_collective() -> dict:
 
 
 LEGS = (("build", 900, leg_build), ("served", 600, leg_served),
-        ("device", 400, leg_device), ("collective", 400, leg_collective))
+        ("device", 400, leg_device), ("tensor_echo", 200, leg_tensor_echo),
+        ("collective", 400, leg_collective))
 
 
 # ---------------------------------------------------------------- runner

@@ -1,17 +1,30 @@
 #include "trpc/c_api.h"
 
+#include <atomic>
+#include <chrono>
+#include <condition_variable>
 #include <cstring>
+#include <deque>
+#include <mutex>
 #include <string>
 
 #include "rpc_meta.pb.h"
 #include "tbase/crc32c.h"
+#include "tbase/endpoint.h"
+#include "tbase/errno.h"
+#include "tbase/flags.h"
 #include "tbase/iobuf.h"
 #include "tici/block_lease.h"
 #include "tici/block_pool.h"
 #include "tici/verbs.h"
+#include "tensor.pb.h"
 #include "tnet/transport.h"
+#include "trpc/channel.h"
+#include "trpc/controller.h"
 #include "trpc/pb_compat.h"
 #include "trpc/policy_tpu_std.h"
+#include "trpc/server.h"
+#include "tvar/reducer.h"
 #include "tvar/stage_recorder.h"
 
 namespace {
@@ -199,6 +212,273 @@ long tpurpc_stage_dump(char* out, size_t cap) {
 uint64_t tpurpc_ring_inflight_highwater(void* ring) {
     return ((tpurpc::DeviceStagingRing*)ring)->inflight_highwater();
 }
+
+// ---- pull server and blocking client (ISSUE 29) ----
+
+namespace {
+
+using tpurpc::stage::now_us;
+
+tpurpc::LazyAdder g_tensor_calls("rpc_tensor_calls");
+tpurpc::LazyAdder g_tensor_bytes_in("rpc_tensor_bytes_in");
+tpurpc::LazyAdder g_tensor_failed("rpc_tensor_failed");
+std::atomic<int64_t> g_parked_highwater{0};
+
+int64_t ParkedHighwater(void*) {
+    return g_parked_highwater.load(std::memory_order_relaxed);
+}
+
+// A call between its handler and its answer: what `done` needs, and the
+// stamp tdev.take_wait starts from.
+class PullServer;
+struct ParkedCall {
+    tpurpc::Controller* cntl;
+    google::protobuf::Closure* done;
+    int64_t parked_us;
+    PullServer* owner;  // told when a TAKEN call is answered
+};
+
+void FailCall(ParkedCall* call, int code, const char* text) {
+    call->cntl->SetFailed(code, "%s", text);
+    *g_tensor_failed << 1;
+    call->done->Run();
+    delete call;
+}
+
+// tensorpb.Tensor/Step: stamp, park, return.
+class PullServer : public tensorpb::Tensor {
+public:
+    void Step(google::protobuf::RpcController* cntl_base,
+              const tensorpb::StepRequest* request,
+              tensorpb::StepResponse* response,
+              google::protobuf::Closure* done) override {
+        auto* cntl = static_cast<tpurpc::Controller*>(cntl_base);
+        response->set_send_ts_us(request->send_ts_us());
+        *g_tensor_bytes_in << (int64_t)cntl->request_attachment().size();
+        auto* call = new ParkedCall{cntl, done, now_us(), this};
+        int closed_code = 0;
+        {
+            std::lock_guard<std::mutex> g(mu_);
+            if (closed_code_ != 0) {
+                closed_code = closed_code_;
+            } else {
+                parked_.push_back(call);
+                const int64_t n = (int64_t)parked_.size();
+                if (n > g_parked_highwater.load(std::memory_order_relaxed)) {
+                    g_parked_highwater.store(n, std::memory_order_relaxed);
+                }
+            }
+        }
+        if (closed_code != 0) {
+            FailCall(call, closed_code, "tensor service is closed");
+            return;
+        }
+        cv_.notify_one();
+    }
+
+    ParkedCall* Take(long timeout_us, int* status) {
+        std::unique_lock<std::mutex> lk(mu_);
+        const auto ready = [this] {
+            return closed_code_ != 0 || !parked_.empty();
+        };
+        if (timeout_us < 0) {
+            cv_.wait(lk, ready);
+        } else if (!cv_.wait_for(lk, std::chrono::microseconds(timeout_us),
+                                 ready)) {
+            *status = 0;
+            return nullptr;
+        }
+        if (parked_.empty()) {  // closed: what was parked has been failed
+            *status = -2;
+            return nullptr;
+        }
+        ParkedCall* call = parked_.front();
+        parked_.pop_front();
+        ++taken_;
+        return call;
+    }
+
+    // A taken call has been answered (reply or fail). True: that was the
+    // last one of a server already stopped, and the caller frees it.
+    bool Answered() {
+        std::lock_guard<std::mutex> g(mu_);
+        return --taken_ == 0 && stopped_;
+    }
+
+    // Stop + Join are over. True: no taken call is left that would call
+    // Answered, and the caller frees the server; else the last one does.
+    bool Stopped() {
+        std::lock_guard<std::mutex> g(mu_);
+        stopped_ = true;
+        return taken_ == 0;
+    }
+
+    void CloseQueue(int code) {
+        std::deque<ParkedCall*> orphans;
+        {
+            std::lock_guard<std::mutex> g(mu_);
+            if (closed_code_ == 0) closed_code_ = code != 0 ? code : -1;
+            orphans.swap(parked_);
+        }
+        cv_.notify_all();
+        for (ParkedCall* call : orphans) {
+            FailCall(call, closed_code_, "tensor service closed with the "
+                                         "call parked");
+        }
+    }
+
+    tpurpc::Server server;
+
+private:
+    std::mutex mu_;
+    std::condition_variable cv_;
+    std::deque<ParkedCall*> parked_;
+    int64_t taken_ = 0;    // taken and not answered yet
+    int closed_code_ = 0;  // != 0: closed, and what new calls fail with
+    bool stopped_ = false;  // tpurpc_server_stop is through with it
+};
+
+struct ClientChannel {
+    tpurpc::Channel channel;
+    tensorpb::Tensor_Stub stub{&channel};
+};
+
+}  // namespace
+
+void* tpurpc_server_start(int port) {
+    if (tpurpc_global_init() != 0) return nullptr;
+    // In /vars from the first scrape, before the first call.
+    *g_tensor_calls << 0;
+    *g_tensor_bytes_in << 0;
+    *g_tensor_failed << 0;
+    static auto* highwater = [] {
+        auto* v = new tpurpc::PassiveStatus<int64_t>(ParkedHighwater,
+                                                     nullptr);
+        v->expose("rpc_tensor_parked_highwater");
+        return v;
+    }();
+    (void)highwater;
+    auto* ps = new PullServer;
+    tpurpc::EndPoint listen;
+    tpurpc::str2endpoint("127.0.0.1", port, &listen);
+    if (ps->server.AddService(ps) != 0 ||
+        ps->server.Start(listen, nullptr) != 0) {
+        delete ps;
+        return nullptr;
+    }
+    return ps;
+}
+
+int tpurpc_server_port(void* server) {
+    return ((PullServer*)server)->server.listened_port();
+}
+
+void* tpurpc_server_take(void* server, long timeout_us, size_t* len,
+                         int* status) {
+    int st = 0;
+    ParkedCall* call = ((PullServer*)server)->Take(timeout_us, &st);
+    if (status != nullptr) *status = st;
+    if (call == nullptr) return nullptr;
+    tpurpc::stage::Add(tpurpc::stage::kTakeWait,
+                       now_us() - call->parked_us);
+    if (len != nullptr) *len = call->cntl->request_attachment().size();
+    return call;
+}
+
+void tpurpc_server_close_queue(void* server, int code) {
+    ((PullServer*)server)->CloseQueue(code);
+}
+
+void tpurpc_server_stop(void* server) {
+    auto* ps = (PullServer*)server;
+    ps->CloseQueue(tpurpc::TERR_CLOSE);
+    // Stop closes the connections, and an answer not yet written would be
+    // lost with them: the drain waits (bounded: a taker that never answers
+    // must not hang it; Join then waits with the connection gone) until
+    // every taken call is answered and the write queues are flushed.
+    ps->server.GracefulStop(10 * 1000);
+    // Join returns once every `done` has run; the thread that ran the last
+    // one may still be on its way into Answered.
+    if (ps->Stopped()) delete ps;
+}
+
+long tpurpc_call_copy_out(void* call, void* dst, size_t cap) {
+    return (long)((ParkedCall*)call)->cntl->request_attachment().copy_to(
+        dst, cap);
+}
+
+int tpurpc_flag_set(const char* name, const char* value) {
+    return tpurpc::SetFlagValue(name, value) ? 0 : -1;
+}
+
+void tpurpc_tensor_step_answered(void) { *g_tensor_calls << 1; }
+
+int tpurpc_call_reply(void* handle, const void* body, size_t n,
+                      const void* tail, size_t tail_n) {
+    auto* call = (ParkedCall*)handle;
+    PullServer* owner = call->owner;
+    const int64_t entered_us = now_us();
+    tpurpc::IOBuf& att = call->cntl->response_attachment();
+    if (n > 0) att.append(body, n);
+    if (tail_n > 0) att.append(tail, tail_n);
+    call->done->Run();  // trpc.handler ends, the reply is enqueued
+    tpurpc::stage::Add(tpurpc::stage::kDevReply, now_us() - entered_us);
+    delete call;
+    if (owner->Answered()) delete owner;
+    return 0;
+}
+
+int tpurpc_call_fail(void* handle, int code, const char* text) {
+    PullServer* owner = ((ParkedCall*)handle)->owner;
+    FailCall((ParkedCall*)handle, code != 0 ? code : -1,
+             text != nullptr ? text : "failed");
+    if (owner->Answered()) delete owner;
+    return 0;
+}
+
+void* tpurpc_channel_open(const char* host, int port, int ici,
+                          long timeout_ms) {
+    if (tpurpc_global_init() != 0) return nullptr;
+    tpurpc::EndPoint ep;
+    if (tpurpc::str2endpoint(host, port, &ep) != 0) return nullptr;
+    tpurpc::ChannelOptions opts;
+    opts.timeout_ms = timeout_ms;
+    opts.max_retry = 0;
+    auto* cc = new ClientChannel;
+    const int rc = ici ? cc->channel.InitIci(ep, &opts)
+                       : cc->channel.Init(ep, &opts);
+    if (rc != 0) {
+        delete cc;
+        return nullptr;
+    }
+    return cc;
+}
+
+int tpurpc_channel_call(void* channel, const void* req, size_t n, void* out,
+                        size_t cap, size_t* out_len, long timeout_ms,
+                        char* err, size_t err_cap) {
+    auto* cc = (ClientChannel*)channel;
+    tpurpc::Controller cntl;
+    cntl.set_timeout_ms(timeout_ms);
+    cntl.set_max_retry(0);
+    tensorpb::StepRequest request;
+    tensorpb::StepResponse response;
+    request.set_send_ts_us(now_us());
+    cntl.request_attachment().append(req, n);
+    cc->stub.Step(&cntl, &request, &response, nullptr);
+    if (cntl.Failed()) {
+        if (err != nullptr && err_cap > 0) {
+            snprintf(err, err_cap, "%s", cntl.ErrorText().c_str());
+        }
+        return cntl.ErrorCode() != 0 ? cntl.ErrorCode() : -1;
+    }
+    const tpurpc::IOBuf& att = cntl.response_attachment();
+    if (out_len != nullptr) *out_len = att.size();
+    if (out != nullptr && cap > 0) att.copy_to(out, cap);
+    return 0;
+}
+
+void tpurpc_channel_close(void* channel) { delete (ClientChannel*)channel; }
 
 namespace {
 

@@ -109,6 +109,71 @@ long tpurpc_transport_tier_sgl_max(int tier);
 // completes; bytes for socket-attached tiers).
 long tpurpc_transport_tier_ops(int tier);
 
+// ---- pull server and blocking client (ISSUE 29) ----
+// The process that holds the chip is the Python/JAX one, so a replica is a
+// Server INSIDE that process. It hosts tensorpb.Tensor/Step (attachment
+// in, attachment out, tpu_std) on the listener `echo_bench --ici-server`
+// opens (TCP handshake, then the shm queue pair; builtin portal on the
+// same port). The method's handler only stamps and PARKS the call and
+// returns: no fiber worker ever runs the caller's code or waits for its
+// interpreter lock. The process pulls parked calls with
+// tpurpc_server_take and answers each from whichever thread it likes.
+//
+// Stages (tvar/stage_recorder.h), both inside/around trpc.handler:
+// tdev.take_wait = handler entered -> taken; tdev.reply = reply entered ->
+// reply enqueued on the socket. /vars: rpc_tensor_calls (steps whose result
+// the device gave back: tpurpc_tensor_step_answered), rpc_tensor_bytes_in,
+// rpc_tensor_failed, rpc_tensor_parked_highwater.
+//
+// Set one of the framework's flags (tbase/flags.h; what /flags lists) by
+// name, e.g. socket_send_buffer_size: the embedding process's to choose,
+// before it starts a server or opens a channel. 0, or -1 where the flag
+// is unknown or refuses the value.
+int tpurpc_flag_set(const char* name, const char* value);
+// Starts the server on 127.0.0.1:`port` (0 = ephemeral). NULL on failure.
+void* tpurpc_server_start(int port);
+int tpurpc_server_port(void* server);
+// Blocks up to timeout_us (<0 = until a call or the close) for a parked
+// call. Returns its handle and sets *len to the request attachment's
+// length; NULL with *status 0 on timeout, -2 once the queue is closed.
+void* tpurpc_server_take(void* server, long timeout_us, size_t* len,
+                         int* status);
+// Close the queue: every parked call fails with `code`, every later call
+// fails on arrival, every parked and later take returns (-2). Calls
+// already taken stay the taker's to answer. Idempotent.
+void tpurpc_server_close_queue(void* server, int code);
+// close_queue, then Server::GracefulStop (waits, bounded, until every
+// taken call is answered and the write queues are flushed; then Stop +
+// Join), then frees the server, or leaves that to the answer of the last
+// taken call where one is still on its way.
+void tpurpc_server_stop(void* server);
+// Copy the request attachment into dst[0..cap) in one pass; returns the
+// bytes copied (the attachment's length when cap is enough).
+long tpurpc_call_copy_out(void* call, void* dst, size_t cap);
+// Answer: the response attachment is body ‖ tail (one copy each; tail may
+// be NULL/0). Runs `done` on the calling thread and frees the handle.
+int tpurpc_call_reply(void* call, const void* body, size_t n,
+                      const void* tail, size_t tail_n);
+// rpc_tensor_calls += 1. The service calls it where the D2H of a step's
+// result came back, before it answers with it; an answer made any other
+// way never passes here.
+void tpurpc_tensor_step_answered(void);
+// Fail the call with `code` (a TERR_* or any non-zero int) and frees the
+// handle.
+int tpurpc_call_fail(void* call, int code, const char* text);
+
+// One blocking client of that service: `ici` != 0 pins the channel to the
+// shm link (Channel::InitIci), else plain TCP. NULL on failure.
+void* tpurpc_channel_open(const char* host, int port, int ici,
+                          long timeout_ms);
+// Step(attachment) -> attachment, synchronous, no retry. Returns 0 and
+// sets *out_len (the reply's length; copied into out only up to cap), or
+// the call's error code with its text in err[0..err_cap).
+int tpurpc_channel_call(void* channel, const void* req, size_t n, void* out,
+                        size_t cap, size_t* out_len, long timeout_ms,
+                        char* err, size_t err_cap);
+void tpurpc_channel_close(void* channel);
+
 // Frame `payload` as one tpu_std frame: "TRPC" header + RpcMeta
 // {correlation_id, body_checksum=crc32c(payload)} + payload as raw
 // attachment. Writes into out[0..out_cap). Returns the frame size in

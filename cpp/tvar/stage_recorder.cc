@@ -61,7 +61,8 @@ const char* const kNames[kCount] = {
     "tici.link_handoff",  "tnet.consume_to_cut", "tfiber.dispatch_to_handler",
     "trpc.handler",       "trpc.respond",        "tnet.write_queue",
     "trpc.issue",         "trpc.match",          "trpc.caller_wake",
-    "tfiber.wake_to_run", "test.only",
+    "tfiber.wake_to_run", "tdev.take_wait",      "tdev.reply",
+    "test.only",
 };
 
 // Immortal: worker threads sample (and exit) after static destruction.

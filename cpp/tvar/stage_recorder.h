@@ -46,6 +46,8 @@ enum Id : int {
     kMatch,               // trpc.match (client)
     kCallerWake,          // trpc.caller_wake (client, synchronous calls)
     kWakeToRun,           // tfiber.wake_to_run
+    kTakeWait,            // tdev.take_wait (inside trpc.handler)
+    kDevReply,            // tdev.reply (ends past trpc.handler's end)
     kPublished,           // the dumps show the stages above
     kTestOnly = kPublished,  // unit tests sample this one; never shown
     kCount,

@@ -1,0 +1,258 @@
+"""`device_path.DeviceLane` (ISSUE 29): the long-lived lane a served handler
+submits to, and `_ChunkPipeline.run` as a loop over `submit` on one -- the
+ring cell and the served call run one piece of code. CPU backend; the
+spans, words and crc verdicts are held to what `_ChunkPipeline` gave
+before the lane existed (the assertions of test_device_path_spans /
+test_device_path_threads, reused)."""
+import signal
+import threading
+
+import numpy as np
+import pytest
+from test_device_path_spans import LAUNCH_CHILDREN, RETIRE_CHILDREN
+
+from brpc_tpu import spans, tensor_reference
+
+CHUNK_BYTES, N_CHUNKS, DEPTH = 64 << 10, 6, 3
+LIMIT_S = 60
+
+
+@pytest.fixture(autouse=True)
+def time_limit():
+    def expired(signum, frame):
+        raise TimeoutError(f"the test ran over {LIMIT_S} s: a thread hangs")
+
+    before = signal.signal(signal.SIGALRM, expired)
+    signal.alarm(LIMIT_S)
+    yield
+    signal.alarm(0)
+    signal.signal(signal.SIGALRM, before)
+
+
+@pytest.fixture
+def parts(cpp_build):
+    """(device_path, ring, dev, kernel, chunks), compiled and warm."""
+    import jax
+
+    from brpc_tpu import device_path, native
+
+    dev = jax.devices("cpu")[0]
+    per = CHUNK_BYTES // 4
+    words = np.arange(N_CHUNKS * per, dtype=np.uint32) * np.uint32(2654435761)
+    chunks = [words[i * per:(i + 1) * per] for i in range(N_CHUNKS)]
+    kernel = device_path._touch_kernel(per, dev.platform)
+    ring = native.DeviceStagingRing(DEPTH, CHUNK_BYTES + 1024)
+    device_path._ChunkPipeline(ring, chunks, dev, kernel, DEPTH,
+                               False).run(1)  # compile, first transfers
+    spans.clear()
+    yield device_path, ring, dev, kernel, chunks
+    ring.close()
+
+
+def host_words(device_path, chunks, passes):
+    return [device_path._integrity_word_host(c) for c in chunks] * passes
+
+
+def filler(chunk):
+    return lambda view: np.copyto(view.view(np.uint32), chunk)
+
+
+def test_a_pass_is_a_loop_over_submit_on_a_lane(parts, monkeypatch):
+    device_path, ring, dev, kernel, chunks = parts
+    lanes, submits = [], []
+    real_init = device_path.DeviceLane.__init__
+    real_submit = device_path.DeviceLane.submit
+
+    def init(self, *a, **kw):
+        lanes.append(self)
+        real_init(self, *a, **kw)
+
+    def submit(self, fill, nbytes, token, correlation_id=1):
+        submits.append((token, nbytes, correlation_id))
+        real_submit(self, fill, nbytes, token, correlation_id)
+
+    monkeypatch.setattr(device_path.DeviceLane, "__init__", init)
+    monkeypatch.setattr(device_path.DeviceLane, "submit", submit)
+    pipe = device_path._ChunkPipeline(ring, chunks, dev, kernel, DEPTH, False)
+    threads = threading.active_count()
+    pipe.run(2)
+    assert threading.active_count() == threads  # the lane lived inside run()
+    assert len(lanes) == 1 and lanes[0].failure is None
+    assert submits == [((p, k), CHUNK_BYTES, k + 1)
+                       for p in (1, 2) for k in range(N_CHUNKS)]
+    # What the pipeline gave before the lane: words in launch order, every
+    # crc verdict good, the ring's window kept.
+    assert pipe.ok and pipe.dev_checks == host_words(device_path, chunks, 2)
+    assert ring.inflight_highwater <= DEPTH
+
+
+def test_run_over_the_lane_gives_the_spans_it_gave_before(parts):
+    device_path, ring, dev, kernel, chunks = parts
+    pipe = device_path._ChunkPipeline(ring, chunks, dev, kernel, DEPTH, False)
+    pipe.run(1)
+    me = threading.get_ident()
+    by_name = {}
+    for name, start, end, request, thread in spans.snapshot():
+        by_name.setdefault(name, []).append((request, thread, start, end))
+    assert set(by_name) == (LAUNCH_CHILDREN | RETIRE_CHILDREN
+                            | {"ring.launch", "ring.retire", "ring.pass",
+                               "ring.drain"})
+    for name in LAUNCH_CHILDREN | {"ring.launch", "ring.pass", "ring.drain"}:
+        assert {t for _, t, _, _ in by_name[name]} == {me}, name
+    (completions,) = {t for _, t, _, _ in by_name["ring.retire"]}
+    assert completions != me
+    for name in RETIRE_CHILDREN:
+        assert {t for _, t, _, _ in by_name[name]} == {completions}, name
+    for name in LAUNCH_CHILDREN | RETIRE_CHILDREN | {"ring.launch",
+                                                     "ring.retire"}:
+        assert [r for r, *_ in by_name[name]] == [
+            (1, k) for k in range(N_CHUNKS)], name
+    # The drain is the close of the lane, under a ring.pass of its own.
+    (_, _, d0, d1), = by_name["ring.drain"]
+    assert any(s <= d0 and d1 <= e for _, _, s, e in by_name["ring.pass"])
+
+
+def test_a_lane_survives_three_passes_worth_of_submits(parts):
+    device_path, ring, dev, kernel, chunks = parts
+    done = []
+    threads = threading.active_count()
+    lane = device_path.DeviceLane(
+        ring, dev, kernel, DEPTH,
+        lambda token, back, word, good: done.append(
+            (token, word, good, bytes(back.view(np.uint8)[:16]))))
+    assert threading.active_count() == threads + 1
+    completions = lane._completions
+    for p in range(3):
+        for k, chunk in enumerate(chunks):
+            lane.submit(filler(chunk), CHUNK_BYTES, (p, k), k + 1)
+        assert lane._completions is completions and completions.is_alive()
+        assert threading.active_count() == threads + 1  # never re-started
+    lane.close()
+    assert threading.active_count() == threads and lane.failure is None
+    assert [t for t, *_ in done] == [(p, k) for p in range(3)
+                                     for k in range(N_CHUNKS)]
+    assert [w for _, w, _, _ in done] == host_words(device_path, chunks, 3)
+    assert all(good for _, _, good, _ in done)
+    assert [head for *_, head in done] == [
+        bytes(c.view(np.uint8)[:16]) for c in chunks] * 3
+    assert len({rec[4] for rec in spans.snapshot()
+                if rec[0] == "ring.retire"}) == 1
+    assert ring.inflight_highwater <= DEPTH
+
+
+def test_requests_of_other_sizes_share_one_lane(parts):
+    """Independent requests: each submit brings its own size and token."""
+    device_path, ring, dev, _, chunks = parts
+    key = 0x0F0F1234
+    kernel = device_path._tensor_step_kernel(key, dev.platform)
+    got = {}
+    lane = device_path.DeviceLane(
+        ring, dev, kernel, DEPTH,
+        lambda token, back, word, good: got.__setitem__(
+            token, back.tobytes() + int(word).to_bytes(4, "little")),
+        verify=False)
+    sent = {}
+
+    def filling(x, token):
+        def fill(view):  # the caller's own span around its copy
+            with spans.span("tensor.fill", token):
+                np.copyto(view, x)
+        return fill
+
+    for token, nbytes in enumerate([16, 4096, CHUNK_BYTES, 40, 4096]):
+        x = np.random.default_rng(token).integers(0, 256, nbytes,
+                                                  dtype=np.uint8)
+        sent[token] = x
+        lane.submit(filling(x, token), nbytes, token)
+    lane.close()
+    assert got == {t: tensor_reference.step(x, key) for t, x in sent.items()}
+    names = {rec[0] for rec in spans.snapshot()}
+    assert "tensor.fill" in names and "ring.stage" not in names
+    assert "ring.verify" not in names  # not the identity: no crc to hold
+    # The caller's span lies inside the lane's ring.launch of that request.
+    by = {(rec[0], rec[3]): rec for rec in spans.snapshot()}
+    for token in sent:
+        _, f0, f1, _, thread = by["tensor.fill", token]
+        _, l0, l1, _, launcher = by["ring.launch", token]
+        assert l0 <= f0 and f1 <= l1 and thread == launcher
+
+
+def test_depth_one_retires_inside_submit_on_the_callers_thread(parts):
+    device_path, ring, dev, kernel, chunks = parts
+    done = []
+    threads = threading.active_count()
+    lane = device_path.DeviceLane(
+        ring, dev, kernel, 1, lambda token, *rest: done.append(token))
+    assert threading.active_count() == threads
+    for k, chunk in enumerate(chunks):
+        lane.submit(filler(chunk), CHUNK_BYTES, k)
+        assert done[-1] == k
+    lane.close()
+    assert {rec[4] for rec in spans.snapshot()} == {threading.get_ident()}
+
+
+@pytest.mark.parametrize("hold_the_retire", [False, True],
+                         ids=["as_it_comes", "launcher_runs_ahead"])
+def test_an_error_behind_the_launcher_abandons_what_follows(
+        parts, monkeypatch, hold_the_retire):
+    """Whichever thread is ahead: with the launcher as far ahead as the
+    credits let it be, nothing is left of them when the retire fails, and
+    the submits after it still meet the aborted ring at once (the timeout
+    is set longer than the test's own limit)."""
+    from brpc_tpu import native
+
+    device_path, ring, dev, kernel, chunks = parts
+    monkeypatch.setattr(device_path, "ACQUIRE_TIMEOUT_US",
+                        2 * LIMIT_S * 1_000_000)
+    go = threading.Event()
+    if not hold_the_retire:
+        go.set()
+
+    class NeverComesBack:
+        def __array__(self, *args, **kwargs):
+            go.wait()
+            raise OSError("the copy back failed")
+
+    calls = []
+
+    def failing(x):
+        y, w = kernel(x)
+        calls.append(1)
+        if len(calls) == DEPTH + 1:  # the launcher has used every credit
+            go.set()
+        return (NeverComesBack() if len(calls) == 2 else y), w
+
+    done, abandoned = [], []
+    lane = device_path.DeviceLane(
+        ring, dev, failing, DEPTH, lambda token, *rest: done.append(token),
+        on_abandon=abandoned.append)
+    launched = []
+    with pytest.raises(native.RingAbortedError):
+        for k, chunk in enumerate(chunks):
+            lane.submit(filler(chunk), CHUNK_BYTES, k)
+            launched.append(k)
+    lane.close()
+    if hold_the_retire:
+        assert len(launched) == DEPTH + 1
+    assert isinstance(lane.failure, OSError) and ring.aborted
+    # Every chunk handed over was answered one way or the other, in order.
+    assert done == [0] and abandoned == launched[1:]
+    with pytest.raises(native.RingAbortedError):
+        lane.submit(filler(chunks[0]), CHUNK_BYTES, 99)
+
+
+@pytest.mark.parametrize("nbytes", [16, 24, 4096, 65544, 1048576])
+def test_tensor_step_kernel_is_the_reference(cpp_build, nbytes):
+    import jax
+
+    from brpc_tpu import device_path
+
+    key = 0xDEADBEEF
+    kernel = device_path._tensor_step_kernel(key, "cpu")
+    assert kernel.__wrapped__.__name__ == "tensor_step"  # jit_tensor_step
+    x = np.random.default_rng(nbytes).integers(0, 256, nbytes,
+                                               dtype=np.uint8)
+    y, w = kernel(jax.device_put(x.view(np.uint32), jax.devices("cpu")[0]))
+    got = np.asarray(y).tobytes() + int(w).to_bytes(4, "little")
+    assert got == tensor_reference.step(x, key)
+    assert np.asarray(y)[:2].tobytes() == x[:8].tobytes()

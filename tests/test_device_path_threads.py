@@ -29,13 +29,15 @@ def time_limit():
 
 
 @pytest.fixture
-def make_pipeline(cpp_build):
+def make_pipeline(cpp_build, monkeypatch):
     """make_pipeline(depth, touch=None, copy_mode=False, ring_depth=3) ->
     (pipeline, ring, chunks) on the CPU backend, compiled and warm."""
     import jax
 
     from brpc_tpu import device_path, native
 
+    # A wedge ends inside the test's limit.
+    monkeypatch.setattr(device_path, "ACQUIRE_TIMEOUT_US", 10_000_000)
     dev = jax.devices("cpu")[0]
     per = CHUNK_BYTES // 4
     words = np.arange(N_CHUNKS * per, dtype=np.uint32) * np.uint32(2654435761)
@@ -52,7 +54,6 @@ def make_pipeline(cpp_build):
         pipe = device_path._ChunkPipeline(
             ring, chunks, dev, kernel if touch is None else touch(kernel),
             depth, copy_mode)
-        pipe.ACQUIRE_TIMEOUT_US = 10_000_000  # a wedge ends inside the limit
         spans.clear()
         return pipe, ring, chunks
 

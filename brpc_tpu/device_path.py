@@ -4,8 +4,10 @@ a pipelined DMA staging ring.
 PR-8 (ISSUE 9) rebuilt this module around `DeviceStagingRing`
 (cpp/tici/block_pool.cc, exported through cpp/trpc/c_api.cc): the
 payload is cut into chunks, each chunk staged into a depth-N ring of
-registered pool slots and framed IN PLACE by the C++ framework (header
-+ meta written right before the payload — no payload memcpy,
+registered pool slots by ONE pass over its bytes that also computes
+their crc32c (brpc_tpu/native.copy_crc32c; ISSUE 30) and framed IN
+PLACE by the C++ framework from that crc (header + meta written right
+before the payload — no payload memcpy and no second pass over it,
 brpc_tpu/native.frame_in_place), so that H2D of chunk i+1, the
 on-device integrity kernel on chunk i, and D2H + crc32c verification of
 chunk i-1 overlap. That is the transport seam the reference's RDMA
@@ -46,7 +48,7 @@ import numpy as np
 
 # In-place frame headroom per slot (importing brpc_tpu.native does NOT
 # load the shared library — that happens lazily at the first call).
-from brpc_tpu import spans
+from brpc_tpu import native, spans
 from brpc_tpu.native import IN_PLACE_HEADROOM as HEADROOM
 
 # Never park forever on the ring (ISSUE 10c): 30s >> any sane per-chunk
@@ -157,12 +159,16 @@ class DeviceLane:
     are submitted one at a time and each is answered when its D2H is back.
 
     `submit(fill, nbytes, token)` runs on the caller's thread (the
-    launcher): a credit and a ring slot, `fill(view)` stages the bytes
-    into the slot, the C++ framer frames them in place, H2D, the jitted
-    `kernel(x) -> (y, word)`, and the request for both results' copies
-    back; then the chunk is handed to the lane's completion thread, which
-    waits for the D2H, checks crc32c of the returned bytes against the
-    framer's where `verify` is set (a kernel that is the identity), calls
+    launcher): a credit and a ring slot; `fill(view)` stages `nbytes`
+    into the slot and returns their crc32c, both from one pass over the
+    bytes (native.copy_crc32c, ParkedCall.copy_into: there is no other
+    kind of fill, and one that returns nothing is a TypeError); the C++
+    framer writes header + meta around that crc and never reads the
+    payload; H2D, the jitted `kernel(x) -> (y, word)`, and the request for
+    both results' copies back; then the chunk is handed to the lane's
+    completion thread, which waits for the D2H, checks crc32c of the
+    returned bytes against the frame's where `verify` is set (a kernel
+    that is the identity), calls
     `on_done(token, host_bytes, word, good)` and completes the slot, in
     the order of the submits. `host_bytes` is the device's answer on the
     host, not the slot. That is the RDMA endpoint's sender and its
@@ -181,9 +187,10 @@ class DeviceLane:
 
     Spans (brpc_tpu/spans.py), request = token: per submit one
     `ring.launch` with children ring.acquire (waiting for a credit and a
-    free slot), whatever `fill` opens around its copy into the slot
-    (ring.stage in the ring pass, tensor.fill in a served call),
-    ring.frame, ring.h2d,
+    free slot), whatever `fill` opens around its pass over the bytes
+    (ring.stage in the ring pass, tensor.fill in a served call: the copy
+    into the slot AND the crc32c), ring.frame (header + meta: a few
+    microseconds), ring.h2d,
     ring.kernel_dispatch (the jitted function + the async D2H requests),
     and one `ring.retire` with children ring.d2h_wait (blocks until the
     device is done), ring.verify (crc32c, where `verify`), ring.complete.
@@ -224,20 +231,21 @@ class DeviceLane:
             "device stream); ring aborted")
 
     def submit(self, fill, nbytes, token, correlation_id=1):
-        from brpc_tpu import native
         try:
             with spans.span("ring.launch", token):
                 with spans.span("ring.acquire", token):
                     slot = self._acquire()
                 sa = self.ring.slots[slot]
                 view = sa[HEADROOM:HEADROOM + nbytes]
-                # Staged once, framed in place (no payload memcpy -- ISSUE
-                # 9 satellite), imported zero-copy where the platform
-                # backs arrays with host memory.
-                fill(view)
+                # Staged once, by the one pass over the bytes that also
+                # gives their crc32c (ISSUE 30); framed in place from it
+                # (header + meta only: no payload memcpy -- ISSUE 9
+                # satellite -- and no second pass); imported zero-copy
+                # where the platform backs arrays with host memory.
+                crc = fill(view)
                 with spans.span("ring.frame", token):
-                    _, _, crc = native.frame_in_place(correlation_id, sa,
-                                                      HEADROOM, nbytes)
+                    native.frame_in_place(correlation_id, sa, HEADROOM,
+                                          nbytes, crc)
                 with spans.span("ring.h2d", token):
                     x = _h2d(view.view(np.uint32), self.dev)
                 with spans.span("ring.kernel_dispatch", token):
@@ -256,7 +264,6 @@ class DeviceLane:
             self._handoff.put(item)
 
     def _retire(self, item):
-        from brpc_tpu import native
         token, slot, crc, y, word = item
         with spans.span("ring.retire", token):
             with spans.span("ring.d2h_wait", token):
@@ -311,8 +318,9 @@ class _ChunkPipeline:
     per chunk — frame() with the payload memcpy, device_put (always a
     copy), full sync, fresh ndarray materialization, copy-back — run at
     depth 1 with nothing in flight. copy_mode=False is the ring path: a
-    loop over `DeviceLane.submit` (payload staged once into the
-    registered slot, framed IN PLACE, dlpack zero-copy import where the
+    loop over `DeviceLane.submit` (payload staged into the registered slot
+    by the pass that computes its crc32c, framed IN PLACE, dlpack
+    zero-copy import where the
     platform backs arrays with host memory, donated device buffers
     elsewhere, depth-N chunks in flight so H2D/compute/D2H of neighboring
     chunks overlap) -- the same lane a served handler submits to
@@ -354,7 +362,7 @@ class _ChunkPipeline:
 
         def stage(view):
             with spans.span("ring.stage", req):
-                np.copyto(view.view(np.uint32), self.chunks[k])
+                return native.copy_crc32c(view, self.chunks[k])
 
         lane.submit(stage, self.chunk_bytes, req, k + 1)
 
@@ -365,7 +373,6 @@ class _ChunkPipeline:
         copied back into staging, and the framework re-parses and
         crc32c-verifies the whole frame around it."""
         import jax
-        from brpc_tpu import native
         req = (self.passes, k)
         clen = self.chunk_bytes
         with spans.span("ring.launch", req):
@@ -438,7 +445,7 @@ class _ChunkPipeline:
 
 def run(payload_mb: int = 4, reps: int = 5, ring_depth: int = 4,
         chunk_kb: int = 2044, device=None) -> dict:
-    from brpc_tpu import compile_cache, native
+    from brpc_tpu import compile_cache
 
     compile_cache.enable()
     dev = _resolve_device(device)

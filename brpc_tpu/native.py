@@ -73,6 +73,12 @@ def lib() -> ctypes.CDLL:
         L.tpurpc_crc32c.restype = ctypes.c_uint32
         L.tpurpc_crc32c.argtypes = [ctypes.c_uint32, ctypes.c_void_p,
                                     ctypes.c_size_t]
+        for fn in (L.tpurpc_crc32c_copy, L.tpurpc_crc32c_copy_tables):
+            fn.restype = ctypes.c_uint32
+            fn.argtypes = [ctypes.c_uint32, ctypes.c_void_p, ctypes.c_void_p,
+                           ctypes.c_size_t]
+        L.tpurpc_stage_fused_bytes.restype = ctypes.c_long
+        L.tpurpc_frame_crc_pass_bytes.restype = ctypes.c_long
         L.tpurpc_block_alloc.restype = ctypes.c_void_p
         L.tpurpc_block_alloc.argtypes = [ctypes.c_size_t]
         L.tpurpc_block_free.argtypes = [ctypes.c_void_p]
@@ -130,8 +136,8 @@ def lib() -> ctypes.CDLL:
         L.tpurpc_frame_in_place.restype = ctypes.c_long
         L.tpurpc_frame_in_place.argtypes = [
             ctypes.c_uint64, ctypes.c_void_p, ctypes.c_size_t,
-            ctypes.c_size_t, ctypes.POINTER(ctypes.c_size_t),
-            ctypes.POINTER(ctypes.c_uint32),
+            ctypes.c_size_t, ctypes.POINTER(ctypes.c_uint32),
+            ctypes.POINTER(ctypes.c_size_t),
         ]
         L.tpurpc_unframe.restype = ctypes.c_long
         L.tpurpc_unframe.argtypes = [
@@ -155,7 +161,8 @@ def lib() -> ctypes.CDLL:
         L.tpurpc_server_stop.argtypes = [ctypes.c_void_p]
         L.tpurpc_call_copy_out.restype = ctypes.c_long
         L.tpurpc_call_copy_out.argtypes = [ctypes.c_void_p, ctypes.c_void_p,
-                                           ctypes.c_size_t]
+                                           ctypes.c_size_t,
+                                           ctypes.POINTER(ctypes.c_uint32)]
         L.tpurpc_call_reply.restype = ctypes.c_int
         L.tpurpc_call_reply.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t,
@@ -188,6 +195,32 @@ def crc32c(data: bytes | np.ndarray, init: int = 0) -> int:
         data, np.ndarray) else np.frombuffer(data, dtype=np.uint8)
     return int(lib().tpurpc_crc32c(
         init, buf.ctypes.data_as(ctypes.c_void_p), buf.nbytes))
+
+
+def copy_crc32c(dst: np.ndarray, src: np.ndarray, init: int = 0,
+                tables: bool = False) -> int:
+    """`src`'s bytes into `dst` (both contiguous, the same size, not
+    overlapping) and their crc32c, in ONE pass: each line is folded into
+    the crc and copied while it sits in L1 (cpp/tbase/crc32c.h
+    crc32c_copy_extend). How a payload is staged into a ring slot; the crc
+    is `frame_in_place`'s. `tables` runs the table path whatever the cpu
+    (tests)."""
+    if dst.nbytes != src.nbytes:
+        raise ValueError(f"{src.nbytes} bytes into room for {dst.nbytes}")
+    fn = (lib().tpurpc_crc32c_copy_tables if tables
+          else lib().tpurpc_crc32c_copy)
+    return int(fn(init, _address(dst), _address(src), src.nbytes))
+
+
+def staging_counters() -> dict:
+    """/vars rpc_stage_fused_bytes (bytes staged with their crc32c in one
+    pass: `copy_crc32c`, `ParkedCall.copy_into`) and
+    rpc_frame_crc_pass_bytes (payload bytes the in-place framer had to walk
+    itself: `frame`'s aliasing fast path only; a lane pass or a served
+    call leaves it where it was)."""
+    return {"rpc_stage_fused_bytes": int(lib().tpurpc_stage_fused_bytes()),
+            "rpc_frame_crc_pass_bytes":
+                int(lib().tpurpc_frame_crc_pass_bytes())}
 
 
 def stage_dump() -> dict:
@@ -397,13 +430,17 @@ class ParkedCall:
         self._ptr = ptr
         self.nbytes = nbytes
 
-    def copy_into(self, view: np.ndarray) -> None:
-        """The request attachment into `view` (uint8, at least `nbytes`),
-        in one pass."""
+    def copy_into(self, view: np.ndarray) -> int:
+        """The request attachment into `view` (uint8, at least `nbytes`)
+        and zeros into what is left of it, each block folded into the
+        crc32c by the pass that copies it. Returns the crc32c of all of
+        `view`, which is `frame_in_place`'s."""
+        crc = ctypes.c_uint32()
         got = lib().tpurpc_call_copy_out(self._ptr, _address(view),
-                                         view.nbytes)
+                                         view.nbytes, ctypes.byref(crc))
         if got != self.nbytes:
             raise ValueError(f"copied {got} of {self.nbytes} bytes")
+        return int(crc.value)
 
     def reply(self, body: np.ndarray,
               tail: np.ndarray | None = None) -> None:
@@ -521,8 +558,10 @@ def frame(correlation_id: int, payload: np.ndarray,
     if out is not None and _within(out, pay):
         off = pay.ctypes.data - out.ctypes.data
         if off >= IN_PLACE_HEADROOM:
-            frame_off, n, _ = frame_in_place(correlation_id, out, off,
-                                             pay.nbytes)
+            # Staged by the caller's own hand, so no crc comes with the
+            # bytes: the framer walks them (rpc_frame_crc_pass_bytes).
+            frame_off, n = _frame_in_place(correlation_id, out, off,
+                                           pay.nbytes, None)
             return out[frame_off:frame_off + n]
         # Payload sits too close to the buffer start for an in-place
         # header: fall through to the copy path (tpurpc_frame memmoves
@@ -546,21 +585,27 @@ IN_PLACE_HEADROOM = 64
 
 
 def frame_in_place(correlation_id: int, buf: np.ndarray, payload_off: int,
-                   payload_len: int) -> tuple[int, int, int]:
+                   payload_len: int, crc: int) -> tuple[int, int]:
     """Frame a payload that already resides at buf[payload_off:...]:
-    writes header+meta right-justified before it (no payload memcpy).
-    Returns (frame_off, frame_len, payload_crc32c) — the crc is the one
-    embedded in the frame meta, handed back so the caller can verify
-    round-tripped payload bytes without re-parsing."""
+    writes header+meta right-justified before it -- no payload memcpy and
+    no pass over the payload either: `crc` is its crc32c from the pass
+    that staged it (`copy_crc32c`, `ParkedCall.copy_into`) and is what the
+    meta embeds. Returns (frame_off, frame_len)."""
+    return _frame_in_place(correlation_id, buf, payload_off, payload_len,
+                           ctypes.byref(ctypes.c_uint32(crc)))
+
+
+def _frame_in_place(correlation_id, buf, payload_off, payload_len, crc_ref):
+    """`crc_ref` None is `frame`'s aliasing fast path alone: the C++ framer
+    walks the payload for its crc32c itself."""
     b = buf.view(np.uint8).reshape(-1)
     frame_off = ctypes.c_size_t()
-    crc = ctypes.c_uint32()
     n = lib().tpurpc_frame_in_place(
         correlation_id, b.ctypes.data_as(ctypes.c_void_p), payload_off,
-        payload_len, ctypes.byref(frame_off), ctypes.byref(crc))
+        payload_len, crc_ref, ctypes.byref(frame_off))
     if n < 0:
         raise ValueError("tpurpc_frame_in_place failed (headroom < meta)")
-    return int(frame_off.value), int(n), int(crc.value)
+    return int(frame_off.value), int(n)
 
 
 def unframe(buf: np.ndarray) -> tuple[int, np.ndarray, int]:

@@ -7,8 +7,10 @@ the same port; cpp/trpc/c_api.h) and joins it to a long-lived
 `device_path.DeviceLane`:
 
     taker thread        take a parked call -> lane.submit: a ring slot,
-    (the launcher)      the request attachment copied into it in one pass,
-                        in-place frame, H2D, jitted `tensor_step`, async D2H
+    (the launcher)      the request attachment copied into it, its tail
+                        zeroed and the crc32c of both computed in ONE pass
+                        (ParkedCall.copy_into), header + meta written from
+                        that crc, H2D, jitted `tensor_step`, async D2H
     lane's completion   D2H back -> reply y ‖ w from the returned host
     thread              buffer (one copy into the reply) -> slot completed
 
@@ -26,7 +28,8 @@ and in-flight call fails, `take` returns, both threads end (the ISSUE 10c
 rule); `close()` then only joins.
 
 Spans (brpc_tpu/spans.py) beside the lane's `ring.*`: tensor.take (waiting
-for a call, then taking it), tensor.fill (attachment -> slot), tensor.reply
+for a call, then taking it), tensor.fill (attachment -> slot, zero tail and
+crc32c: the launcher's one pass over a call's bytes), tensor.reply
 (answer -> response attachment -> `done`). Stages and counters on the C++
 side: tdev.take_wait, tdev.reply, rpc_tensor_* (c_api.h); rpc_tensor_calls
 is counted here, where the D2H of a step's result has come back.
@@ -101,8 +104,9 @@ class TensorService:
                     call = self.server.take(TAKE_POLL_US)
                 if call is None:
                     if self.ring.aborted:
-                        self._shut(native.RingAbortedError(
-                            "ring aborted (poisoned)"))
+                        self._shut(self.lane.failure
+                                   or native.RingAbortedError(
+                                       "ring aborted (poisoned)"))
                     continue
                 n = call.nbytes
                 if n < 16 or n % 8 or n > self.max_bytes:
@@ -114,15 +118,16 @@ class TensorService:
                     self._submit(call, n)
                 except Exception as e:  # ring aborted, device error
                     call.fail(TERR_INTERNAL, f"device leg failed: {e!r}")
-                    self._shut(e)
+                    # The completion thread's error, where the aborted ring
+                    # this launch met is only its echo.
+                    self._shut(self.lane.failure or e)
         except native.ServerClosedError:
             pass
 
     def _submit(self, call, n):
         def fill(view):
             with spans.span("tensor.fill", call):
-                call.copy_into(view[:n])
-                view[n:] = 0
+                return call.copy_into(view)  # zero tail and crc32c included
 
         size = self.buckets[bisect.bisect_left(self.buckets, n)]
         self.lane.submit(fill, size, call)

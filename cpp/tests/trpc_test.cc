@@ -552,6 +552,36 @@ TEST(Crc32c, KnownVectors) {
     }
 }
 
+// The checksummed copy (ISSUE 30) gives crc32c_extend's values and
+// memcpy's bytes on the cpu's path and on the table path, whatever the
+// alignments, across the three-lane blocks' boundaries.
+TEST(Crc32c, CopyExtendIsExtendAndMemcpy) {
+    std::string src((1 << 20) + 19, '\0');
+    for (size_t i = 0; i < src.size(); ++i) {
+        src[i] = (char)(i * 2654435761u >> 13);
+    }
+    const size_t lens[] = {0, 1, 7, 8, 9, 767, 768, 769, 4095, 4096,
+                           24575, 24576, 24585, 1 << 20, (1 << 20) + 3};
+    for (size_t n : lens) {
+        for (size_t sa = 0; sa < 8; ++sa) {
+            const uint32_t want = crc32c_extend(77, src.data() + sa, n);
+            EXPECT_EQ(want, crc32c_copy_extend_tables(77, nullptr,
+                                                      src.data() + sa, n));
+            for (size_t da = 0; da < 8; da += (n > 4096 ? 3 : 1)) {
+                std::string hw(n + 16, '\xAA'), sw(n + 16, '\xAA');
+                EXPECT_EQ(want, crc32c_copy_extend(77, &hw[da],
+                                                   src.data() + sa, n));
+                EXPECT_EQ(want, crc32c_copy_extend_tables(
+                                    77, &sw[da], src.data() + sa, n));
+                std::string expect(n + 16, '\xAA');
+                expect.replace(da, n, src, sa, n);
+                EXPECT_TRUE(hw == expect);
+                EXPECT_TRUE(sw == expect);
+            }
+        }
+    }
+}
+
 TEST(Compress, GzipRoundTrip) {
     std::string data;
     for (int i = 0; i < 3000; ++i) data += "compressible payload ";

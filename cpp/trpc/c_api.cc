@@ -1,5 +1,6 @@
 #include "trpc/c_api.h"
 
+#include <algorithm>
 #include <atomic>
 #include <chrono>
 #include <condition_variable>
@@ -41,6 +42,33 @@ int tpurpc_global_init() {
 
 uint32_t tpurpc_crc32c(uint32_t init, const void* data, size_t n) {
     return tpurpc::crc32c_extend(init, (const char*)data, n);
+}
+
+namespace {
+// What says the one-pass staging engaged (ISSUE 30): bytes that went into
+// a staging buffer with their crc32c in one pass, and bytes
+// tpurpc_frame_in_place had to walk itself because no crc came with them.
+tpurpc::LazyAdder g_stage_fused_bytes("rpc_stage_fused_bytes");
+tpurpc::LazyAdder g_frame_crc_pass_bytes("rpc_frame_crc_pass_bytes");
+}  // namespace
+
+uint32_t tpurpc_crc32c_copy(uint32_t init, void* dst, const void* src,
+                            size_t n) {
+    *g_stage_fused_bytes << (int64_t)n;
+    return tpurpc::crc32c_copy_extend(init, dst, src, n);
+}
+
+uint32_t tpurpc_crc32c_copy_tables(uint32_t init, void* dst, const void* src,
+                                   size_t n) {
+    return tpurpc::crc32c_copy_extend_tables(init, dst, src, n);
+}
+
+long tpurpc_stage_fused_bytes() {
+    return (long)(*g_stage_fused_bytes).get_value();
+}
+
+long tpurpc_frame_crc_pass_bytes() {
+    return (long)(*g_frame_crc_pass_bytes).get_value();
 }
 
 void* tpurpc_block_alloc(size_t n) {
@@ -351,6 +379,8 @@ void* tpurpc_server_start(int port) {
     *g_tensor_calls << 0;
     *g_tensor_bytes_in << 0;
     *g_tensor_failed << 0;
+    *g_stage_fused_bytes << 0;
+    *g_frame_crc_pass_bytes << 0;
     static auto* highwater = [] {
         auto* v = new tpurpc::PassiveStatus<int64_t>(ParkedHighwater,
                                                      nullptr);
@@ -402,9 +432,27 @@ void tpurpc_server_stop(void* server) {
     if (ps->Stopped()) delete ps;
 }
 
-long tpurpc_call_copy_out(void* call, void* dst, size_t cap) {
-    return (long)((ParkedCall*)call)->cntl->request_attachment().copy_to(
-        dst, cap);
+long tpurpc_call_copy_out(void* call, void* dst, size_t cap,
+                          uint32_t* crc_out) {
+    const tpurpc::IOBuf& att = ((ParkedCall*)call)->cntl->request_attachment();
+    char* d = (char*)dst;
+    size_t copied = 0;
+    uint32_t crc = 0;
+    for (size_t i = 0; i < att.backing_block_num() && copied < cap; ++i) {
+        size_t len = 0;
+        const char* data = att.backing_block_data(i, &len);
+        len = std::min(len, cap - copied);
+        crc = tpurpc::crc32c_copy_extend(crc, d + copied, data, len);
+        copied += len;
+    }
+    static const char kZeros[4096] = {};
+    for (size_t at = copied; at < cap; at += sizeof(kZeros)) {
+        crc = tpurpc::crc32c_copy_extend(
+            crc, d + at, kZeros, std::min(sizeof(kZeros), cap - at));
+    }
+    *g_stage_fused_bytes << (int64_t)cap;
+    if (crc_out != nullptr) *crc_out = crc;
+    return (long)copied;
 }
 
 int tpurpc_flag_set(const char* name, const char* value) {
@@ -530,11 +578,15 @@ long tpurpc_frame(uint64_t correlation_id, const void* payload, size_t n,
 
 long tpurpc_frame_in_place(uint64_t correlation_id, void* buf,
                            size_t payload_off, size_t payload_len,
-                           size_t* frame_off, uint32_t* crc_out) {
+                           const uint32_t* payload_crc, size_t* frame_off) {
     char* b = (char*)buf;
-    const uint32_t crc =
-        tpurpc::crc32c_extend(0, b + payload_off, payload_len);
-    if (crc_out != nullptr) *crc_out = crc;
+    uint32_t crc;
+    if (payload_crc != nullptr) {
+        crc = *payload_crc;
+    } else {
+        crc = tpurpc::crc32c_extend(0, b + payload_off, payload_len);
+        *g_frame_crc_pass_bytes << (int64_t)payload_len;
+    }
     std::string meta_str;
     if (!frame_meta(correlation_id, payload_len, crc, &meta_str)) {
         return -1;

@@ -20,8 +20,26 @@ extern "C" {
 // One-time framework init (protocol registry + ICI block pool). Returns 0.
 int tpurpc_global_init();
 
-// The framework's crc32c (slice-by-8, RFC 3720 polynomial).
+// The framework's crc32c (RFC 3720 polynomial; tbase/crc32c.h).
 uint32_t tpurpc_crc32c(uint32_t init, const void* data, size_t n);
+// memcpy(dst, src, n) that returns tpurpc_crc32c(init, src, n): one read
+// of src, one write of dst (tbase/crc32c.h crc32c_copy_extend; no
+// overlap). How a payload is staged into a ring slot: the crc goes to
+// tpurpc_frame_in_place, which then never reads the payload.
+// rpc_stage_fused_bytes += n.
+uint32_t tpurpc_crc32c_copy(uint32_t init, void* dst, const void* src,
+                            size_t n);
+// The same through the table path whatever the cpu, uncounted, for the
+// tests that hold the two paths to each other (dst NULL: checksum only).
+uint32_t tpurpc_crc32c_copy_tables(uint32_t init, void* dst, const void* src,
+                                   size_t n);
+// /vars rpc_stage_fused_bytes: bytes staged with their crc32c in one pass
+// (tpurpc_crc32c_copy, tpurpc_call_copy_out). /vars
+// rpc_frame_crc_pass_bytes: payload bytes tpurpc_frame_in_place walked
+// itself because no crc came with them; over a lane pass or a served call
+// it does not move.
+long tpurpc_stage_fused_bytes();
+long tpurpc_frame_crc_pass_bytes();
 
 // Registered-memory staging buffers from the ICI block pool. Allocation
 // routes through the slab-class allocator (recyclable; ISSUE 9c) for
@@ -147,9 +165,14 @@ void tpurpc_server_close_queue(void* server, int code);
 // Join), then frees the server, or leaves that to the answer of the last
 // taken call where one is still on its way.
 void tpurpc_server_stop(void* server);
-// Copy the request attachment into dst[0..cap) in one pass; returns the
-// bytes copied (the attachment's length when cap is enough).
-long tpurpc_call_copy_out(void* call, void* dst, size_t cap);
+// Stage the request attachment into dst[0..cap): its blocks are walked
+// once, each copied and folded into the crc in the same pass
+// (crc32c_copy_extend), and what is left of dst is zero-filled and folded
+// in the same way. Returns the attachment bytes copied (its length when
+// cap is enough) and sets *crc_out (may be NULL) to the crc32c of all of
+// dst[0..cap). rpc_stage_fused_bytes += cap.
+long tpurpc_call_copy_out(void* call, void* dst, size_t cap,
+                          uint32_t* crc_out);
 // Answer: the response attachment is body ‖ tail (one copy each; tail may
 // be NULL/0). Runs `done` on the calling thread and frees the handle.
 int tpurpc_call_reply(void* call, const void* body, size_t n,
@@ -187,15 +210,18 @@ long tpurpc_frame(uint64_t correlation_id, const void* payload, size_t n,
 // payload ALREADY lives at buf[payload_off .. payload_off+payload_len);
 // the header + meta are written right-justified immediately before it,
 // so the finished frame occupies buf[*frame_off .. payload_off+
-// payload_len) with NO payload copy. Requires payload_off >= the
-// header+meta size (~64 bytes is always enough). Returns the frame
-// length, sets *frame_off, and (when non-null) *crc_out = the crc32c
-// embedded in the meta — so callers can verify round-tripped payload
-// bytes without re-parsing the frame. Returns -1 when the prefix space
-// is too small.
+// payload_len) with NO payload copy. *payload_crc is the payload's
+// crc32c, computed by the pass that staged it (tpurpc_crc32c_copy,
+// tpurpc_call_copy_out), and is what the meta embeds: header + meta only,
+// no pass over the payload either (ISSUE 30). NULL, for a payload staged
+// some other way (tpurpc_frame's aliasing caller, native.frame): the
+// framer walks it itself, rpc_frame_crc_pass_bytes += payload_len.
+// Requires payload_off >= the header+meta size (~64 bytes is always
+// enough). Returns the frame length and sets *frame_off; -1 when the
+// prefix space is too small.
 long tpurpc_frame_in_place(uint64_t correlation_id, void* buf,
                            size_t payload_off, size_t payload_len,
-                           size_t* frame_off, uint32_t* crc_out);
+                           const uint32_t* payload_crc, size_t* frame_off);
 
 // Parse ONE frame at buf[0..n): verifies the header, meta, and
 // body_checksum. On success returns bytes consumed and sets *cid,

@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 from test_device_path_spans import LAUNCH_CHILDREN, RETIRE_CHILDREN
 
-from brpc_tpu import spans, tensor_reference
+from brpc_tpu import native, spans, tensor_reference
+from brpc_tpu.native import IN_PLACE_HEADROOM as HEADROOM
 
 CHUNK_BYTES, N_CHUNKS, DEPTH = 64 << 10, 6, 3
 LIMIT_S = 60
@@ -34,7 +35,7 @@ def parts(cpp_build):
     """(device_path, ring, dev, kernel, chunks), compiled and warm."""
     import jax
 
-    from brpc_tpu import device_path, native
+    from brpc_tpu import device_path
 
     dev = jax.devices("cpu")[0]
     per = CHUNK_BYTES // 4
@@ -54,7 +55,9 @@ def host_words(device_path, chunks, passes):
 
 
 def filler(chunk):
-    return lambda view: np.copyto(view.view(np.uint32), chunk)
+    """What `submit` asks of a fill: the bytes into the slot, their crc32c
+    back, from one pass."""
+    return lambda view: native.copy_crc32c(view, chunk)
 
 
 def test_a_pass_is_a_loop_over_submit_on_a_lane(parts, monkeypatch):
@@ -156,7 +159,7 @@ def test_requests_of_other_sizes_share_one_lane(parts):
     def filling(x, token):
         def fill(view):  # the caller's own span around its copy
             with spans.span("tensor.fill", token):
-                np.copyto(view, x)
+                return native.copy_crc32c(view, x)
         return fill
 
     for token, nbytes in enumerate([16, 4096, CHUNK_BYTES, 40, 4096]):
@@ -199,8 +202,6 @@ def test_an_error_behind_the_launcher_abandons_what_follows(
     credits let it be, nothing is left of them when the retire fails, and
     the submits after it still meet the aborted ring at once (the timeout
     is set longer than the test's own limit)."""
-    from brpc_tpu import native
-
     device_path, ring, dev, kernel, chunks = parts
     monkeypatch.setattr(device_path, "ACQUIRE_TIMEOUT_US",
                         2 * LIMIT_S * 1_000_000)
@@ -239,6 +240,96 @@ def test_an_error_behind_the_launcher_abandons_what_follows(
     assert done == [0] and abandoned == launched[1:]
     with pytest.raises(native.RingAbortedError):
         lane.submit(filler(chunks[0]), CHUNK_BYTES, 99)
+
+
+def slot_frame(sa, nbytes):
+    """The frame a slot holds, from where its header starts: (correlation
+    id, payload), parsed and crc32c-checked by the C++ framework."""
+    start = bytes(sa[:HEADROOM]).rindex(b"TRPC")
+    cid, pay, consumed = native.unframe(sa[start:HEADROOM + nbytes])
+    assert consumed == HEADROOM - start + nbytes
+    return cid, pay
+
+
+def test_the_fills_crc_is_the_one_in_the_slots_frame(parts):
+    """ISSUE 30: `fill(view)` returns the crc32c of what it wrote and the
+    framer embeds it unread, so the frame in the slot parses -- `unframe`
+    walks the payload against the meta's checksum -- with the submit's
+    correlation id and the chunk's bytes; the staging counter moved by the
+    bytes and the framer walked none."""
+    device_path, ring, dev, kernel, chunks = parts
+    before = native.staging_counters()
+    done = []
+    lane = device_path.DeviceLane(
+        ring, dev, kernel, DEPTH,
+        lambda token, back, word, good: done.append((token, good)))
+    for k in range(DEPTH):
+        lane.submit(filler(chunks[k]), CHUNK_BYTES, k, 100 + k)
+    lane.close()
+    assert done == [(k, True) for k in range(DEPTH)]
+    for k, sa in enumerate(ring.slots):
+        cid, pay = slot_frame(sa, CHUNK_BYTES)
+        assert cid == 100 + k
+        assert pay.tobytes() == chunks[k].tobytes()
+    after = native.staging_counters()
+    assert (after["rpc_stage_fused_bytes"] - before["rpc_stage_fused_bytes"]
+            == DEPTH * CHUNK_BYTES)
+    assert (after["rpc_frame_crc_pass_bytes"]
+            == before["rpc_frame_crc_pass_bytes"])
+
+
+def test_a_crc_that_is_not_the_bytes_is_a_frame_that_does_not_parse(parts):
+    """The framer does not look: what `fill` returns is what it embeds."""
+    device_path, ring, dev, kernel, chunks = parts
+    verdicts = []
+    lane = device_path.DeviceLane(
+        ring, dev, kernel, 1,
+        lambda token, back, word, good: verdicts.append(good))
+    lane.submit(lambda view: native.copy_crc32c(view, chunks[0]) ^ 1,
+                CHUNK_BYTES, 0)
+    lane.close()
+    assert verdicts == [False]  # ring.verify holds the D2H to that crc
+    with pytest.raises(ValueError, match="corrupt"):
+        slot_frame(ring.slots[0], CHUNK_BYTES)
+
+
+def test_a_fill_that_returns_nothing_is_refused(parts):
+    """One contract and no fallback: the lane never asks the framer to
+    walk the payload for a fill that brought no crc."""
+    device_path, ring, dev, kernel, chunks = parts
+    before = native.staging_counters()
+    lane = device_path.DeviceLane(ring, dev, kernel, DEPTH,
+                                  lambda *a: pytest.fail("nothing launched"))
+    with pytest.raises(TypeError):
+        lane.submit(lambda view: np.copyto(view.view(np.uint32), chunks[0]),
+                    CHUNK_BYTES, 0)
+    lane.close()
+    assert ring.aborted  # a launch that failed never frees its slot
+    assert native.staging_counters() == before
+
+
+@pytest.mark.parametrize("bad", [0, 2, N_CHUNKS - 1])
+def test_a_corrupted_d2h_still_reads_not_good(parts, bad):
+    """The guarantee is the one it was: the bytes that came back from the
+    device are held to the crc32c in the slot's frame, chunk by chunk."""
+    device_path, ring, dev, kernel, chunks = parts
+    calls = []
+
+    def one_bit_off(x):
+        y, w = kernel(x)
+        calls.append(1)
+        if len(calls) - 1 == bad:
+            y = y.at[7].set(y[7] ^ 1)
+        return y, w
+
+    verdicts = []
+    lane = device_path.DeviceLane(
+        ring, dev, one_bit_off, DEPTH,
+        lambda token, back, word, good: verdicts.append((token, good)))
+    for k, chunk in enumerate(chunks):
+        lane.submit(filler(chunk), CHUNK_BYTES, k, k + 1)
+    lane.close()
+    assert verdicts == [(k, k != bad) for k in range(N_CHUNKS)]
 
 
 @pytest.mark.parametrize("nbytes", [16, 24, 4096, 65544, 1048576])

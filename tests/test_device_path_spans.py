@@ -61,6 +61,9 @@ def test_every_chunk_has_its_launch_and_retire_with_their_children(pipeline):
         names = [name for name, _, _ in got]
         assert names.count("ring.launch") == 1, (request, names)
         assert names.count("ring.retire") == 1, (request, names)
+        # One pass over the bytes and one header write a chunk (ISSUE 30).
+        assert names.count("ring.stage") == 1, (request, names)
+        assert names.count("ring.frame") == 1, (request, names)
         # The children lie inside their parent's interval (nesting is in
         # the times), and the launch inside its pass's ring.pass.
         for parent, children in (("ring.launch", LAUNCH_CHILDREN),
@@ -80,6 +83,30 @@ def test_every_chunk_has_its_launch_and_retire_with_their_children(pipeline):
     assert any(nm == "ring.pass" and s <= d0 and d1 <= e for nm, s, e in last)
     assert all(nm != "ring.drain" for p in passes if p != max(passes)
                for nm, _, _ in by_request[p])
+
+
+def test_a_pass_stages_every_byte_fused_and_the_framer_walks_none(pipeline):
+    """ISSUE 30's counters over the ring pass: `ring.stage` is the one
+    pass over a chunk's bytes (copy + crc32c), `ring.frame` a header and a
+    meta."""
+    from brpc_tpu import native
+
+    before = native.staging_counters()
+    pipeline.run(3)
+    assert pipeline.ok
+    after = native.staging_counters()
+    assert (after["rpc_stage_fused_bytes"] - before["rpc_stage_fused_bytes"]
+            == 3 * len(pipeline.chunks) * pipeline.chunk_bytes)
+    assert (after["rpc_frame_crc_pass_bytes"]
+            == before["rpc_frame_crc_pass_bytes"])
+    # The walk is still there for the one caller that stages by hand.
+    buf = native.PoolBuffer(1 << 16)
+    region = buf.array[64:64 + 4096]
+    region[:] = 7
+    native.frame(1, region, out=buf.array)
+    assert (native.staging_counters()["rpc_frame_crc_pass_bytes"]
+            == after["rpc_frame_crc_pass_bytes"] + 4096)
+    buf.free()
 
 
 def test_self_times_and_remainder_add_up_to_the_wall_time(pipeline):

@@ -172,14 +172,14 @@ def test_replies_out_of_order_each_reach_their_own_call(native, pull_server,
     assert got == {c: bytes([c]) * 68 for c in (1, 2)}
 
 
-def tensor_counters(port):
+def tensor_counters(port, prefixes=("rpc_tensor_",)):
     import urllib.request
 
     with urllib.request.urlopen(f"http://127.0.0.1:{port}/vars",
                                 timeout=10) as r:
         return {ln.split(" : ")[0]: int(ln.split(" : ")[1].split()[0])
                 for ln in r.read().decode().splitlines()
-                if ln.startswith("rpc_tensor_")}
+                if ln.startswith(prefixes)}
 
 
 def test_an_answer_made_on_the_host_is_not_counted_as_a_step(native,
@@ -203,6 +203,39 @@ def test_an_answer_made_on_the_host_is_not_counted_as_a_step(native,
     native.tensor_step_answered()
     assert (tensor_counters(pull_server.port)["rpc_tensor_calls"]
             == before["rpc_tensor_calls"] + 1)
+
+
+@pytest.mark.parametrize("pad", [0, 4096], ids=["unpadded", "padded"])
+@pytest.mark.parametrize("nbytes", [16, 4096 + 8, 1 << 20])
+def test_copy_into_returns_the_crc32c_of_what_it_staged(native, pull_server,
+                                                        nbytes, pad):
+    """ISSUE 30: one walk over the request attachment's blocks (the larger
+    sizes arrive over the shm link in many of them) copies each into the
+    view and folds it into the crc; what is left of the view is zeroed and
+    folded in the same call. The crc is that of request + zero tail."""
+    x = np.random.default_rng(nbytes + pad).integers(0, 256, nbytes,
+                                                     dtype=np.uint8)
+    got = []
+    caller = threading.Thread(target=lambda: got.append(
+        native.StepChannel(pull_server.port, ici=True).call(x, 64)))
+    caller.start()
+    call = pull_server.take(10_000_000)
+    assert call.nbytes == nbytes
+    before = native.staging_counters()
+    room = np.full(nbytes + pad + 8, 0xAA, dtype=np.uint8)
+    view = room[:nbytes + pad]
+    crc = call.copy_into(view)
+    call.reply(view[:8])
+    caller.join()
+    staged = x.tobytes() + bytes(pad)
+    assert view.tobytes() == staged and room[-8:].tobytes() == b"\xaa" * 8
+    assert crc == native.crc32c(staged)
+    after = native.staging_counters()
+    assert (after["rpc_stage_fused_bytes"] - before["rpc_stage_fused_bytes"]
+            == nbytes + pad)
+    assert (after["rpc_frame_crc_pass_bytes"]
+            == before["rpc_frame_crc_pass_bytes"])
+    assert got[0].tobytes() == x[:8].tobytes()
 
 
 def test_a_flag_is_the_embedding_processs_to_set(native):
@@ -310,6 +343,44 @@ def test_mixed_sizes_from_many_callers_keep_reply_and_call_together(
                 if rec[0] == "ring.launch"}) == 1
     assert len({rec[4] for rec in spans.snapshot()
                 if rec[0] == "tensor.reply"}) == 1
+
+
+def test_a_served_call_stages_in_one_pass_and_the_framer_walks_none(
+        native, service):
+    """ISSUE 30 on the served path: `tensor.fill` is the one pass over a
+    call's bytes (attachment -> slot, zero tail, crc32c), once a call;
+    `ring.frame` writes a header and a meta inside the same `ring.launch`;
+    rpc_stage_fused_bytes moves by the sizes the calls crossed the chip at
+    and rpc_frame_crc_pass_bytes not at all -- on /vars too."""
+    from brpc_tpu import spans
+
+    def on_vars():
+        return tensor_counters(service.port, ("rpc_stage_fused_bytes",
+                                              "rpc_frame_crc_pass_bytes"))
+
+    spans.clear()
+    before = native.staging_counters()
+    assert on_vars() == before  # there from the first scrape
+    sizes = [4096, 24, 65544, 1048576]  # staged at 4096, 4096, 131072, 1 MiB
+    out = run_callers(native, service.port, 2, sizes, seed=30)
+    assert len(out) == 8 and all(v is True for _, _, v in out), out
+    after = native.staging_counters()
+    assert on_vars() == after
+    assert (after["rpc_stage_fused_bytes"] - before["rpc_stage_fused_bytes"]
+            == 2 * (4096 + 4096 + 131072 + 1048576))
+    assert (after["rpc_frame_crc_pass_bytes"]
+            == before["rpc_frame_crc_pass_bytes"])
+    by_call = {}
+    for name, start, end, request, thread in spans.snapshot():
+        if name in ("tensor.fill", "ring.frame", "ring.launch"):
+            by_call.setdefault(request, []).append((name, start, end, thread))
+    assert len(by_call) == 8
+    for records in by_call.values():
+        assert sorted(r[0] for r in records) == ["ring.frame", "ring.launch",
+                                                 "tensor.fill"]
+        (_, l0, l1, launcher), = [r for r in records if r[0] == "ring.launch"]
+        for name, s0, s1, thread in records:
+            assert l0 <= s0 and s1 <= l1 and thread == launcher, name
 
 
 @pytest.mark.parametrize("max_bytes, want", [

@@ -23,23 +23,13 @@ launch order (wait for the D2H, crc32c, complete). The lane is long-lived
 and takes independent requests: `_ChunkPipeline.run` is a loop over
 `submit` on a lane that lives inside that call, and the served handler
 (brpc_tpu/tensor_service.py) submits each call to one that lives as long
-as the server. The serial baseline keeps nothing in flight, so it retires
-each chunk on the calling thread.
+as the server.
 
-The serial baseline (the retired `device_path_mbps` loop: device_put ->
-compute -> block -> copy-back per chunk, nothing in flight) runs over
-the same chunks; `device_path_overlap_eff` = pipelined / serial
-throughput is the overlap win the ring buys.
-
-Run as a module for one JSON line (bench.py merges it):
-    python -m brpc_tpu.device_path [payload_mb] [reps] [ring_depth] [chunk_kb]
-That drives the first device; `run(..., device=d)` drives any one of
-`jax.devices()` (chip_smoke.py walks them all from one process).
+`run(..., device=d)` is the smoke's one verified ring pass over any one of
+`jax.devices()` (chip_smoke.py walks them all from one process); the
+measurement is `benchmark/run.py --workload bulk_64m_ring`.
 """
-import json
-import os
 import queue
-import sys
 import threading
 import time
 from functools import lru_cache
@@ -312,20 +302,12 @@ class DeviceLane:
 
 class _ChunkPipeline:
     """Drives the staging ring at a given depth over a fixed list of
-    chunks, pass after pass.
-
-    copy_mode=True reproduces the RETIRED device_path_mbps loop shape
-    per chunk — frame() with the payload memcpy, device_put (always a
-    copy), full sync, fresh ndarray materialization, copy-back — run at
-    depth 1 with nothing in flight. copy_mode=False is the ring path: a
-    loop over `DeviceLane.submit` (payload staged into the registered slot
-    by the pass that computes its crc32c, framed IN PLACE, dlpack
-    zero-copy import where the
-    platform backs arrays with host memory, donated device buffers
-    elsewhere, depth-N chunks in flight so H2D/compute/D2H of neighboring
-    chunks overlap) -- the same lane a served handler submits to
-    (brpc_tpu/tensor_service.py). The gap between the two is exactly what
-    the ISSUE-9 ring buys: no per-RPC copies, no per-chunk sync.
+    chunks, pass after pass: a loop over `DeviceLane.submit` (payload
+    staged into the registered slot by the pass that computes its crc32c,
+    framed IN PLACE, dlpack zero-copy import where the platform backs
+    arrays with host memory, donated device buffers elsewhere, depth-N
+    chunks in flight so H2D/compute/D2H of neighboring chunks overlap) --
+    the same lane a served handler submits to (brpc_tpu/tensor_service.py).
 
     Who runs where: `run()`'s caller launches every chunk, so `touch` is
     called in launch order on that thread. At depth 1 it also retires
@@ -341,13 +323,17 @@ class _ChunkPipeline:
     launcher's wait for the last retires, `ring.drain`, under the last
     `ring.pass`."""
 
-    def __init__(self, ring, chunks, dev, touch, depth, copy_mode):
+    def __init__(self, ring, chunks, dev, touch, depth, copy_mode=False):
+        if copy_mode:
+            # The per-copy loop went with ISSUE 31; the parameter waits for
+            # benchmark/drivers/ring.py to stop passing it (ROADMAP C14).
+            raise ValueError("copy_mode: the per-copy loop is gone "
+                             "(ISSUE 31); there is one ring path")
         self.ring = ring
         self.chunks = chunks          # list of uint32 chunk arrays
         self.dev = dev
         self.touch = touch
         self.depth = depth
-        self.copy_mode = copy_mode
         self.chunk_bytes = chunks[0].nbytes
         self.ok = True
         self.dev_checks = []
@@ -366,43 +352,6 @@ class _ChunkPipeline:
 
         lane.submit(stage, self.chunk_bytes, req, k + 1)
 
-    def _copy_chunk(self, k):
-        """The old path, one chunk start to end on this thread: frame()
-        memcpys the external payload into the staging buffer, device_put
-        copies it again, the answer is MATERIALIZED as a fresh ndarray,
-        copied back into staging, and the framework re-parses and
-        crc32c-verifies the whole frame around it."""
-        import jax
-        req = (self.passes, k)
-        clen = self.chunk_bytes
-        with spans.span("ring.launch", req):
-            with spans.span("ring.acquire", req):
-                try:
-                    slot = self.ring.acquire(ACQUIRE_TIMEOUT_US)
-                except TimeoutError:
-                    self.ring.abort()
-                    raise RuntimeError("staging-ring acquire timed out; "
-                                       "ring aborted") from None
-            sa = self.ring.slots[slot]
-            with spans.span("ring.frame", req):
-                flen = len(native.frame(k + 1, self.chunks[k], out=sa))
-            poff = flen - clen
-            with spans.span("ring.h2d", req):
-                x = jax.device_put(sa[poff:poff + clen].view(np.uint32),
-                                   self.dev)
-            with spans.span("ring.kernel_dispatch", req):
-                y, chk = self.touch(x)
-        with spans.span("ring.retire", req):
-            with spans.span("ring.d2h_wait", req):
-                back = np.array(y)
-                word = int(chk)
-            with spans.span("ring.verify", req):
-                np.copyto(sa[poff:poff + clen].view(np.uint32), back)
-                cid, _, _ = native.unframe(sa[:flen])
-            self._retired(req, back, word, cid == k + 1)
-            with spans.span("ring.complete", req):
-                self.ring.complete(slot)
-
     def run(self, reps):
         """`reps` passes over the chunks; every chunk launched here is
         retired when this returns. Seconds taken."""
@@ -415,10 +364,6 @@ class _ChunkPipeline:
                 # chunks still in flight then retire beside the next
                 # pass's launches, or during the drain below.
                 with spans.span("ring.pass", (self.passes, None)):
-                    if self.copy_mode:
-                        for k in range(len(self.chunks)):
-                            self._copy_chunk(k)
-                        continue
                     if lane is None:
                         # Under the first pass's span, so that the
                         # launcher's self times cover all of its time.
@@ -445,94 +390,36 @@ class _ChunkPipeline:
 
 def run(payload_mb: int = 4, reps: int = 5, ring_depth: int = 4,
         chunk_kb: int = 2044, device=None) -> dict:
+    """The smoke's ring pass: `payload_mb` in chunks of `chunk_kb` through
+    one depth-`ring_depth` ring, a warm-up pass and `reps` timed ones,
+    every chunk's crc32c verdict and every on-device word held to the
+    host's. GB/s counts a verified byte once, as benchmark/stats.gbps."""
     from brpc_tpu import compile_cache
 
     compile_cache.enable()
     dev = _resolve_device(device)
     chunk_bytes = (chunk_kb << 10) & ~4095
     n_chunks = max(1, (payload_mb << 20) // chunk_bytes)
-    payload_bytes = n_chunks * chunk_bytes
-    payload = np.arange(payload_bytes // 4, dtype=np.uint32)
-    chunks = [payload[i * (chunk_bytes // 4):(i + 1) * (chunk_bytes // 4)]
-              for i in range(n_chunks)]
-    # Room for the in-place headroom (ring path) AND the copy-mode
-    # frame() headroom contract (payload + 1024).
-    slot_bytes = chunk_bytes + 1024
-
+    payload = np.arange(n_chunks * chunk_bytes // 4, dtype=np.uint32)
+    chunks = np.split(payload, n_chunks)
     touch = _touch_kernel(chunk_bytes // 4, dev.platform)
-
-    def make_ring():
-        return native.DeviceStagingRing(ring_depth, slot_bytes)
-
-    # Warmup: compile + first transfers through a throwaway ring.
-    warm = make_ring()
-    _ChunkPipeline(warm, chunks, dev, touch, ring_depth, False).run(1)
-    _ChunkPipeline(warm, chunks, dev, touch, 1, True).run(1)
-    warm.close()
-
-    # Serial baseline = the retired device_path_mbps loop shape (per-RPC
-    # copies + full sync per chunk, nothing in flight); pipelined =
-    # depth-N ring, in-place frames, zero-copy import, H2D/compute/D2H
-    # of neighboring chunks overlapped. The two are INTERLEAVED rep by
-    # rep and combined by median so shared-host scheduling noise hits
-    # both paths alike instead of fabricating (or erasing) the gap.
-    ring_s = make_ring()
-    ring_p = make_ring()
-    serial = _ChunkPipeline(ring_s, chunks, dev, touch, 1, True)
-    pipe = _ChunkPipeline(ring_p, chunks, dev, touch, ring_depth, False)
-    # Each timed sample spans `passes` full passes over the chunks so
-    # the pipeline reaches steady state (fill/drain amortized); several
-    # alternating samples -> median.
-    passes = max(2, (4 * ring_depth + n_chunks - 1) // n_chunks)
-    samples = max(3, reps // passes)
-    serial_dts, pipe_dts = [], []
-    for _ in range(samples):
-        serial_dts.append(serial.run(passes) / passes)
-        pipe_dts.append(pipe.run(passes) / passes)
-    import statistics
-    dt_serial = statistics.median(serial_dts) * reps
-    dt_pipe = statistics.median(pipe_dts) * reps
-    # Overlap efficiency from ADJACENT sample pairs: each ratio compares
-    # a serial and a pipelined pass that ran back to back, so shared-host
-    # cpu throttling (which swings absolute GB/s several-fold here)
-    # cancels out of the ratio instead of fabricating or erasing the gap.
-    overlap_eff = statistics.median(
-        s / p for s, p in zip(serial_dts, pipe_dts))
-    highwater = ring_p.inflight_highwater
-    registered = ring_p.registered
-    ring_s.close()
-    ring_p.close()
-
-    # Every on-device integrity word, on both paths and every pass, must
-    # equal the independent numpy computation over the same chunk.
-    host_chk = [_integrity_word_host(c) for c in chunks]
-    want = host_chk * (passes * samples)
-    dev_ok = pipe.dev_checks == want and serial.dev_checks == want
-    ok = serial.ok and pipe.ok and dev_ok
-
-    # Bytes cross host->device and device->host once per chunk per rep.
-    gbps = 2.0 * payload_bytes * reps / dt_pipe / 1e9
-    serial_gbps = 2.0 * payload_bytes * reps / dt_serial / 1e9
+    ring = native.DeviceStagingRing(ring_depth, chunk_bytes + 1024)
+    try:
+        pipe = _ChunkPipeline(ring, chunks, dev, touch, ring_depth)
+        pipe.run(1)  # warm-up: compiles, first transfers
+        pipe.dev_checks.clear()
+        seconds = pipe.run(reps)
+        highwater = ring.inflight_highwater
+        registered = ring.registered
+    finally:
+        ring.close()
+    want = [_integrity_word_host(c) for c in chunks] * reps
     return {
-        "device_path_gbps": round(gbps, 3),
-        "device_path_serial_gbps": round(serial_gbps, 3),
-        "device_path_overlap_eff": round(overlap_eff, 2),
+        "device_path_gbps": round(payload.nbytes * reps / seconds / 1e9, 3),
+        "device_path_ok": bool(pipe.ok and pipe.dev_checks == want),
         "device_path_ring_depth": ring_depth,
         "device_path_chunk_bytes": chunk_bytes,
         "device_path_inflight_highwater": int(highwater),
-        "device_path_ok": bool(ok),
         "device_path_registered_staging": bool(registered),
         "device_path_device": f"{dev.platform}:{dev.device_kind}",
-        # Overlap needs a core for the device kernel next to the staging
-        # thread: on single-core (or cgroup-throttled-to-one) hosts the
-        # pipeline degenerates to the copy-elimination win alone.
-        "device_path_cores": int(os.cpu_count() or 1),
     }
-
-
-if __name__ == "__main__":
-    mb = int(sys.argv[1]) if len(sys.argv) > 1 else 4
-    reps = int(sys.argv[2]) if len(sys.argv) > 2 else 5
-    depth = int(sys.argv[3]) if len(sys.argv) > 3 else 4
-    chunk_kb = int(sys.argv[4]) if len(sys.argv) > 4 else 1020
-    print(json.dumps(run(mb, reps, depth, chunk_kb)))

@@ -8,9 +8,9 @@ consumers preempted (a sequence whose sink hasn't drained its last
 grant yields its slot instead of growing a queue) and an optional
 per-tenant slot cap so one tenant can't own the whole batch.
 
-Unit-tested in tests/test_infer_sched.py; `simulate()` is the analytic
-side of bench.py's infer_scrape round — it predicts the batched vs
-unbatched tokens/s ratio the live binary must reproduce.
+Unit-tested in tests/test_infer_sched.py; `simulate()` predicts the
+batched vs unbatched tokens/s ratio of examples/infer_server.cc (nothing
+measures the live binary against it: ROADMAP C16).
 """
 from __future__ import annotations
 
@@ -108,7 +108,7 @@ class MicroBatchScheduler:
 
 def simulate(n_seqs: int, tokens_each: int, max_batch: int = 8,
              unbatched: bool = False, step_us: int = 2000) -> dict:
-    """Closed-form-ish throughput model for bench.py's infer_scrape:
+    """Closed-form-ish throughput model of examples/infer_server.cc:
     run n_seqs identical sequences to completion with an always-ready
     consumer; report steps, tokens and tokens/s at the given step cost.
     Batched serving amortizes the step across the batch width — the

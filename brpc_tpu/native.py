@@ -28,7 +28,7 @@ _LIB = None
 def build(repo: Path = _REPO, timeout: float | None = None) -> Path:
     """cmake -G Ninja + ninja into `<repo>/build`; returns that directory.
 
-    The one build rule for chip_smoke.py, bench.py and the tests: a
+    The one build rule for chip_smoke.py, the benchmark and the tests: a
     `build/` that was not configured for THIS checkout (its
     CMAKE_HOME_DIRECTORY names another path — a copied tree, which is what
     the chip tool makes — or it has no cache at all) is discarded and
@@ -78,7 +78,6 @@ def lib() -> ctypes.CDLL:
             fn.argtypes = [ctypes.c_uint32, ctypes.c_void_p, ctypes.c_void_p,
                            ctypes.c_size_t]
         L.tpurpc_stage_fused_bytes.restype = ctypes.c_long
-        L.tpurpc_frame_crc_pass_bytes.restype = ctypes.c_long
         L.tpurpc_block_alloc.restype = ctypes.c_void_p
         L.tpurpc_block_alloc.argtypes = [ctypes.c_size_t]
         L.tpurpc_block_free.argtypes = [ctypes.c_void_p]
@@ -136,7 +135,7 @@ def lib() -> ctypes.CDLL:
         L.tpurpc_frame_in_place.restype = ctypes.c_long
         L.tpurpc_frame_in_place.argtypes = [
             ctypes.c_uint64, ctypes.c_void_p, ctypes.c_size_t,
-            ctypes.c_size_t, ctypes.POINTER(ctypes.c_uint32),
+            ctypes.c_size_t, ctypes.c_uint32,
             ctypes.POINTER(ctypes.c_size_t),
         ]
         L.tpurpc_unframe.restype = ctypes.c_long
@@ -213,14 +212,9 @@ def copy_crc32c(dst: np.ndarray, src: np.ndarray, init: int = 0,
 
 
 def staging_counters() -> dict:
-    """/vars rpc_stage_fused_bytes (bytes staged with their crc32c in one
-    pass: `copy_crc32c`, `ParkedCall.copy_into`) and
-    rpc_frame_crc_pass_bytes (payload bytes the in-place framer had to walk
-    itself: `frame`'s aliasing fast path only; a lane pass or a served
-    call leaves it where it was)."""
-    return {"rpc_stage_fused_bytes": int(lib().tpurpc_stage_fused_bytes()),
-            "rpc_frame_crc_pass_bytes":
-                int(lib().tpurpc_frame_crc_pass_bytes())}
+    """/vars rpc_stage_fused_bytes: bytes staged with their crc32c in one
+    pass (`copy_crc32c`, `ParkedCall.copy_into`)."""
+    return {"rpc_stage_fused_bytes": int(lib().tpurpc_stage_fused_bytes())}
 
 
 def stage_dump() -> dict:
@@ -272,8 +266,8 @@ def pool_epoch() -> int:
 
 
 def lease_counters() -> tuple[int, int]:
-    """(live pinned blocks, reaped pins) — the leak evidence bench.py
-    records after every round (a healthy round ends pinned == 0)."""
+    """(live pinned blocks, reaped pins) — the leak evidence: a healthy
+    round ends pinned == 0 (the soaks read the same two on /pools)."""
     L = lib()
     return int(L.tpurpc_lease_pinned()), int(L.tpurpc_lease_reaped())
 
@@ -538,34 +532,13 @@ class StepChannel:
             lib().tpurpc_channel_close(ptr)
 
 
-def _within(buf: np.ndarray, payload: np.ndarray) -> bool:
-    b0 = buf.ctypes.data
-    p0 = payload.ctypes.data
-    return b0 <= p0 and p0 + payload.nbytes <= b0 + buf.nbytes
-
-
 def frame(correlation_id: int, payload: np.ndarray,
           out: np.ndarray | None = None) -> np.ndarray:
     """tpu_std-frame `payload` (any contiguous array) via the C++
-    framework; returns a uint8 view of the frame (in `out` if given).
-
-    Fast path (ISSUE 9 satellite): when `payload` is itself a view INTO
-    `out` (already staged inside the destination pool buffer, at offset
-    >= 64), the payload bytes are NOT copied — the header+meta is
-    written in place right before them and the returned frame view ends
-    exactly at the payload's end."""
+    framework, which copies it behind the header + meta it writes and
+    walks it for the crc32c; returns a uint8 view of the frame (in `out`
+    if given). A payload staged in a ring slot is `frame_in_place`'s."""
     pay = np.ascontiguousarray(payload).view(np.uint8).reshape(-1)
-    if out is not None and _within(out, pay):
-        off = pay.ctypes.data - out.ctypes.data
-        if off >= IN_PLACE_HEADROOM:
-            # Staged by the caller's own hand, so no crc comes with the
-            # bytes: the framer walks them (rpc_frame_crc_pass_bytes).
-            frame_off, n = _frame_in_place(correlation_id, out, off,
-                                           pay.nbytes, None)
-            return out[frame_off:frame_off + n]
-        # Payload sits too close to the buffer start for an in-place
-        # header: fall through to the copy path (tpurpc_frame memmoves
-        # overlapping sources safely).
     cap = pay.nbytes + 1024
     if out is None:
         out = np.empty(cap, dtype=np.uint8)
@@ -590,19 +563,13 @@ def frame_in_place(correlation_id: int, buf: np.ndarray, payload_off: int,
     writes header+meta right-justified before it -- no payload memcpy and
     no pass over the payload either: `crc` is its crc32c from the pass
     that staged it (`copy_crc32c`, `ParkedCall.copy_into`) and is what the
-    meta embeds. Returns (frame_off, frame_len)."""
-    return _frame_in_place(correlation_id, buf, payload_off, payload_len,
-                           ctypes.byref(ctypes.c_uint32(crc)))
-
-
-def _frame_in_place(correlation_id, buf, payload_off, payload_len, crc_ref):
-    """`crc_ref` None is `frame`'s aliasing fast path alone: the C++ framer
-    walks the payload for its crc32c itself."""
+    meta embeds; one that is no integer is a TypeError. Returns
+    (frame_off, frame_len)."""
     b = buf.view(np.uint8).reshape(-1)
     frame_off = ctypes.c_size_t()
     n = lib().tpurpc_frame_in_place(
         correlation_id, b.ctypes.data_as(ctypes.c_void_p), payload_off,
-        payload_len, crc_ref, ctypes.byref(frame_off))
+        payload_len, ctypes.c_uint32(crc), ctypes.byref(frame_off))
     if n < 0:
         raise ValueError("tpurpc_frame_in_place failed (headroom < meta)")
     return int(frame_off.value), int(n)

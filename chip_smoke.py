@@ -318,7 +318,6 @@ def leg_device() -> dict:
             if r["device_path_inflight_highwater"] > 4:
                 raise LegFailed(f"{dev}: ring window exceeded: {r}")
             rec[f"gbps_{label}"] = r["device_path_gbps"]
-            rec[f"serial_gbps_{label}"] = r["device_path_serial_gbps"]
             rec[f"chunk_bytes_{label}"] = r["device_path_chunk_bytes"]
         chips.append({"device": str(dev), "verified": True, **rec})
     return {"chips": chips, "note": "GB/s are observations"}

@@ -16,7 +16,7 @@
 
 // Always-on by default: the whole point of a flight recorder is that it is
 // already running when the crash happens. -flight_recorder_enabled=0 exists
-// for the overhead bench (bench.py blackbox_scrape) and A/B debugging.
+// for pricing the recorder itself (ROADMAP A9) and A/B debugging.
 DEFINE_bool(flight_recorder_enabled, true,
             "Record flight events into per-thread rings");
 DEFINE_int64(flight_recorder_ring, 4096,

@@ -358,16 +358,16 @@ TEST(Fiber, PingPongThroughput) {
     // style) — also a smoke test that heavy switching doesn't corrupt state.
     struct Ctx {
         void* b;
-        int rounds = 0;
+        // The two runners may be on two workers at once: a plain int
+        // loses increments under load (seen with six suites at a time).
+        std::atomic<int> rounds{0};
     } ctx;
     ctx.b = butex_create();
     butex_word(ctx.b)->store(0);
     auto runner = [](void* arg) -> void* {
         Ctx* c = (Ctx*)arg;
         for (int i = 0; i < 2000; ++i) {
-            std::atomic<int>* w = butex_word(c->b);
-            int v = w->load();
-            w->store(v + 1);
+            butex_word(c->b)->fetch_add(1);
             ++c->rounds;
             butex_wake(c->b);
             fiber_yield();
@@ -379,7 +379,7 @@ TEST(Fiber, PingPongThroughput) {
     fiber_start_background(&b2, nullptr, runner, &ctx);
     fiber_join(a, nullptr);
     fiber_join(b2, nullptr);
-    EXPECT_EQ(ctx.rounds, 4000);
+    EXPECT_EQ(ctx.rounds.load(), 4000);
     butex_destroy(ctx.b);
 }
 

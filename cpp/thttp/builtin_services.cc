@@ -418,7 +418,7 @@ void HandleRpczTrace(Server*, const HttpRequest& req, HttpResponse* res) {
 
 void HandleStatus(Server* server, const HttpRequest& req,
                   HttpResponse* res) {
-    // ?format=json: the machine form — bench.py and the soak tests
+    // ?format=json: the machine form — the benchmark and the soak tests
     // consume per-method MethodStatus without scraping the text table.
     // Method names are pb identifiers + '_', so no JSON escaping needed.
     if (req.QueryParam("format") == "json") {
@@ -795,7 +795,7 @@ void HandlePools(Server*, const HttpRequest& req, HttpResponse* res) {
 // soak asserts on.
 // /streams: push-stream tier (ISSUE 17) — the rpc_stream_* counters,
 // replay-ring high-water and one row per live server/client stream;
-// ?format=json is what the restart soak and bench.py scrape.
+// ?format=json is what the restart soak scrapes.
 void HandleStreams(Server*, const HttpRequest& req, HttpResponse* res) {
     if (req.QueryParam("format") == "json") {
         res->set_content_type("application/json");

@@ -445,8 +445,10 @@ void ProcessH2ClientFrame(InputMessageBase* raw) {
                 last_id = ntohl(last_id) & 0x7fffffffu;
             }
             if (error_code != 0) {
-                FailAllStreams(sess, TERR_FAILED_SOCKET);
+                // The socket first: a caller woken by its stream's error
+                // must not find the rejected connection still live.
                 s->SetFailedWithError(TERR_FAILED_SOCKET);
+                FailAllStreams(sess, TERR_FAILED_SOCKET);
                 break;
             }
             // NO_ERROR: the server promises to answer every stream at or

@@ -462,7 +462,8 @@ int Socket::SetFailedWithError(int error_code) {
 
 // ---------------- write path ----------------
 
-int Socket::Write(IOBuf* data, uint64_t notify_id, int64_t enqueued_us) {
+int Socket::Write(IOBuf* data, uint64_t notify_id, int64_t enqueued_us,
+                  int fail_after) {
     if (Failed()) {
         errno = TERR_FAILED_SOCKET;
         return -1;
@@ -478,6 +479,7 @@ int Socket::Write(IOBuf* data, uint64_t notify_id, int64_t enqueued_us) {
     WriteRequest* req = new WriteRequest;
     req->notify_id = notify_id;
     req->enqueued_us = enqueued_us;
+    req->fail_after = fail_after;
     req->data.swap(*data);
     req->next.store(WriteRequest::unlinked(), std::memory_order_relaxed);
     const int64_t queued =
@@ -876,9 +878,16 @@ bool Socket::FlushOnce(bool allow_block) {
                 if (posted_us == 0) posted_us = stage::now_us();
                 stage::Add(stage::kWriteQueue, posted_us - enqueued_us);
             }
+            const int fail_after =
+                inflight_batch_[inflight_index_]->fail_after;
             delete inflight_batch_[inflight_index_];
             ++inflight_index_;
             ++consumed;
+            if (__builtin_expect(fail_after != 0, 0)) {
+                SetFailedWithError(fail_after);
+                DrainWriteQueue();
+                return true;
+            }
         }
     }
 }

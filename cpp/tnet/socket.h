@@ -106,7 +106,12 @@ public:
     // `enqueued_us`: the caller's stage-clock read as it enqueues the
     // frame (tvar/stage_recorder.h); the writer adds tnet.write_queue as
     // it posts the frame's last byte. 0 = not sampled.
-    int Write(IOBuf* data, uint64_t notify_id = 0, int64_t enqueued_us = 0);
+    // `fail_after`: non-zero fails the socket with that error once this
+    // request's last byte is posted -- a final reply the peer must read
+    // before the connection goes (a rejected credential). What is queued
+    // behind it is dropped.
+    int Write(IOBuf* data, uint64_t notify_id = 0, int64_t enqueued_us = 0,
+              int fail_after = 0);
 
     // ---- read path (called by EventDispatcher) ----
     static void OnInputEventById(SocketId id);
@@ -415,6 +420,7 @@ private:
         IOBuf data;
         uint64_t notify_id = 0;
         int64_t enqueued_us = 0;  // stage clock, see Write
+        int fail_after = 0;       // see Write
         static WriteRequest* unlinked() { return (WriteRequest*)0x1; }
     };
 

@@ -67,7 +67,7 @@ private:
 // recently returned socket: sockets shard across the epoll loops by fd,
 // and the old LIFO stack kept re-dispatching the whole pooled load onto
 // the one or two hottest fds — the direct cause of pooled-TCP QPS
-// landing below single-connection in BENCH_r05 (ISSUE 7).
+// landing below single-connection in a pre-PR-1 record (ISSUE 7).
 class SocketPool {
 public:
     static SocketPool* singleton();

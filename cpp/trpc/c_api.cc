@@ -46,10 +46,8 @@ uint32_t tpurpc_crc32c(uint32_t init, const void* data, size_t n) {
 
 namespace {
 // What says the one-pass staging engaged (ISSUE 30): bytes that went into
-// a staging buffer with their crc32c in one pass, and bytes
-// tpurpc_frame_in_place had to walk itself because no crc came with them.
+// a staging buffer with their crc32c in one pass.
 tpurpc::LazyAdder g_stage_fused_bytes("rpc_stage_fused_bytes");
-tpurpc::LazyAdder g_frame_crc_pass_bytes("rpc_frame_crc_pass_bytes");
 }  // namespace
 
 uint32_t tpurpc_crc32c_copy(uint32_t init, void* dst, const void* src,
@@ -65,10 +63,6 @@ uint32_t tpurpc_crc32c_copy_tables(uint32_t init, void* dst, const void* src,
 
 long tpurpc_stage_fused_bytes() {
     return (long)(*g_stage_fused_bytes).get_value();
-}
-
-long tpurpc_frame_crc_pass_bytes() {
-    return (long)(*g_frame_crc_pass_bytes).get_value();
 }
 
 void* tpurpc_block_alloc(size_t n) {
@@ -380,7 +374,6 @@ void* tpurpc_server_start(int port) {
     *g_tensor_bytes_in << 0;
     *g_tensor_failed << 0;
     *g_stage_fused_bytes << 0;
-    *g_frame_crc_pass_bytes << 0;
     static auto* highwater = [] {
         auto* v = new tpurpc::PassiveStatus<int64_t>(ParkedHighwater,
                                                      nullptr);
@@ -564,13 +557,8 @@ long tpurpc_frame(uint64_t correlation_id, const void* payload, size_t n,
     char* o = (char*)out;
     char* att_pos = o + kHeaderLen + meta_str.size();
     // Payload placement FIRST (memmove: the source may overlap the
-    // header/meta region about to be written). When the payload already
-    // sits exactly at the frame's attachment position — staged in place
-    // inside the destination pool buffer — the copy is skipped entirely:
-    // the frame costs a header+meta write and the crc read only.
-    if ((const char*)payload != att_pos) {
-        memmove(att_pos, payload, n);
-    }
+    // header/meta region about to be written).
+    memmove(att_pos, payload, n);
     write_frame_header(o, meta_str.size(), n);
     memcpy(o + kHeaderLen, meta_str.data(), meta_str.size());
     return (long)frame_len;
@@ -578,17 +566,10 @@ long tpurpc_frame(uint64_t correlation_id, const void* payload, size_t n,
 
 long tpurpc_frame_in_place(uint64_t correlation_id, void* buf,
                            size_t payload_off, size_t payload_len,
-                           const uint32_t* payload_crc, size_t* frame_off) {
+                           uint32_t payload_crc, size_t* frame_off) {
     char* b = (char*)buf;
-    uint32_t crc;
-    if (payload_crc != nullptr) {
-        crc = *payload_crc;
-    } else {
-        crc = tpurpc::crc32c_extend(0, b + payload_off, payload_len);
-        *g_frame_crc_pass_bytes << (int64_t)payload_len;
-    }
     std::string meta_str;
-    if (!frame_meta(correlation_id, payload_len, crc, &meta_str)) {
+    if (!frame_meta(correlation_id, payload_len, payload_crc, &meta_str)) {
         return -1;
     }
     const size_t prefix = kHeaderLen + meta_str.size();

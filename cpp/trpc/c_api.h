@@ -34,12 +34,8 @@ uint32_t tpurpc_crc32c_copy(uint32_t init, void* dst, const void* src,
 uint32_t tpurpc_crc32c_copy_tables(uint32_t init, void* dst, const void* src,
                                    size_t n);
 // /vars rpc_stage_fused_bytes: bytes staged with their crc32c in one pass
-// (tpurpc_crc32c_copy, tpurpc_call_copy_out). /vars
-// rpc_frame_crc_pass_bytes: payload bytes tpurpc_frame_in_place walked
-// itself because no crc came with them; over a lane pass or a served call
-// it does not move.
+// (tpurpc_crc32c_copy, tpurpc_call_copy_out).
 long tpurpc_stage_fused_bytes();
-long tpurpc_frame_crc_pass_bytes();
 
 // Registered-memory staging buffers from the ICI block pool. Allocation
 // routes through the slab-class allocator (recyclable; ISSUE 9c) for
@@ -87,7 +83,7 @@ long tpurpc_stage_dump(char* out, size_t cap);
 // Crash-safety counters of the pinned-block lease registry
 // (tici/block_lease.h): live pins, expiry-reaped pins, and the local
 // pool's current epoch — the leak/staleness evidence the device-ring
-// tests and bench.py record.
+// tests read.
 uint64_t tpurpc_lease_pinned();
 uint64_t tpurpc_lease_reaped();
 uint64_t tpurpc_pool_epoch();
@@ -199,10 +195,8 @@ void tpurpc_channel_close(void* channel);
 
 // Frame `payload` as one tpu_std frame: "TRPC" header + RpcMeta
 // {correlation_id, body_checksum=crc32c(payload)} + payload as raw
-// attachment. Writes into out[0..out_cap). Returns the frame size in
-// bytes, or -1 if out_cap is too small. When `payload` ALREADY sits at
-// the frame's attachment position inside `out` (exact aliasing), the
-// payload memcpy is skipped — header + meta write + crc only.
+// attachment. Writes into out[0..out_cap) (`payload` may overlap it).
+// Returns the frame size in bytes, or -1 if out_cap is too small.
 long tpurpc_frame(uint64_t correlation_id, const void* payload, size_t n,
                   void* out, size_t out_cap);
 
@@ -210,18 +204,16 @@ long tpurpc_frame(uint64_t correlation_id, const void* payload, size_t n,
 // payload ALREADY lives at buf[payload_off .. payload_off+payload_len);
 // the header + meta are written right-justified immediately before it,
 // so the finished frame occupies buf[*frame_off .. payload_off+
-// payload_len) with NO payload copy. *payload_crc is the payload's
+// payload_len) with NO payload copy. payload_crc is the payload's
 // crc32c, computed by the pass that staged it (tpurpc_crc32c_copy,
 // tpurpc_call_copy_out), and is what the meta embeds: header + meta only,
-// no pass over the payload either (ISSUE 30). NULL, for a payload staged
-// some other way (tpurpc_frame's aliasing caller, native.frame): the
-// framer walks it itself, rpc_frame_crc_pass_bytes += payload_len.
+// the payload is never read (ISSUE 30).
 // Requires payload_off >= the header+meta size (~64 bytes is always
 // enough). Returns the frame length and sets *frame_off; -1 when the
 // prefix space is too small.
 long tpurpc_frame_in_place(uint64_t correlation_id, void* buf,
                            size_t payload_off, size_t payload_len,
-                           const uint32_t* payload_crc, size_t* frame_off);
+                           uint32_t payload_crc, size_t* frame_off);
 
 // Parse ONE frame at buf[0..n): verifies the header, meta, and
 // body_checksum. On success returns bytes consumed and sets *cid,

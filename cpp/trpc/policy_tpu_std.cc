@@ -767,8 +767,11 @@ FiberAttr UserCallAttr(Server* server, UserCallArgs* uc) {
     return attr;
 }
 
+// `fail_after`: the socket fails with that error once this reply is
+// posted (Socket::Write).
 void SendErrorResponse(SocketId sid, uint64_t cid, int err,
-                       const std::string& text, int64_t backoff_ms = 0) {
+                       const std::string& text, int64_t backoff_ms = 0,
+                       int fail_after = 0) {
     rpc::RpcMeta meta;
     meta.mutable_response()->set_error_code(err);
     meta.mutable_response()->set_error_text(text);
@@ -782,7 +785,9 @@ void SendErrorResponse(SocketId sid, uint64_t cid, int err,
     PackTpuStdFrame(&frame, meta_buf, IOBuf(), IOBuf());
     SocketUniquePtr s;
     if (Socket::AddressSocket(sid, &s) == 0) {
-        s->Write(&frame);
+        if (s->Write(&frame, 0, 0, fail_after) != 0 && fail_after != 0) {
+            s->SetFailedWithError(fail_after);  // no reply could be queued
+        }
     }
 }
 
@@ -876,8 +881,9 @@ void ProcessTpuStdRequest(TpuStdMessage* msg, const rpc::RpcMeta& meta) {
         if (!meta.has_auth_data() ||
             server->options().auth->VerifyCredential(
                 meta.auth_data(), s->remote_side(), &actx) != 0) {
-            SendErrorResponse(sid, cid, TERR_AUTH, "authentication failed");
-            s->SetFailedWithError(TERR_AUTH);
+            // The caller reads TERR_AUTH, then the connection goes.
+            SendErrorResponse(sid, cid, TERR_AUTH, "authentication failed",
+                              0, TERR_AUTH);
             return;
         }
         s->SetAuthenticated(actx.user());

@@ -326,8 +326,8 @@ int main(int argc, char** argv) {
         return 1;
     }
     g_sched.Start();
-    // Scripted-boot handshake (bench.py infer_scrape / the soaks use
-    // the same contract as mesh_node).
+    // Scripted-boot handshake (the same contract as mesh_node, which
+    // the soaks use).
     printf("READY %d\n", server.listened_port());
     fflush(stdout);
     printf("InferServer on :%d — step %lldus, batch %d%s; try\n"

@@ -256,9 +256,10 @@ def test_deadline_budget_soak(cpp_build, tmp_path):
     deadlines, both below the learned ~50 ms service time -> shed by the
     TimeoutConcurrencyLimiter at admission), a raw probe fiber sends
     handcrafted frames stamped timeout_ms=0 (the wire shape of a client
-    that already gave up -> expired-on-arrival shed), and one node gets
-    reset-chaos on its client side to provoke retries against the
-    configured retry budget.
+    that already gave up -> expired-on-arrival shed), every server fails
+    three in ten of the calls it admitted with a retriable error, and one
+    node gets reset-chaos on its client side too, to provoke retries
+    against the configured retry budget.
 
     Asserted:
       * expired requests are SHED, not executed (rpc_server_expired_requests
@@ -301,12 +302,22 @@ def test_deadline_budget_soak(cpp_build, tmp_path):
         # --- delay-heavy phase -----------------------------------------
         for n in nodes:
             n.send("delay 50 30")
-        # Reset-chaos on node 2's client side: connection-level failures
-        # are retryable, so its channels retry until the budget is dry.
+        # What drains the budget: every server answers 30 % of the calls
+        # it admitted TERR_OVERCROWDED (the handler seam: retriable, and
+        # with all three alike the outlier tier has no one to route
+        # around), so an LB call wants 0.3 re-issues where a success earns
+        # 0.1 back and the 20 tokens are gone in about a hundred calls.
+        # Reset-chaos on node 2's client side alone never did that (ISSUE
+        # 31): health checks route around a connection that resets, one
+        # call in 2-3 s failed retryably against 6-8 tokens a second
+        # earned back, and rpc_retry_budget_exhausted stayed 0. It stays in
+        # the phase for the connection-level failures and reconnects.
+        for p in ports[:2]:
+            _chaos(p, enable=1, seed=4242, plan="error_rate=0.3")
         others = ",".join(
             "127.0.0.1:%d" % p for i, p in enumerate(ports) if i != 2)
-        _chaos(ports[2], enable=1, seed=4242, plan="reset=0.3",
-               peers=others)
+        _chaos(ports[2], enable=1, seed=4242,
+               plan="reset=0.3,error_rate=0.3", peers=others)
 
         # Shedding and budget exhaustion become observable within the
         # phase (bounded poll beats a fixed sleep on a loaded host).
@@ -326,7 +337,8 @@ def test_deadline_budget_soak(cpp_build, tmp_path):
         assert exhausted >= 1, "retry budget never exhausted under chaos"
 
         # --- heal + drain ----------------------------------------------
-        _chaos(ports[2], enable=0)
+        for p in ports:
+            _chaos(p, enable=0)
         for n in nodes:
             n.send("delay 0 0")
         time.sleep(1.5)

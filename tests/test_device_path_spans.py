@@ -88,7 +88,7 @@ def test_every_chunk_has_its_launch_and_retire_with_their_children(pipeline):
 def test_a_pass_stages_every_byte_fused_and_the_framer_walks_none(pipeline):
     """ISSUE 30's counters over the ring pass: `ring.stage` is the one
     pass over a chunk's bytes (copy + crc32c), `ring.frame` a header and a
-    meta."""
+    meta from that crc: the framer has no way to read a payload."""
     from brpc_tpu import native
 
     before = native.staging_counters()
@@ -97,16 +97,6 @@ def test_a_pass_stages_every_byte_fused_and_the_framer_walks_none(pipeline):
     after = native.staging_counters()
     assert (after["rpc_stage_fused_bytes"] - before["rpc_stage_fused_bytes"]
             == 3 * len(pipeline.chunks) * pipeline.chunk_bytes)
-    assert (after["rpc_frame_crc_pass_bytes"]
-            == before["rpc_frame_crc_pass_bytes"])
-    # The walk is still there for the one caller that stages by hand.
-    buf = native.PoolBuffer(1 << 16)
-    region = buf.array[64:64 + 4096]
-    region[:] = 7
-    native.frame(1, region, out=buf.array)
-    assert (native.staging_counters()["rpc_frame_crc_pass_bytes"]
-            == after["rpc_frame_crc_pass_bytes"] + 4096)
-    buf.free()
 
 
 def test_self_times_and_remainder_add_up_to_the_wall_time(pipeline):

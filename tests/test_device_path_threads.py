@@ -30,7 +30,7 @@ def time_limit():
 
 @pytest.fixture
 def make_pipeline(cpp_build, monkeypatch):
-    """make_pipeline(depth, touch=None, copy_mode=False, ring_depth=3) ->
+    """make_pipeline(depth, touch=None, ring_depth=3) ->
     (pipeline, ring, chunks) on the CPU backend, compiled and warm."""
     import jax
 
@@ -45,15 +45,14 @@ def make_pipeline(cpp_build, monkeypatch):
     kernel = device_path._touch_kernel(per, dev.platform)
     rings = []
 
-    def make(depth, touch=None, copy_mode=False, ring_depth=RING_DEPTH):
+    def make(depth, touch=None, ring_depth=RING_DEPTH):
         ring = native.DeviceStagingRing(ring_depth, CHUNK_BYTES + 1024)
         rings.append(ring)
-        warm = device_path._ChunkPipeline(ring, chunks, dev, kernel, depth,
-                                          copy_mode)
+        warm = device_path._ChunkPipeline(ring, chunks, dev, kernel, depth)
         warm.run(1)  # compile, first transfers
         pipe = device_path._ChunkPipeline(
             ring, chunks, dev, kernel if touch is None else touch(kernel),
-            depth, copy_mode)
+            depth)
         spans.clear()
         return pipe, ring, chunks
 
@@ -96,9 +95,8 @@ def test_retires_in_launch_order_on_one_other_thread(make_pipeline):
     assert retired == [(p, k) for p in (1, 2, 3) for k in range(N_CHUNKS)]
 
 
-@pytest.mark.parametrize("copy_mode", [False, True])
-def test_depth_one_stays_on_the_calling_thread(make_pipeline, copy_mode):
-    pipe, ring, chunks = make_pipeline(1, copy_mode=copy_mode)
+def test_depth_one_stays_on_the_calling_thread(make_pipeline):
+    pipe, ring, chunks = make_pipeline(1)
     highwater = ring.inflight_highwater  # the warm-up's, at depth 1 too
     threads = threading.active_count()
     pipe.run(2)
@@ -108,6 +106,20 @@ def test_depth_one_stays_on_the_calling_thread(make_pipeline, copy_mode):
     assert "ring.retire" in names and "ring.drain" not in names
     assert {rec[4] for rec in spans.snapshot()} == {threading.get_ident()}
     assert threading.active_count() == threads
+
+
+def test_the_per_copy_loop_is_refused(make_pipeline):
+    """ISSUE 31: the sixth parameter stays for benchmark/drivers/ring.py,
+    which passes False; anything truthy names the issue and stores nothing."""
+    from brpc_tpu import device_path
+
+    pipe, ring, chunks = make_pipeline(1)
+    with pytest.raises(ValueError, match="ISSUE 31"):
+        device_path._ChunkPipeline(ring, chunks, pipe.dev, pipe.touch, 1,
+                                   True)
+    again = device_path._ChunkPipeline(ring, chunks, pipe.dev, pipe.touch, 1,
+                                       False)
+    assert not hasattr(again, "copy_mode")
 
 
 class CopyBackFailed(Exception):

@@ -21,21 +21,23 @@ from test_metrics_lint import _lint_exposition
 
 
 def _section_rows(text, header_token):
-    """Rows of the /loops table whose header contains `header_token`."""
-    lines = text.splitlines()
+    """Rows of the /loops table whose header contains `header_token`,
+    each a dict from the header's column names to the row's values (the
+    leading one-word columns; a column is found by its name, never by its
+    position)."""
     rows = []
-    in_section = False
-    for line in lines:
+    header = None
+    for line in text.splitlines():
         if header_token in line:
-            in_section = True
+            header = line.split()
             continue
-        if in_section:
+        if header is not None:
             if not line.strip():
-                in_section = False
+                header = None
                 continue
             parts = line.split()
             if parts and parts[0].isdigit():
-                rows.append(parts)
+                rows.append(dict(zip(header, parts)))
     return rows
 
 
@@ -72,11 +74,11 @@ def test_perf_attribution_surfaces(cpp_build, tmp_path):
         assert disp, "no dispatcher rows:\n" + loops
         # Wakes and events summed ACROSS loops: sockets shard by fd, so
         # on a multi-loop host any single loop may legitimately be idle.
-        assert sum(int(r[1]) for r in disp) > 0, loops
-        assert sum(int(r[2]) for r in disp) > 0, loops
+        assert sum(int(r["epoll_waits"]) for r in disp) > 0, loops
+        assert sum(int(r["events"]) for r in disp) > 0, loops
         pools = _section_rows(loops, "runq_highwater")
         assert pools, "no scheduler pool rows:\n" + loops
-        assert int(pools[0][1]) > 0, loops  # workers
+        assert int(pools[0]["workers"]) > 0, loops
 
         # ---- /connections: per-socket I/O attribution ----
         header = _http_get(port, "/connections").splitlines()[0]

@@ -620,8 +620,8 @@ int main(int argc, char** argv) {
             stub, kDescBytes, kIters, &rsp_zero_copy_ok);
         if (rsp_mbps < 0) return 1;
         // Leak gauge (ISSUE 10 satellite): after the rounds every pinned
-        // block must be back in the pool — a nonzero pinned_after in a
-        // BENCH record is the descriptor path leaking under load. The
+        // block must be back in the pool — a nonzero pinned_after in
+        // the result line is the descriptor path leaking under load. The
         // LAST response ack may still be in flight (it rides the wire
         // after the RPC completes): give it a bounded moment.
         long long pinned_after = (long long)block_lease::pinned();

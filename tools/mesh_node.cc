@@ -1275,7 +1275,8 @@ int main(int argc, char** argv) {
         } else if (strcmp(argv[i], "--collective") == 0) {
             // Mesh collectives (ISSUE 13): serve the CollectiveService
             // + engine; rounds are driven by stdin "coll ..." commands
-            // (bench.py) or the --coll_traffic fiber (the soak).
+            // (tests/test_collectives.py) or the --coll_traffic fiber
+            // (the soak).
             collective = true;
         } else if (strcmp(argv[i], "--coll_traffic") == 0) {
             collective = true;

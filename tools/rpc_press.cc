@@ -776,8 +776,8 @@ int main(int argc, char** argv) {
         const bool fresh = access(metrics_csv, F_OK) != 0;
         csv = fopen(metrics_csv, "a");
         if (csv != nullptr && fresh) {
-            // Stream columns APPENDED at the end: bench.py's
-            // series_scrape indexes qps/p99 positionally (c[1], c[3]).
+            // Stream columns APPENDED at the end: an operator's script
+            // may index qps/p99 positionally (c[1], c[3]).
             fprintf(csv,
                     "elapsed_s,qps,p50_us,p99_us,p999_us,failed,tenant,"
                     "ttft_p50_us,ttft_p99_us,itl_p99_us\n");
@@ -969,7 +969,7 @@ int main(int argc, char** argv) {
         }
     }
     if (json) {
-        // Generator config rides along so BENCH records are
+        // Generator config rides along so a recorded line is
         // reproducible: the same qps from 1 vs 16 connections stresses
         // completely different server paths.
         printf("{\"press_qps\": %.0f, \"press_target_qps\": %lld, "

@@ -15,15 +15,17 @@ endpoint implements with ibv_post_send out of its registered block pool
 (rdma_endpoint.cpp CutFromIOBufList): device DMA reading straight from
 pool-registered frame bytes, several transfers in flight.
 
-Two threads drive a `DeviceLane`, as that endpoint's sender and its
-completion-queue poller do: the thread that calls `submit` launches
-chunks (acquire, stage, frame, H2D, dispatch, and the request for both
-results' copies back), the lane's completion thread retires them in
-launch order (wait for the D2H, crc32c, complete). The lane is long-lived
-and takes independent requests: `_ChunkPipeline.run` is a loop over
-`submit` on a lane that lives inside that call, and the served handler
-(brpc_tpu/tensor_service.py) submits each call to one that lives as long
-as the server.
+Three threads drive a `DeviceLane` (ISSUE 32), as that endpoint's sender,
+its doorbell and its completion-queue poller do: the thread that calls
+`submit` stages a chunk and puts it on the chip (acquire, the one pass over
+its bytes, frame, H2D), the lane's dispatch thread steps it (the jitted call
+and the request for both results' copies back), the lane's completion
+thread retires it (wait for the D2H, crc32c, complete) -- each in the order
+of the submits, so the pass over chunk i+1's bytes and its H2D run beside
+chunk i's dispatch. The lane is long-lived and takes independent requests:
+`_ChunkPipeline.run` is a loop over `submit` on a lane that lives inside
+that call, and the served handler (brpc_tpu/tensor_service.py) submits each
+call to one that lives as long as the server.
 
 `run(..., device=d)` is the smoke's one verified ring pass over any one of
 `jax.devices()` (chip_smoke.py walks them all from one process); the
@@ -149,43 +151,60 @@ class DeviceLane:
     are submitted one at a time and each is answered when its D2H is back.
 
     `submit(fill, nbytes, token)` runs on the caller's thread (the
-    launcher): a credit and a ring slot; `fill(view)` stages `nbytes`
+    submitter): a credit and a ring slot; `fill(view)` stages `nbytes`
     into the slot and returns their crc32c, both from one pass over the
     bytes (native.copy_crc32c, ParkedCall.copy_into: there is no other
     kind of fill, and one that returns nothing is a TypeError); the C++
     framer writes header + meta around that crc and never reads the
-    payload; H2D, the jitted `kernel(x) -> (y, word)`, and the request for
-    both results' copies back; then the chunk is handed to the lane's
-    completion thread, which waits for the D2H, checks crc32c of the
-    returned bytes against the frame's where `verify` is set (a kernel
-    that is the identity), calls
-    `on_done(token, host_bytes, word, good)` and completes the slot, in
+    payload; H2D. The chunk then goes to the lane's dispatch thread: the
+    jitted `kernel(x) -> (y, word)` and the request for both results'
+    copies back -- beside the submitter's pass over the next chunk's bytes
+    and its H2D, which need nothing of it (ISSUE 32). From there it goes
+    to the lane's completion thread, which waits for the D2H, checks crc32c
+    of the returned bytes against the frame's where `verify` is set (a
+    kernel that is the identity), calls
+    `on_done(token, host_bytes, word, good)` and completes the slot.
+    One submitter, one FIFO, one dispatcher, one FIFO, one retirer: all in
     the order of the submits. `host_bytes` is the device's answer on the
-    host, not the slot. That is the RDMA endpoint's sender and its
-    completion-queue poller. `close()` drains what is in flight and joins
-    the thread; a lane lives through any number of submits before it.
+    host, not the slot. That is the RDMA endpoint's sender, its doorbell
+    and its completion-queue poller. `close()` drains what is in flight
+    and joins both threads; a lane lives through any number of submits
+    before it.
 
-    At depth 1 there is no second thread: nothing is in flight that it
-    could overlap, so `submit` retires its chunk before it returns.
+    Where the cut lies, and why (PERF.md section 6, PR 32): the H2D stays
+    with the submitter because `device_put` copies the slot's bytes on the
+    thread that calls it, and they are in that core's cache from the fill;
+    on the dispatch thread it cost a fifth more, and left that thread with
+    three quarters of a chunk's work. The three threads share one
+    interpreter lock, so what they hand over is kept short: the credits
+    are tokens in a C queue, and the calls of a microsecond keep the lock
+    (native._short_calls).
 
-    An error on either thread aborts the ring: the launcher's leaves
-    `submit` as itself; the completion thread's is kept in `failure`, the
-    chunks behind it are given to `on_abandon(token)` instead of
+    At depth 1 there is no helper thread: nothing is in flight that they
+    could overlap, so `submit` dispatches and retires its chunk before it
+    returns.
+
+    An error on any thread aborts the ring: the submitter's leaves
+    `submit` as itself; a helper thread's is kept in `failure` (the first
+    one, whichever thread met it), the chunk it met and every chunk behind
+    it reach the completion thread marked abandoned and are given to
+    `on_abandon(token)` instead of `on_done`, after every earlier chunk's
     `on_done`, and the next `submit` raises RingAbortedError. Never
     parked forever (ISSUE 10c): an acquire that outlasts
     ACQUIRE_TIMEOUT_US aborts the ring too.
 
     Spans (brpc_tpu/spans.py), request = token: per submit one
-    `ring.launch` with children ring.acquire (waiting for a credit and a
-    free slot), whatever `fill` opens around its pass over the bytes
-    (ring.stage in the ring pass, tensor.fill in a served call: the copy
-    into the slot AND the crc32c), ring.frame (header + meta: a few
-    microseconds), ring.h2d,
-    ring.kernel_dispatch (the jitted function + the async D2H requests),
+    `ring.launch` on the submitter with children ring.acquire (waiting for
+    a credit and a free slot), whatever `fill` opens around its pass over
+    the bytes (ring.stage in the ring pass, tensor.fill in a served call:
+    the copy into the slot AND the crc32c), ring.frame (header + meta: a
+    few microseconds), ring.h2d; one `ring.dispatch` with the child
+    ring.kernel_dispatch (the jitted function + the async D2H requests);
     and one `ring.retire` with children ring.d2h_wait (blocks until the
     device is done), ring.verify (crc32c, where `verify`), ring.complete.
-    `ring.retire` is top-level on the completion thread. Self times are
-    per thread. PERF.md section 3 names the metric that reads each."""
+    `ring.dispatch` is top-level on the dispatch thread, `ring.retire` on
+    the completion thread. Self times are per thread. PERF.md section 3
+    names the metric that reads each."""
 
     def __init__(self, ring, dev, kernel, depth, on_done, *, verify=True,
                  on_abandon=None):
@@ -195,25 +214,40 @@ class DeviceLane:
         self.on_done = on_done
         self.on_abandon = on_abandon
         self.verify = verify
-        self.failure = None  # the completion thread's first error
-        # `depth` less what is in flight, whatever the ring's own depth.
-        self._credits = threading.Semaphore(depth)
-        self._handoff = queue.SimpleQueue()
-        self._completions = None
+        self.failure = None  # the helper threads' first error
+        self._failing = threading.Lock()
+        # `depth` less what is past `ring.acquire`, whatever thread holds
+        # it and whatever the ring's own depth: it bounds both queues. A
+        # token a credit, in a C queue: taking and giving one runs no
+        # Python, where threading.Semaphore is a condition variable in it.
+        self._credits = queue.SimpleQueue()
+        for _ in range(depth):
+            self._credits.put(None)
+        self._staged = queue.SimpleQueue()   # submitter -> dispatch thread
+        self._handoff = queue.SimpleQueue()  # dispatch -> completion thread
+        self._helpers = []
         if depth > 1:
-            self._completions = threading.Thread(
-                target=self._retire_handed_over, name="ring.completions")
-            self._completions.start()
+            self._helpers = [
+                threading.Thread(target=self._dispatch_staged,
+                                 name="ring.dispatch"),
+                threading.Thread(target=self._retire_handed_over,
+                                 name="ring.completions")]
+            for helper in self._helpers:
+                helper.start()
 
     def _acquire(self):
         """A credit, then the ring's next slot; both within the timeout."""
-        if self._credits.acquire(timeout=ACQUIRE_TIMEOUT_US / 1e6):
+        try:
+            self._credits.get(timeout=ACQUIRE_TIMEOUT_US / 1e6)
+        except queue.Empty:
+            pass
+        else:
             try:
                 return self.ring.acquire(ACQUIRE_TIMEOUT_US)
             except TimeoutError:
                 pass
             except BaseException:
-                self._credits.release()  # an aborted ring: nothing launched
+                self._credits.put(None)  # an aborted ring: nothing launched
                 raise
         self.ring.abort()
         raise RuntimeError(
@@ -238,20 +272,26 @@ class DeviceLane:
                                           nbytes, crc)
                 with spans.span("ring.h2d", token):
                     x = _h2d(view.view(np.uint32), self.dev)
-                with spans.span("ring.kernel_dispatch", token):
-                    y, word = self.kernel(x)
-                    # Everything `_retire` will wait for is asked for here.
-                    for out in (y, word):
-                        if hasattr(out, "copy_to_host_async"):
-                            out.copy_to_host_async()
+            staged = (token, slot, crc, x)
+            if self._helpers:
+                self._staged.put(staged)
+                return
+            item = self._dispatch(staged)
         except BaseException:
             self.ring.abort()  # a launch that failed never frees its slot
             raise
-        item = (token, slot, crc, y, word)
-        if self._completions is None:
-            self._retire(item)
-        else:
-            self._handoff.put(item)
+        self._retire(item)
+
+    def _dispatch(self, staged):
+        token, slot, crc, x = staged
+        with spans.span("ring.dispatch", token), \
+                spans.span("ring.kernel_dispatch", token):
+            y, word = self.kernel(x)
+            # Everything `_retire` will wait for is asked for here.
+            for out in (y, word):
+                if hasattr(out, "copy_to_host_async"):
+                    out.copy_to_host_async()
+        return token, slot, crc, y, word
 
     def _retire(self, item):
         token, slot, crc, y, word = item
@@ -270,34 +310,60 @@ class DeviceLane:
             self.on_done(token, back, word, good)
             with spans.span("ring.complete", token):
                 self.ring.complete(slot)
-        self._credits.release()
+        self._credits.put(None)
+
+    def _fail(self, error):
+        """A helper thread's error: the first is kept, and the ring is
+        aborted, which is what unblocks a submitter parked in `_acquire`."""
+        with self._failing:
+            if self.failure is None:
+                self.failure = error
+        self.ring.abort()
+
+    def _dispatch_staged(self):
+        """The dispatch thread's body: step what the submitter staged and
+        put on the chip, in that order, until its `None`, and hand each
+        chunk on with whether it was. After an error, its own or the
+        completion thread's, nothing more is dispatched: a chunk goes on as
+        it came, for the completion thread to abandon in its turn."""
+        for staged in iter(self._staged.get, None):
+            if self.failure is None:
+                try:
+                    self._handoff.put((self._dispatch(staged), True))
+                    continue
+                except BaseException as e:
+                    self._fail(e)
+            self._handoff.put((staged, False))
+        self._handoff.put(None)
 
     def _retire_handed_over(self):
-        """The completion thread's body: retire what the launcher hands
-        over, in that order, until its `None`. An error here aborts the
-        ring, which is what unblocks a launcher parked in `_acquire`."""
-        for item in iter(self._handoff.get, None):
-            if self.failure is None:
+        """The completion thread's body: retire what the dispatch thread
+        hands over, in that order, until its `None`. From the first chunk
+        that fails here or comes undispatched, each is abandoned."""
+        retiring = True
+        for item, dispatched in iter(self._handoff.get, None):
+            if retiring and dispatched:
                 try:
                     self._retire(item)
                     continue
                 except BaseException as e:
-                    self.failure = e
-                    self.ring.abort()
+                    self._fail(e)
+            retiring = False
             # The failed chunk's credit and each one's behind it: the ring
-            # is aborted, so the launcher that takes one meets that at
+            # is aborted, so the submitter that takes one meets that at
             # once and never waits the timeout out for a credit.
-            self._credits.release()
+            self._credits.put(None)
             if self.on_abandon is not None:
                 self.on_abandon(item[0])
 
     def close(self):
-        """Everything submitted is retired (or abandoned) and the
-        completion thread is gone when this returns; `failure` says how."""
-        if self._completions is not None:
-            self._handoff.put(None)
-            self._completions.join()
-            self._completions = None
+        """Everything submitted is retired (or abandoned) and both helper
+        threads are gone when this returns; `failure` says how."""
+        if self._helpers:
+            self._staged.put(None)  # the dispatch thread sends it on
+            for helper in self._helpers:
+                helper.join()
+            self._helpers = []
 
 
 class _ChunkPipeline:
@@ -309,19 +375,21 @@ class _ChunkPipeline:
     chunks in flight so H2D/compute/D2H of neighboring chunks overlap) --
     the same lane a served handler submits to (brpc_tpu/tensor_service.py).
 
-    Who runs where: `run()`'s caller launches every chunk, so `touch` is
-    called in launch order on that thread. At depth 1 it also retires
-    each chunk before the next launch. At any other depth the lane's
-    completion thread, started and joined inside `run()`, retires them in
-    launch order, so `dev_checks` is in launch order. A credit bounds the
-    chunks launched and not yet completed to `depth` whatever the ring's
-    own depth. An error on either thread aborts the ring and leaves
-    `run()` as itself, after the other thread has stopped.
+    Who runs where: `run()`'s caller stages every chunk and puts it on the
+    device. At depth 1 it also dispatches and retires each chunk before
+    the next. At any other
+    depth the lane's dispatch thread calls `touch`, in launch order, and
+    its completion thread retires the chunks in launch order, so
+    `dev_checks` is in launch order; both are started and joined inside
+    `run()`. A credit bounds the chunks staged and not yet completed to
+    `depth` whatever the ring's own depth. An error on any thread aborts
+    the ring and leaves `run()` as itself, after the other threads have
+    stopped.
 
     Spans, request = (pass, chunk): per pass one `ring.pass` (its self
     time is this loop's own), the lane's per chunk (DeviceLane), and the
-    launcher's wait for the last retires, `ring.drain`, under the last
-    `ring.pass`."""
+    caller's wait for the last dispatches and retires, `ring.drain`, under
+    the last `ring.pass`."""
 
     def __init__(self, ring, chunks, dev, touch, depth, copy_mode=False):
         if copy_mode:
@@ -382,7 +450,7 @@ class _ChunkPipeline:
                             spans.span("ring.drain", req):
                         lane.close()
                 if lane.failure is not None:
-                    # What the launcher met after that (RingAbortedError
+                    # What this thread met after that (RingAbortedError
                     # out of `_acquire`) is only its echo.
                     raise lane.failure
         return time.monotonic() - t0

@@ -23,6 +23,7 @@ import numpy as np
 
 _REPO = Path(__file__).resolve().parent.parent
 _LIB = None
+_SHORT = None
 
 
 def build(repo: Path = _REPO, timeout: float | None = None) -> Path:
@@ -58,6 +59,27 @@ def build(repo: Path = _REPO, timeout: float | None = None) -> Path:
                 f"{' '.join(cmd)} failed (rc {proc.returncode}):\n"
                 f"{proc.stdout[-4000:]}")
     return build_dir
+
+
+def _short_calls() -> ctypes.PyDLL:
+    """The same library for the calls that take about a microsecond and
+    never wait (`frame_in_place`, a ring's `complete`, its `acquire` while a
+    slot is free): through this handle a call keeps the interpreter lock.
+    `lib()`'s calls give it up and take it back, which between the lane's
+    three threads (brpc_tpu/device_path.py, ISSUE 32) is a hand-over to
+    whichever thread waits for it, and a wait of tens of microseconds to get
+    it back, for a microsecond of C (PERF.md section 6, PR 32). Nothing
+    that copies, checksums or blocks goes through here."""
+    global _SHORT
+    if _SHORT is None:
+        lib()  # the one check that the library is built
+        L = ctypes.PyDLL(str(_REPO / "build" / "libtpurpc.so"))
+        for name in ("tpurpc_ring_acquire", "tpurpc_ring_complete",
+                     "tpurpc_frame_in_place"):
+            fn, declared = getattr(L, name), getattr(_LIB, name)
+            fn.restype, fn.argtypes = declared.restype, declared.argtypes
+        _SHORT = L
+    return _SHORT
 
 
 def lib() -> ctypes.CDLL:
@@ -348,7 +370,11 @@ class DeviceStagingRing:
                 shape=(self.slot_bytes,)))
 
     def acquire(self, timeout_us: int = -1) -> int:
-        slot = int(lib().tpurpc_ring_acquire(self._ptr, timeout_us))
+        # A free slot is handed out without a wait and without giving up
+        # the interpreter lock; only a full ring is waited for, released.
+        slot = int(_short_calls().tpurpc_ring_acquire(self._ptr, 0))
+        if slot == -1 and timeout_us != 0:
+            slot = int(lib().tpurpc_ring_acquire(self._ptr, timeout_us))
         if slot == -2:
             raise RingAbortedError("ring aborted (poisoned)")
         if slot < 0:
@@ -356,7 +382,7 @@ class DeviceStagingRing:
         return slot
 
     def complete(self, slot: int) -> None:
-        if lib().tpurpc_ring_complete(self._ptr, slot) != 0:
+        if _short_calls().tpurpc_ring_complete(self._ptr, slot) != 0:
             raise ValueError(f"slot {slot} not in flight")
 
     def abort(self) -> None:
@@ -567,7 +593,7 @@ def frame_in_place(correlation_id: int, buf: np.ndarray, payload_off: int,
     (frame_off, frame_len)."""
     b = buf.view(np.uint8).reshape(-1)
     frame_off = ctypes.c_size_t()
-    n = lib().tpurpc_frame_in_place(
+    n = _short_calls().tpurpc_frame_in_place(
         correlation_id, b.ctypes.data_as(ctypes.c_void_p), payload_off,
         payload_len, ctypes.c_uint32(crc), ctypes.byref(frame_off))
     if n < 0:

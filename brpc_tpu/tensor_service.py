@@ -7,10 +7,13 @@ the same port; cpp/trpc/c_api.h) and joins it to a long-lived
 `device_path.DeviceLane`:
 
     taker thread        take a parked call -> lane.submit: a ring slot,
-    (the launcher)      the request attachment copied into it, its tail
+    (the submitter)     the request attachment copied into it, its tail
                         zeroed and the crc32c of both computed in ONE pass
                         (ParkedCall.copy_into), header + meta written from
-                        that crc, H2D, jitted `tensor_step`, async D2H
+                        that crc, H2D
+    lane's dispatch     jitted `tensor_step`, async D2H -- beside the taker's
+    thread              pass over the next call's bytes and its H2D
+                        (ISSUE 32)
     lane's completion   D2H back -> reply y ‖ w from the returned host
     thread              buffer (one copy into the reply) -> slot completed
 
@@ -23,13 +26,15 @@ request crosses the chip at the smallest of a few fixed sizes that holds it
 (`buckets`: powers of two from 4 KiB, and `max_bytes`), its tail zeroed in
 the slot -- zeros add nothing to w, and y's tail is not sent back -- and
 `serve` compiles every one of them before the first call: no call ever
-waits for the compiler. Ring aborted or device error: every parked
-and in-flight call fails, `take` returns, both threads end (the ISSUE 10c
-rule); `close()` then only joins.
+waits for the compiler. Ring aborted or device error (on the dispatch or
+the completion thread: the lane abandons the call it met and every call
+behind it, `_abandoned`): every parked and in-flight call fails, `take`
+returns, the taker and the lane's two threads end (the ISSUE 10c rule);
+`close()` then only joins.
 
 Spans (brpc_tpu/spans.py) beside the lane's `ring.*`: tensor.take (waiting
 for a call, then taking it), tensor.fill (attachment -> slot, zero tail and
-crc32c: the launcher's one pass over a call's bytes), tensor.reply
+crc32c: the taker's one pass over a call's bytes), tensor.reply
 (answer -> response attachment -> `done`). Stages and counters on the C++
 side: tdev.take_wait, tdev.reply, rpc_tensor_* (c_api.h); rpc_tensor_calls
 is counted here, where the D2H of a step's result has come back.
@@ -116,10 +121,10 @@ class TensorService:
                     continue
                 try:
                     self._submit(call, n)
-                except Exception as e:  # ring aborted, device error
+                except Exception as e:  # ring aborted, the fill's or H2D's
                     call.fail(TERR_INTERNAL, f"device leg failed: {e!r}")
-                    # The completion thread's error, where the aborted ring
-                    # this launch met is only its echo.
+                    # A helper thread's error, where the aborted ring this
+                    # submit met is only its echo.
                     self._shut(self.lane.failure or e)
         except native.ServerClosedError:
             pass
@@ -140,12 +145,15 @@ class TensorService:
                        np.array([word], dtype="<u4").view(np.uint8))
 
     def _abandoned(self, call):
+        """The lane's completion thread: a device error on either helper
+        thread reached `call` or a call ahead of it."""
         call.fail(TERR_INTERNAL, f"device leg failed: {self.lane.failure!r}")
         self._shut(self.lane.failure)
 
     def close(self):
-        """Parked calls fail, in-flight calls are answered, both threads
-        are joined, the server is stopped and the ring freed."""
+        """Parked calls fail, in-flight calls are answered, the taker and
+        the lane's threads are joined, the server is stopped and the ring
+        freed."""
         if self.server is not None:
             self.server.close_queue()
         if self._taker is not None:

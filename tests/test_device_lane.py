@@ -1,15 +1,20 @@
 """`device_path.DeviceLane` (ISSUE 29): the long-lived lane a served handler
 submits to, and `_ChunkPipeline.run` as a loop over `submit` on one -- the
-ring cell and the served call run one piece of code. CPU backend; the
+ring cell and the served call run one piece of code. Its two helper
+threads (ISSUE 32: a dispatch thread between the submitter and the
+completion thread): order, errors on either, the drain. CPU backend; the
 spans, words and crc verdicts are held to what `_ChunkPipeline` gave
 before the lane existed (the assertions of test_device_path_spans /
 test_device_path_threads, reused)."""
 import signal
+import sys
 import threading
+import time
 
 import numpy as np
 import pytest
-from test_device_path_spans import LAUNCH_CHILDREN, RETIRE_CHILDREN
+from test_device_path_spans import (DISPATCH_CHILDREN, LAUNCH_CHILDREN,
+                                    RETIRE_CHILDREN)
 
 from brpc_tpu import native, spans, tensor_reference
 from brpc_tpu.native import IN_PLACE_HEADROOM as HEADROOM
@@ -97,17 +102,19 @@ def test_run_over_the_lane_gives_the_spans_it_gave_before(parts):
     by_name = {}
     for name, start, end, request, thread in spans.snapshot():
         by_name.setdefault(name, []).append((request, thread, start, end))
-    assert set(by_name) == (LAUNCH_CHILDREN | RETIRE_CHILDREN
-                            | {"ring.launch", "ring.retire", "ring.pass",
-                               "ring.drain"})
+    per_chunk = (LAUNCH_CHILDREN | DISPATCH_CHILDREN | RETIRE_CHILDREN
+                 | {"ring.launch", "ring.dispatch", "ring.retire"})
+    assert set(by_name) == per_chunk | {"ring.pass", "ring.drain"}
     for name in LAUNCH_CHILDREN | {"ring.launch", "ring.pass", "ring.drain"}:
         assert {t for _, t, _, _ in by_name[name]} == {me}, name
+    (dispatcher,) = {t for _, t, _, _ in by_name["ring.dispatch"]}
     (completions,) = {t for _, t, _, _ in by_name["ring.retire"]}
-    assert completions != me
+    assert len({me, dispatcher, completions}) == 3
+    for name in DISPATCH_CHILDREN:
+        assert {t for _, t, _, _ in by_name[name]} == {dispatcher}, name
     for name in RETIRE_CHILDREN:
         assert {t for _, t, _, _ in by_name[name]} == {completions}, name
-    for name in LAUNCH_CHILDREN | RETIRE_CHILDREN | {"ring.launch",
-                                                     "ring.retire"}:
+    for name in per_chunk:
         assert [r for r, *_ in by_name[name]] == [
             (1, k) for k in range(N_CHUNKS)], name
     # The drain is the close of the lane, under a ring.pass of its own.
@@ -123,13 +130,14 @@ def test_a_lane_survives_three_passes_worth_of_submits(parts):
         ring, dev, kernel, DEPTH,
         lambda token, back, word, good: done.append(
             (token, word, good, bytes(back.view(np.uint8)[:16]))))
-    assert threading.active_count() == threads + 1
-    completions = lane._completions
+    assert threading.active_count() == threads + 2
+    helpers = list(lane._helpers)
+    assert [h.name for h in helpers] == ["ring.dispatch", "ring.completions"]
     for p in range(3):
         for k, chunk in enumerate(chunks):
             lane.submit(filler(chunk), CHUNK_BYTES, (p, k), k + 1)
-        assert lane._completions is completions and completions.is_alive()
-        assert threading.active_count() == threads + 1  # never re-started
+        assert lane._helpers == helpers and all(h.is_alive() for h in helpers)
+        assert threading.active_count() == threads + 2  # never re-started
     lane.close()
     assert threading.active_count() == threads and lane.failure is None
     assert [t for t, *_ in done] == [(p, k) for p in range(3)
@@ -138,8 +146,9 @@ def test_a_lane_survives_three_passes_worth_of_submits(parts):
     assert all(good for _, _, good, _ in done)
     assert [head for *_, head in done] == [
         bytes(c.view(np.uint8)[:16]) for c in chunks] * 3
-    assert len({rec[4] for rec in spans.snapshot()
-                if rec[0] == "ring.retire"}) == 1
+    for name in ("ring.dispatch", "ring.retire"):
+        assert len({rec[4] for rec in spans.snapshot()
+                    if rec[0] == name}) == 1, name
     assert ring.inflight_highwater <= DEPTH
 
 
@@ -191,14 +200,21 @@ def test_depth_one_retires_inside_submit_on_the_callers_thread(parts):
         lane.submit(filler(chunk), CHUNK_BYTES, k)
         assert done[-1] == k
     lane.close()
+    assert lane._helpers == [] and threading.active_count() == threads
     assert {rec[4] for rec in spans.snapshot()} == {threading.get_ident()}
+    # The same three parents a chunk, one after the other on this thread.
+    for k in range(len(chunks)):
+        times = [t for name in ("ring.launch", "ring.dispatch", "ring.retire")
+                 for rec in spans.snapshot() if rec[0] == name and rec[3] == k
+                 for t in rec[1:3]]
+        assert len(times) == 6 and times == sorted(times)
 
 
 @pytest.mark.parametrize("hold_the_retire", [False, True],
                          ids=["as_it_comes", "launcher_runs_ahead"])
 def test_an_error_behind_the_launcher_abandons_what_follows(
         parts, monkeypatch, hold_the_retire):
-    """Whichever thread is ahead: with the launcher as far ahead as the
+    """Whichever thread is ahead: with the submitter as far ahead as the
     credits let it be, nothing is left of them when the retire fails, and
     the submits after it still meet the aborted ring at once (the timeout
     is set longer than the test's own limit)."""
@@ -219,7 +235,7 @@ def test_an_error_behind_the_launcher_abandons_what_follows(
     def failing(x):
         y, w = kernel(x)
         calls.append(1)
-        if len(calls) == DEPTH + 1:  # the launcher has used every credit
+        if len(calls) == DEPTH + 1:  # the submitter has used every credit
             go.set()
         return (NeverComesBack() if len(calls) == 2 else y), w
 
@@ -240,6 +256,222 @@ def test_an_error_behind_the_launcher_abandons_what_follows(
     assert done == [0] and abandoned == launched[1:]
     with pytest.raises(native.RingAbortedError):
         lane.submit(filler(chunks[0]), CHUNK_BYTES, 99)
+
+
+@pytest.fixture
+def deep(parts):
+    """A ring of four slots beside the fixture's three: the depth both
+    chip cells run at, one chunk in each thread's hands and one between."""
+    ring = native.DeviceStagingRing(4, CHUNK_BYTES + 1024)
+    yield ring
+    ring.close()
+
+
+def test_answers_come_in_submit_order_with_both_helpers_at_depth_four(
+        parts, deep):
+    """One submitter, one FIFO, one dispatcher, one FIFO, one retirer --
+    with the interpreter switching threads as often as it can, so that a
+    hand-over that lost or swapped a chunk would show."""
+    device_path, _, dev, kernel, chunks = parts
+    passes, dispatched, done = 12, [], []
+
+    def counting(x):
+        dispatched.append(threading.get_ident())
+        return kernel(x)
+
+    lane = device_path.DeviceLane(
+        deep, dev, counting, 4,
+        lambda token, back, word, good: done.append((token, word, good)))
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for p in range(passes):
+            for k, chunk in enumerate(chunks):
+                lane.submit(filler(chunk), CHUNK_BYTES, (p, k), k + 1)
+        lane.close()
+    finally:
+        sys.setswitchinterval(interval)
+    assert lane.failure is None
+    assert [t for t, _, _ in done] == [(p, k) for p in range(passes)
+                                       for k in range(N_CHUNKS)]
+    assert [w for _, w, _ in done] == host_words(device_path, chunks, passes)
+    assert all(good for _, _, good in done)
+    # The kernel ran on one thread, neither the submitter's nor the
+    # retirer's, once a chunk.
+    assert len(dispatched) == passes * N_CHUNKS
+    (dispatcher,) = set(dispatched)
+    retirers = {rec[4] for rec in spans.snapshot() if rec[0] == "ring.retire"}
+    assert dispatcher not in retirers | {threading.get_ident()}
+    assert deep.inflight_highwater <= 4
+
+
+class PutFailed(Exception):
+    pass
+
+
+def failing_kernel(kernel, bad):
+    """The jitted call raises on its `bad`-th chunk (from 0)."""
+    calls = []
+
+    def touch(x):
+        calls.append(1)
+        if len(calls) - 1 == bad:
+            raise PutFailed("the dispatch failed")
+        return kernel(x)
+    return touch
+
+
+def failing_h2d(monkeypatch, device_path, bad):
+    """`device_put` raises on the `bad`-th chunk (from 0)."""
+    real, calls = device_path._h2d, []
+
+    def h2d(view, dev):
+        calls.append(1)
+        if len(calls) - 1 == bad:
+            raise PutFailed("the H2D failed")
+        return real(view, dev)
+
+    monkeypatch.setattr(device_path, "_h2d", h2d)
+
+
+@pytest.mark.parametrize("bad", [0, 2, 5])
+def test_an_error_on_the_dispatch_thread_abandons_it_and_what_follows(
+        parts, deep, monkeypatch, bad):
+    """ISSUE 32: chunks ahead of the failed one are answered, it and every
+    later one go through the completion thread's queue marked abandoned, in
+    submit order and after the last answer; `failure` is that error; the
+    next submit meets the aborted ring; `close()` leaves no thread and every
+    credit back."""
+    device_path, _, dev, kernel, chunks = parts
+    monkeypatch.setattr(device_path, "ACQUIRE_TIMEOUT_US",
+                        2 * LIMIT_S * 1_000_000)
+    events = []
+    threads = threading.active_count()
+    lane = device_path.DeviceLane(
+        deep, dev, failing_kernel(kernel, bad), 4,
+        lambda token, back, word, good: events.append(("done", token, good)),
+        on_abandon=lambda token: events.append(("abandoned", token)))
+    launched = []
+    try:
+        for k, chunk in enumerate(chunks + chunks):
+            lane.submit(filler(chunk), CHUNK_BYTES, k)
+            launched.append(k)
+    except native.RingAbortedError:
+        pass
+    else:
+        pytest.fail("twelve submits at depth 4 outran an error on chunk "
+                    f"{bad}")
+    lane.close()
+    assert threading.active_count() == threads and lane._helpers == []
+    assert isinstance(lane.failure, PutFailed) and deep.aborted
+    assert len(launched) > bad
+    assert events == ([("done", k, True) for k in range(bad)]
+                      + [("abandoned", k) for k in launched[bad:]])
+    with pytest.raises(native.RingAbortedError):
+        lane.submit(filler(chunks[0]), CHUNK_BYTES, 99)
+    assert lane._credits.qsize() == 4  # every credit is back
+
+
+@pytest.mark.parametrize("bad", [0, 2, 5])
+def test_an_h2d_that_raises_leaves_submit_as_itself(parts, deep, monkeypatch,
+                                                    bad):
+    """The H2D is the submitter's (it copies the slot's bytes where the
+    fill left them): its error is not a helper thread's. `submit` raises it,
+    the ring is aborted, what was submitted before it is answered, nothing
+    is abandoned and `failure` stays empty."""
+    device_path, _, dev, kernel, chunks = parts
+    failing_h2d(monkeypatch, device_path, bad)
+    done, abandoned = [], []
+    threads = threading.active_count()
+    lane = device_path.DeviceLane(
+        deep, dev, kernel, 4, lambda token, *rest: done.append(token),
+        on_abandon=abandoned.append)
+    with pytest.raises(PutFailed):
+        for k, chunk in enumerate(chunks):
+            lane.submit(filler(chunk), CHUNK_BYTES, k)
+    assert deep.aborted
+    with pytest.raises(native.RingAbortedError):
+        lane.submit(filler(chunks[0]), CHUNK_BYTES, 99)
+    lane.close()
+    assert threading.active_count() == threads and lane._helpers == []
+    assert done == list(range(bad)) and abandoned == []
+    assert lane.failure is None
+
+
+def test_the_first_error_is_the_one_kept_whichever_helper_met_it(
+        parts, deep, monkeypatch):
+    """The retire of chunk 1 fails while chunk 3 is in the dispatch
+    thread's hands, which then fails too: `failure` stays the first."""
+    device_path, _, dev, kernel, chunks = parts
+    monkeypatch.setattr(device_path, "ACQUIRE_TIMEOUT_US",
+                        2 * LIMIT_S * 1_000_000)
+    retire_failed, calls = threading.Event(), []
+
+    class NeverComesBack:
+        def __array__(self, *args, **kwargs):
+            raise OSError("the copy back failed")
+
+    def failing(x):
+        calls.append(1)
+        if len(calls) == 4:
+            assert retire_failed.wait(LIMIT_S)
+            raise PutFailed("the dispatch failed, second")
+        y, w = kernel(x)
+        return (NeverComesBack() if len(calls) == 2 else y), w
+
+    done, abandoned = [], []
+
+    def abandon(token):
+        abandoned.append(token)
+        retire_failed.set()
+
+    lane = device_path.DeviceLane(
+        deep, dev, failing, 4, lambda token, *rest: done.append(token),
+        on_abandon=abandon)
+    for k in range(4):
+        lane.submit(filler(chunks[k]), CHUNK_BYTES, k)
+    lane.close()
+    assert isinstance(lane.failure, OSError)
+    assert done == [0] and abandoned == [1, 2, 3]
+
+
+def test_close_drains_the_chunks_in_both_queues(parts, deep):
+    """Chunk 0 in the retirer's hands, 1 handed over, 2 in the dispatcher's
+    hands, 3 staged: `close()` waits for all four, in order."""
+    device_path, _, dev, kernel, chunks = parts
+    retire_gate, dispatch_gate = threading.Event(), threading.Event()
+    calls, done = [], []
+
+    def held_kernel(x):
+        calls.append(1)
+        if len(calls) == 3:
+            assert dispatch_gate.wait(LIMIT_S)
+        return kernel(x)
+
+    def held_done(token, back, word, good):
+        if token == 0:
+            assert retire_gate.wait(LIMIT_S)
+        done.append((token, good))
+
+    threads = threading.active_count()
+    lane = device_path.DeviceLane(deep, dev, held_kernel, 4, held_done)
+    for k in range(4):
+        lane.submit(filler(chunks[k]), CHUNK_BYTES, k)
+    deadline = time.monotonic() + LIMIT_S / 2
+    while (lane._staged.qsize(), lane._handoff.qsize()) != (1, 1):
+        assert time.monotonic() < deadline, "the queues never filled"
+        time.sleep(0.001)
+    closer = threading.Thread(target=lane.close)
+    closer.start()
+    closer.join(timeout=0.2)
+    assert closer.is_alive() and done == []  # it waits for what is in flight
+    dispatch_gate.set()
+    retire_gate.set()
+    closer.join(timeout=LIMIT_S / 2)
+    assert not closer.is_alive()
+    assert done == [(k, True) for k in range(4)] and lane.failure is None
+    assert threading.active_count() == threads and lane._helpers == []
+    assert lane._staged.empty() and lane._handoff.empty()
 
 
 def slot_frame(sa, nbytes):

@@ -1,9 +1,9 @@
-"""Spans inside the staging-ring pass (ISSUE 25, 26): a small pass on the
-CPU backend leaves, per chunk, one `ring.launch` on the calling thread and
-one `ring.retire` on the completion thread, with the children the issues
-list; the launcher's self times add up to the passes' wall time and the
-completion thread's stay inside it; the ring of records is bounded; a
-window cut out of it clips."""
+"""Spans inside the staging-ring pass (ISSUE 25, 26, 32): a small pass on
+the CPU backend leaves, per chunk, one `ring.launch` on the calling thread,
+one `ring.dispatch` on the dispatch thread and one `ring.retire` on the
+completion thread, with the children the issues list; the caller's self
+times add up to the passes' wall time and the two helper threads' stay
+inside it; the ring of records is bounded; a window cut out of it clips."""
 import threading
 import time
 
@@ -12,8 +12,8 @@ import pytest
 
 from brpc_tpu import spans
 
-LAUNCH_CHILDREN = {"ring.acquire", "ring.stage", "ring.frame", "ring.h2d",
-                   "ring.kernel_dispatch"}
+LAUNCH_CHILDREN = {"ring.acquire", "ring.stage", "ring.frame", "ring.h2d"}
+DISPATCH_CHILDREN = {"ring.kernel_dispatch"}
 RETIRE_CHILDREN = {"ring.d2h_wait", "ring.verify", "ring.complete"}
 
 
@@ -46,12 +46,16 @@ def test_every_chunk_has_its_launch_and_retire_with_their_children(pipeline):
         assert end >= start
         by_request.setdefault(request, []).append((name, start, end))
         threads.setdefault(name, set()).add(thread)
-    # The launch side is the caller's, the retire side one other thread's.
+    # The pass over the bytes and the H2D are the caller's, the dispatch a
+    # second thread's, the retire side a third's.
     me = threading.get_ident()
     for name in LAUNCH_CHILDREN | {"ring.launch", "ring.pass", "ring.drain"}:
         assert threads[name] == {me}, name
+    (dispatcher,) = threads["ring.dispatch"]
     (completions,) = threads["ring.retire"]
-    assert completions != me
+    assert len({me, dispatcher, completions}) == 3
+    for name in DISPATCH_CHILDREN:
+        assert threads[name] == {dispatcher}, name
     for name in RETIRE_CHILDREN:
         assert threads[name] == {completions}, name
     chunk_requests = [r for r in by_request if r[1] is not None]
@@ -60,17 +64,23 @@ def test_every_chunk_has_its_launch_and_retire_with_their_children(pipeline):
         got = by_request[request]
         names = [name for name, _, _ in got]
         assert names.count("ring.launch") == 1, (request, names)
+        assert names.count("ring.dispatch") == 1, (request, names)
         assert names.count("ring.retire") == 1, (request, names)
         # One pass over the bytes and one header write a chunk (ISSUE 30).
         assert names.count("ring.stage") == 1, (request, names)
         assert names.count("ring.frame") == 1, (request, names)
         # The children lie inside their parent's interval (nesting is in
-        # the times), and the launch inside its pass's ring.pass.
+        # the times), and the launch inside its pass's ring.pass. A chunk's
+        # three parents follow one another: each is top-level on its thread.
+        parents = []
         for parent, children in (("ring.launch", LAUNCH_CHILDREN),
+                                 ("ring.dispatch", DISPATCH_CHILDREN),
                                  ("ring.retire", RETIRE_CHILDREN)):
             (p0, p1), = [(s, e) for nm, s, e in got if nm == parent]
             assert {nm for nm, s, e in got
                     if nm != parent and p0 <= s and e <= p1} == children
+            parents += [p0, p1]
+        assert parents == sorted(parents), (request, parents)
         (l0, l1), = [(s, e) for nm, s, e in got if nm == "ring.launch"]
         assert any(s <= l0 and l1 <= e
                    for _, s, e in by_request[(request[0], None)])
@@ -107,7 +117,7 @@ def test_self_times_and_remainder_add_up_to_the_wall_time(pipeline):
     records = spans.snapshot(t0, t1)
     me = threading.get_ident()
     launcher = [r for r in records if r[4] == me]
-    completions = [r for r in records if r[4] != me]
+    helpers = [r for r in records if r[4] != me]
     own = spans.self_times(launcher)
     assert set(own) == LAUNCH_CHILDREN | {"ring.launch", "ring.pass",
                                           "ring.drain"}
@@ -122,12 +132,17 @@ def test_self_times_and_remainder_add_up_to_the_wall_time(pipeline):
     top = sum(end - start for name, start, end, *_ in launcher
               if name == "ring.pass")
     assert sum(own.values()) == pytest.approx(top, rel=1e-9)
-    # The completion thread's self times are its own clock: beside the
-    # launcher's, never more than the wall time, and reduced per thread
-    # (over all the records the two threads' sums simply add).
-    beside = spans.self_times(completions)
-    assert set(beside) == RETIRE_CHILDREN | {"ring.retire"}
-    assert 0 < sum(beside.values()) <= t1 - t0
+    # The helper threads' self times are their own clocks: beside the
+    # caller's, each side's never more than the wall time, and reduced per
+    # thread (over all the records the threads' sums simply add). A lane a
+    # run, so a thread's number may be a dispatch thread's in one run and a
+    # completion thread's in the next: the sides are told apart by name.
+    beside = spans.self_times(helpers)
+    dispatch_side = DISPATCH_CHILDREN | {"ring.dispatch"}
+    retire_side = RETIRE_CHILDREN | {"ring.retire"}
+    assert set(beside) == dispatch_side | retire_side
+    for side in (dispatch_side, retire_side):
+        assert 0 < sum(beside[name] for name in side) <= t1 - t0
     assert sum(spans.self_times(records).values()) == pytest.approx(
         sum(own.values()) + sum(beside.values()), rel=1e-9)
 

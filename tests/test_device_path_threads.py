@@ -1,8 +1,10 @@
-"""Who runs on which thread in the staging-ring pass (ISSUE 26): the caller
-launches, a completion thread that lives inside `run()` retires in launch
-order, depth 1 stays on one thread, an error on either side leaves `run()`
-as itself with the ring aborted and no thread behind. CPU backend; every
-test has a time limit of its own, so a hang fails here and stalls nothing."""
+"""Who runs on which thread in the staging-ring pass (ISSUE 26, 32): the
+caller stages, a dispatch thread puts each chunk on the device and a
+completion thread retires it, both living inside `run()` and both in launch
+order, depth 1 stays on one thread, an error on any of the three leaves
+`run()` as itself with the ring aborted and no thread behind. CPU backend;
+every test has a time limit of its own, so a hang fails here and stalls
+nothing."""
 import signal
 import sys
 import threading
@@ -71,7 +73,8 @@ def host_words(chunks, passes):
     return [device_path._integrity_word_host(c) for c in chunks] * passes
 
 
-def test_retires_in_launch_order_on_one_other_thread(make_pipeline):
+def test_dispatches_and_retires_in_launch_order_on_two_other_threads(
+        make_pipeline):
     pipe, ring, chunks = make_pipeline(3)
     calls = []
 
@@ -87,12 +90,16 @@ def test_retires_in_launch_order_on_one_other_thread(make_pipeline):
     assert pipe.dev_checks == host_words(chunks, 3)
     assert ring.inflight_highwater <= 3
     me = threading.get_ident()
-    assert calls == [me] * (3 * N_CHUNKS)  # touch: the caller's, every chunk
     assert thread_ids("ring.launch") == {me}
+    (dispatcher,) = thread_ids("ring.dispatch")
     (completions,) = thread_ids("ring.retire")
-    assert completions != me
-    retired = [rec[3] for rec in spans.snapshot() if rec[0] == "ring.retire"]
-    assert retired == [(p, k) for p in (1, 2, 3) for k in range(N_CHUNKS)]
+    assert len({me, dispatcher, completions}) == 3
+    # touch: the dispatch thread's, every chunk
+    assert calls == [dispatcher] * (3 * N_CHUNKS)
+    for name in ("ring.launch", "ring.dispatch", "ring.retire"):
+        in_order = [rec[3] for rec in spans.snapshot() if rec[0] == name]
+        assert in_order == [(p, k) for p in (1, 2, 3)
+                            for k in range(N_CHUNKS)], name
 
 
 def test_depth_one_stays_on_the_calling_thread(make_pipeline):
@@ -103,7 +110,8 @@ def test_depth_one_stays_on_the_calling_thread(make_pipeline):
     assert pipe.ok and pipe.dev_checks == host_words(chunks, 2)
     assert highwater == ring.inflight_highwater == 1
     names = {rec[0] for rec in spans.snapshot()}
-    assert "ring.retire" in names and "ring.drain" not in names
+    assert {"ring.dispatch", "ring.retire"} <= names
+    assert "ring.drain" not in names
     assert {rec[4] for rec in spans.snapshot()} == {threading.get_ident()}
     assert threading.active_count() == threads
 
@@ -147,6 +155,10 @@ class DispatchFailed(Exception):
     pass
 
 
+class StageFailed(Exception):
+    pass
+
+
 def failing_dispatch(kernel):
     calls = []
 
@@ -158,15 +170,28 @@ def failing_dispatch(kernel):
     return touch
 
 
+@pytest.mark.parametrize("depth", [1, 3])
 @pytest.mark.parametrize("touch, error", [
     (failing_copy_back, CopyBackFailed),  # on the completion thread
-    (failing_dispatch, DispatchFailed),   # on the launcher
+    (failing_dispatch, DispatchFailed),   # on the dispatch thread
+    (None, StageFailed),                  # on the caller, in its own pass
 ])
-def test_an_error_on_either_thread_is_what_run_raises(make_pipeline, touch,
-                                                      error):
+def test_an_error_on_any_thread_is_what_run_raises(make_pipeline, monkeypatch,
+                                                   touch, error, depth):
+    """At depth 1 the one thread that does all three meets it itself."""
     from brpc_tpu import native
 
-    pipe, ring, chunks = make_pipeline(3, touch=touch)
+    pipe, ring, chunks = make_pipeline(depth, touch=touch)
+    if touch is None:
+        real, calls = native.copy_crc32c, []
+
+        def failing_stage(view, chunk):
+            calls.append(1)
+            if len(calls) == 4:
+                raise StageFailed("the pass over the bytes failed")
+            return real(view, chunk)
+
+        monkeypatch.setattr(native, "copy_crc32c", failing_stage)
     threads = threading.active_count()
     with pytest.raises(error):
         pipe.run(2)
@@ -191,14 +216,15 @@ def test_two_runs_in_a_row_leave_no_thread_and_retire_everything(
     assert pipe.ok and pipe.dev_checks == host_words(chunks, 2)
     for name in ("ring.launch", "ring.retire"):
         assert sum(rec[0] == name for rec in spans.snapshot()) == 2 * N_CHUNKS
-    assert len(thread_ids("ring.retire")) <= 2  # one a call, ids may recur
+    for name in ("ring.dispatch", "ring.retire"):
+        assert len(thread_ids(name)) <= 2, name  # one a call, ids may recur
     assert ring.inflight_highwater <= 3
 
 
 def test_the_pipelines_depth_bounds_the_chunks_in_flight(make_pipeline):
     """Depth 2 over a ring of 4 slots, with the interpreter switching
     threads as often as it can: the credit, not the ring, is what holds
-    the launcher back, and the hand-over keeps the order."""
+    the caller back, and both hand-overs keep the order."""
     pipe, ring, chunks = make_pipeline(2, ring_depth=4)
     interval = sys.getswitchinterval()
     sys.setswitchinterval(1e-6)

@@ -9,6 +9,8 @@ missing device path is a failure, not a skip).
 """
 import json
 import subprocess
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -76,6 +78,45 @@ def test_ring_fifo_and_recycling(native):
     live_closed, recycled = native.slab_counters()
     assert live_closed == live0
     assert recycled >= 0
+
+
+def test_a_full_rings_acquire_waits_with_the_interpreter_lock_released(
+        native):
+    """ISSUE 32: a free slot is handed out through `native._short_calls`,
+    whose calls keep the interpreter lock; a full ring must not be waited
+    for that way, or every thread of the process would stand still. While
+    one thread waits for a slot another one runs Python, completes a slot,
+    and the waiter gets it; an aborted ring reads aborted on either way."""
+    ring = native.DeviceStagingRing(2, 64 << 10)
+    assert [ring.acquire(), ring.acquire()] == [0, 1]
+    with pytest.raises(TimeoutError):
+        ring.acquire(timeout_us=0)  # full, asked not to wait: at once
+    got = []
+    waiter = threading.Thread(
+        target=lambda: got.append(ring.acquire(timeout_us=20_000_000)))
+    t0 = time.monotonic()
+    waiter.start()
+    spins = 0
+    while time.monotonic() - t0 < 0.3:  # runs only if the lock is free
+        spins += 1
+    assert waiter.is_alive() and spins > 10_000, spins
+    ring.complete(0)
+    waiter.join(timeout=10)
+    assert not waiter.is_alive() and got == [0]
+    assert time.monotonic() - t0 < 5
+    ring.abort()
+    with pytest.raises(native.RingAbortedError):
+        ring.acquire(timeout_us=0)
+    with pytest.raises(native.RingAbortedError):
+        ring.acquire(timeout_us=1000)
+    ring.close()
+    # The two handles are one library: same functions, same declarations.
+    for name in ("tpurpc_ring_acquire", "tpurpc_ring_complete",
+                 "tpurpc_frame_in_place"):
+        short = getattr(native._short_calls(), name)
+        long_ = getattr(native.lib(), name)
+        assert (short.restype, short.argtypes) == (long_.restype,
+                                                   long_.argtypes)
 
 
 def test_frame_in_place_skips_payload_copy(native):

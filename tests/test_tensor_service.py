@@ -336,11 +336,12 @@ def test_mixed_sizes_from_many_callers_keep_reply_and_call_together(
             "ring.frame", "ring.h2d", "ring.kernel_dispatch", "ring.retire",
             "ring.d2h_wait", "ring.complete"} <= names
     assert "ring.stage" not in names and "ring.verify" not in names
-    # One launcher (the taker), one completion thread, for the server's life.
-    assert len({rec[4] for rec in spans.snapshot()
-                if rec[0] == "ring.launch"}) == 1
-    assert len({rec[4] for rec in spans.snapshot()
-                if rec[0] == "tensor.reply"}) == 1
+    # One submitter (the taker), one dispatch thread, one completion
+    # thread, for the server's life.
+    owners = [{rec[4] for rec in spans.snapshot() if rec[0] == name}
+              for name in ("ring.launch", "ring.dispatch", "tensor.reply")]
+    assert [len(owner) for owner in owners] == [1, 1, 1]
+    assert len(set.union(*owners)) == 3
 
 
 def test_a_served_call_stages_in_one_pass_and_the_framer_walks_none(
@@ -457,7 +458,8 @@ def test_close_with_calls_parked_fails_them_and_joins_in_time(native):
     assert verdicts.count(True) == 1  # the one in hand was answered
     assert sorted(v.code for v in verdicts if v is not True) == [
         native.TERR_CLOSE] * 3
-    assert threading.active_count() == threads - 2 - 4  # all joined
+    # The taker, the lane's two helpers, the four callers: all joined.
+    assert threading.active_count() == threads - 3 - 4
 
 
 def test_ring_abort_unblocks_take_and_fails_what_follows(native, service):
@@ -494,5 +496,76 @@ def test_a_device_error_fails_every_call_in_flight_and_joins(native,
     failed = [v for v in verdicts if v is not True]
     assert failed and all(v.code == native.TERR_INTERNAL for v in failed)
     assert "copy back failed" in repr(service.failure)
+    service._taker.join(timeout=5)
+    assert not service._taker.is_alive()
+
+
+def test_a_dispatch_error_fails_the_call_through_the_lanes_abandon(
+        native, service):
+    """ISSUE 32: the step is dispatched on the lane's dispatch thread, so
+    its error no longer leaves `lane.submit`: it reaches the parked call as
+    `on_abandon` on the completion thread, after the answers ahead of it,
+    shuts the service and ends the taker; `close()` joins the lane's two."""
+    seen, abandoned = [], []
+    step = service.lane.kernel
+
+    def second_fails(x):
+        seen.append(threading.get_ident())
+        if len(seen) == 2:
+            raise RuntimeError("the dispatch failed")
+        return step(x)
+
+    service.lane.kernel = second_fails
+    real_abandon = service.lane.on_abandon
+
+    def abandon(call):
+        abandoned.append(threading.get_ident())
+        real_abandon(call)
+
+    service.lane.on_abandon = abandon
+    threads = threading.active_count()
+    out = run_callers(native, service.port, 4, [4096, 4096])
+    verdicts = [v for _, _, v in out]
+    assert len(verdicts) == 8 and verdicts.count(True) == 1
+    failed = [v for v in verdicts if v is not True]
+    assert all(v.code == native.TERR_INTERNAL for v in failed)
+    assert "dispatch failed" in repr(service.failure)
+    assert service.failure is service.lane.failure
+    # Met on the dispatch thread, handed to the call on the completion
+    # thread: neither is the taker.
+    assert abandoned and set(abandoned).isdisjoint(seen)
+    assert service._taker.ident not in set(abandoned) | set(seen)
+    service._taker.join(timeout=5)
+    assert not service._taker.is_alive()
+    helpers = list(service.lane._helpers)
+    assert len(helpers) == 2
+    service.close()
+    assert not any(h.is_alive() for h in helpers)
+    assert threading.active_count() == threads - 3
+
+
+def test_an_h2d_error_fails_the_call_in_the_takers_hands(native, service,
+                                                         monkeypatch):
+    """The H2D is the taker's: its error leaves `lane.submit`, fails that
+    call from the taker, shuts the service; the call ahead is answered."""
+    from brpc_tpu import device_path
+
+    real, seen = device_path._h2d, []
+
+    def second_fails(view, dev):
+        seen.append(threading.get_ident())
+        if len(seen) == 2:
+            raise RuntimeError("the H2D failed")
+        return real(view, dev)
+
+    monkeypatch.setattr(device_path, "_h2d", second_fails)
+    out = run_callers(native, service.port, 4, [4096, 4096])
+    verdicts = [v for _, _, v in out]
+    assert len(verdicts) == 8 and verdicts.count(True) == 1
+    assert all(v.code == native.TERR_INTERNAL for v in verdicts
+               if v is not True)
+    assert "H2D failed" in repr(service.failure)
+    assert service.lane.failure is None and service.ring.aborted
+    assert set(seen) == {service._taker.ident}
     service._taker.join(timeout=5)
     assert not service._taker.is_alive()

@@ -132,6 +132,52 @@ def _tensor_step_kernel(key: int, platform: str):
     return jax.jit(tensor_step, donate_argnums=0)
 
 
+@lru_cache(maxsize=4)
+def _kv_put_kernel(platform: str):
+    """The step of the served call kvpb.Cache/Put (ISSUE 33;
+    brpc_tpu/kv_service.py): a kernel that carries device state. Over one
+    layer's pool buffer `pool` (uint32[sessions * row_words], the sessions'
+    rows end to end -- flat, so that a chunk is one contiguous run of it
+    and no row is padded to a tile; donated: XLA writes in place, the pool
+    is never copied), one chunk `x` of a call as uint32 words and `where` =
+    int32[2] (the chunk's first word in the pool, and in the call), returns
+    (pool', w): pool' is pool with x written at [at:at + len(x)], and w =
+    the wraparound sum of x[j] * (2 (j + off) + 1) READ BACK FROM pool'
+    after the write -- the chunk's share of the call's integrity word,
+    which therefore does not depend on how the call was cut. Nothing of the
+    chunk comes back to the host but w. Traced as module `jit_kv_put_step`;
+    one shape, so it compiles once. Donation as `_touch_kernel`."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def kv_put_step(pool, x, where):
+        at, off = where[0], where[1]
+        pool = lax.dynamic_update_slice(pool, x, (at,))
+        landed = lax.dynamic_slice(pool, (at,), x.shape)
+        idx = jnp.arange(x.shape[0], dtype=jnp.uint32) + off.astype(jnp.uint32)
+        return pool, jnp.sum(landed * (idx * jnp.uint32(2) + jnp.uint32(1)),
+                             dtype=jnp.uint32)
+
+    if platform == "cpu":
+        return jax.jit(kv_put_step)
+    return jax.jit(kv_put_step, donate_argnums=0)
+
+
+@lru_cache(maxsize=4)
+def _kv_get_kernel(chunk_words: int):
+    """kvpb.Cache/Get's read of one chunk out of a layer's pool buffer:
+    pool[at:at + chunk_words] with `where` as `_kv_put_kernel`'s. The pool
+    is not donated: it stays."""
+    import jax
+    from jax import lax
+
+    def kv_get_step(pool, where):
+        return lax.dynamic_slice(pool, (where[0],), (chunk_words,))
+
+    return jax.jit(kv_get_step)
+
+
 def _h2d(view: np.ndarray, dev):
     """Import one staged slot view onto the device: dlpack zero-copy on
     host-backed platforms (the registered slot IS the device buffer), a
@@ -170,6 +216,18 @@ class DeviceLane:
     and its completion-queue poller. `close()` drains what is in flight
     and joins both threads; a lane lives through any number of submits
     before it.
+
+    The kernel's contract (ISSUE 33): `kernel(x) -> (y, word)`, called on
+    the dispatch thread, in submit order, and by nothing else. `word` is a
+    device scalar and always comes back. `y` is the chunk's bulk result,
+    and comes back as `host_bytes`; a kernel whose result STAYS on the
+    device returns `y` None, and then only the 4-byte word crosses back
+    (`host_bytes` None). A chunk may bring a kernel of its own
+    (`submit(..., kernel=)`): a closure over device state that it replaces
+    (`(pool, x, where) -> (pool', word)` with the pool donated) is safe
+    there because one thread runs every step, in order. One token may own
+    several submits (a call cut into chunks): `on_done` and `on_abandon`
+    run once a chunk, in submit order, and joining them is the owner's.
 
     Where the cut lies, and why (PERF.md section 6, PR 32): the H2D stays
     with the submitter because `device_put` copies the slot's bytes on the
@@ -254,7 +312,7 @@ class DeviceLane:
             "staging-ring acquire timed out (lost completion or wedged "
             "device stream); ring aborted")
 
-    def submit(self, fill, nbytes, token, correlation_id=1):
+    def submit(self, fill, nbytes, token, correlation_id=1, kernel=None):
         try:
             with spans.span("ring.launch", token):
                 with spans.span("ring.acquire", token):
@@ -272,7 +330,7 @@ class DeviceLane:
                                           nbytes, crc)
                 with spans.span("ring.h2d", token):
                     x = _h2d(view.view(np.uint32), self.dev)
-            staged = (token, slot, crc, x)
+            staged = (token, slot, crc, x, kernel or self.kernel)
             if self._helpers:
                 self._staged.put(staged)
                 return
@@ -283,10 +341,10 @@ class DeviceLane:
         self._retire(item)
 
     def _dispatch(self, staged):
-        token, slot, crc, x = staged
+        token, slot, crc, x, kernel = staged
         with spans.span("ring.dispatch", token), \
                 spans.span("ring.kernel_dispatch", token):
-            y, word = self.kernel(x)
+            y, word = kernel(x)
             # Everything `_retire` will wait for is asked for here.
             for out in (y, word):
                 if hasattr(out, "copy_to_host_async"):
@@ -297,8 +355,9 @@ class DeviceLane:
         token, slot, crc, y, word = item
         with spans.span("ring.retire", token):
             with spans.span("ring.d2h_wait", token):
-                # Blocks until the device is done; then its word comes back.
-                back = np.asarray(y)
+                # Blocks until the device is done; then its word comes back,
+                # and the bulk result where the kernel gave one.
+                back = None if y is None else np.asarray(y)
                 word = int(word)
             good = True
             if self.verify:

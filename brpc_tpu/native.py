@@ -14,6 +14,7 @@ DMAs to HBM.
 from __future__ import annotations
 
 import ctypes
+import errno
 import re
 import shutil
 import subprocess
@@ -64,7 +65,8 @@ def build(repo: Path = _REPO, timeout: float | None = None) -> Path:
 def _short_calls() -> ctypes.PyDLL:
     """The same library for the calls that take about a microsecond and
     never wait (`frame_in_place`, a ring's `complete`, its `acquire` while a
-    slot is free): through this handle a call keeps the interpreter lock.
+    slot is free, the kv counters): through this handle a call keeps the
+    interpreter lock.
     `lib()`'s calls give it up and take it back, which between the lane's
     three threads (brpc_tpu/device_path.py, ISSUE 32) is a hand-over to
     whichever thread waits for it, and a wait of tens of microseconds to get
@@ -75,7 +77,8 @@ def _short_calls() -> ctypes.PyDLL:
         lib()  # the one check that the library is built
         L = ctypes.PyDLL(str(_REPO / "build" / "libtpurpc.so"))
         for name in ("tpurpc_ring_acquire", "tpurpc_ring_complete",
-                     "tpurpc_frame_in_place"):
+                     "tpurpc_frame_in_place", "tpurpc_kv_chunk_landed",
+                     "tpurpc_kv_pool_state"):
             fn, declared = getattr(L, name), getattr(_LIB, name)
             fn.restype, fn.argtypes = declared.restype, declared.argtypes
         _SHORT = L
@@ -176,20 +179,27 @@ def lib() -> ctypes.CDLL:
         L.tpurpc_server_take.restype = ctypes.c_void_p
         L.tpurpc_server_take.argtypes = [
             ctypes.c_void_p, ctypes.c_long, ctypes.POINTER(ctypes.c_size_t),
-            ctypes.POINTER(ctypes.c_int)]
+            ctypes.POINTER(ctypes.c_int), ctypes.POINTER(ctypes.c_uint64)]
         L.tpurpc_server_close_queue.argtypes = [ctypes.c_void_p,
                                                 ctypes.c_int]
         L.tpurpc_server_stop.argtypes = [ctypes.c_void_p]
         L.tpurpc_call_copy_out.restype = ctypes.c_long
-        L.tpurpc_call_copy_out.argtypes = [ctypes.c_void_p, ctypes.c_void_p,
-                                           ctypes.c_size_t,
+        L.tpurpc_call_copy_out.argtypes = [ctypes.c_void_p, ctypes.c_size_t,
+                                           ctypes.c_void_p, ctypes.c_size_t,
                                            ctypes.POINTER(ctypes.c_uint32)]
         L.tpurpc_call_reply.restype = ctypes.c_int
         L.tpurpc_call_reply.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t,
             ctypes.c_void_p, ctypes.c_size_t]
+        L.tpurpc_call_reply_put.restype = ctypes.c_int
+        L.tpurpc_call_reply_put.argtypes = [ctypes.c_void_p, ctypes.c_uint32,
+                                            ctypes.c_uint64]
         L.tpurpc_tensor_step_answered.restype = None
         L.tpurpc_tensor_step_answered.argtypes = []
+        L.tpurpc_kv_chunk_landed.restype = None
+        L.tpurpc_kv_chunk_landed.argtypes = [ctypes.c_size_t]
+        L.tpurpc_kv_pool_state.restype = None
+        L.tpurpc_kv_pool_state.argtypes = [ctypes.c_long] * 3
         L.tpurpc_flag_set.restype = ctypes.c_int
         L.tpurpc_flag_set.argtypes = [ctypes.c_char_p, ctypes.c_char_p]
         L.tpurpc_call_fail.restype = ctypes.c_int
@@ -201,6 +211,18 @@ def lib() -> ctypes.CDLL:
         L.tpurpc_channel_call.restype = ctypes.c_int
         L.tpurpc_channel_call.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t,
+            ctypes.c_void_p, ctypes.c_size_t,
+            ctypes.POINTER(ctypes.c_size_t), ctypes.c_long, ctypes.c_char_p,
+            ctypes.c_size_t]
+        L.tpurpc_channel_put.restype = ctypes.c_int
+        L.tpurpc_channel_put.argtypes = [
+            ctypes.c_void_p, ctypes.c_uint64, ctypes.c_uint32,
+            ctypes.c_void_p, ctypes.c_size_t,
+            ctypes.POINTER(ctypes.c_uint32), ctypes.POINTER(ctypes.c_uint64),
+            ctypes.c_long, ctypes.c_char_p, ctypes.c_size_t]
+        L.tpurpc_channel_get.restype = ctypes.c_int
+        L.tpurpc_channel_get.argtypes = [
+            ctypes.c_void_p, ctypes.c_uint64, ctypes.c_uint32,
             ctypes.c_void_p, ctypes.c_size_t,
             ctypes.POINTER(ctypes.c_size_t), ctypes.c_long, ctypes.c_char_p,
             ctypes.c_size_t]
@@ -406,9 +428,13 @@ class DeviceStagingRing:
 
 
 # Error codes of cpp/tbase/errno.h that the Python side answers with.
+TERR_NO_METHOD = 4004
 TERR_REQUEST = 4005
 TERR_CLOSE = 4009
 TERR_INTERNAL = 4010
+# What kvpb.Cache/Get fails with for a (session, layer) that is not in the
+# pool: never put, or evicted.
+KV_NOT_FOUND = errno.ENOENT
 
 
 def set_flag(name: str, value) -> None:
@@ -422,6 +448,20 @@ def tensor_step_answered() -> None:
     """/vars rpc_tensor_calls += 1: the D2H of one step's result is back
     (brpc_tpu/tensor_service.py counts there, and nowhere else)."""
     lib().tpurpc_tensor_step_answered()
+
+
+def kv_chunk_landed(nbytes: int) -> None:
+    """/vars rpc_kv_chunks += 1, rpc_kv_bytes_landed += nbytes: the word of
+    one chunk of a Put is back from the device (brpc_tpu/kv_service.py
+    counts there, on the lane's completion thread, and nowhere else)."""
+    _short_calls().tpurpc_kv_chunk_landed(nbytes)
+
+
+def kv_pool_state(pool_bytes: int, resident_bytes: int,
+                  evicted: int = 0) -> None:
+    """/vars rpc_kv_pool_bytes and rpc_kv_resident_bytes as they are now;
+    rpc_kv_evictions += evicted."""
+    _short_calls().tpurpc_kv_pool_state(pool_bytes, resident_bytes, evicted)
 
 
 class ServerClosedError(RuntimeError):
@@ -440,38 +480,58 @@ def _address(array: np.ndarray) -> ctypes.c_void_p:
     return ctypes.c_void_p(array.ctypes.data)
 
 
+STEP, PUT, GET = 0, 1, 2  # ParkedCall.method (c_api.h TPURPC_METHOD_*)
+
+
 class ParkedCall:
-    """One call of tensorpb.Tensor/Step between the C++ handler that
-    parked it and its answer. Exactly one of `reply` / `fail` ends it."""
+    """One call of the pull server's services between the C++ handler that
+    parked it and its answer: `method` says which (STEP, PUT, GET), `nbytes`
+    is its request attachment's length, `session` and `layer` are a Put's
+    or a Get's. Exactly one of `reply` / `reply_put` / `fail` ends it."""
 
-    __slots__ = ("_ptr", "nbytes")
+    __slots__ = ("_ptr", "nbytes", "method", "session", "layer")
 
-    def __init__(self, ptr: int, nbytes: int):
+    def __init__(self, ptr: int, nbytes: int, method: int = STEP,
+                 session: int = 0, layer: int = 0):
         self._ptr = ptr
         self.nbytes = nbytes
+        self.method = method
+        self.session = session
+        self.layer = layer
 
-    def copy_into(self, view: np.ndarray) -> int:
-        """The request attachment into `view` (uint8, at least `nbytes`)
-        and zeros into what is left of it, each block folded into the
-        crc32c by the pass that copies it. Returns the crc32c of all of
-        `view`, which is `frame_in_place`'s."""
+    def copy_into(self, view: np.ndarray, offset: int = 0) -> int:
+        """The request attachment from byte `offset` on into `view` (uint8)
+        and zeros into what is left of it where the attachment ends first,
+        each block folded into the crc32c by the pass that copies it.
+        Returns the crc32c of all of `view`, which is `frame_in_place`'s."""
         crc = ctypes.c_uint32()
-        got = lib().tpurpc_call_copy_out(self._ptr, _address(view),
+        got = lib().tpurpc_call_copy_out(self._ptr, offset, _address(view),
                                          view.nbytes, ctypes.byref(crc))
-        if got != self.nbytes:
-            raise ValueError(f"copied {got} of {self.nbytes} bytes")
+        want = max(0, min(view.nbytes, self.nbytes - offset))
+        if got != want:
+            raise ValueError(f"copied {got} of {want} bytes")
         return int(crc.value)
 
-    def reply(self, body: np.ndarray,
-              tail: np.ndarray | None = None) -> None:
+    def _answering(self) -> int:
         ptr, self._ptr = self._ptr, None
         if not ptr:
             raise ValueError("the call has been answered already")
+        return ptr
+
+    def reply(self, body: np.ndarray,
+              tail: np.ndarray | None = None) -> None:
+        ptr = self._answering()
         body = body.reshape(-1).view(np.uint8)
         lib().tpurpc_call_reply(
             ptr, _address(body), body.nbytes,
             None if tail is None else _address(tail),
             0 if tail is None else tail.nbytes)
+
+    def reply_put(self, word: int, admitted: int) -> None:
+        """A Put's answer: the response's two integers, no attachment."""
+        if self.method != PUT:
+            raise ValueError("reply_put answers a Put")
+        lib().tpurpc_call_reply_put(self._answering(), word, admitted)
 
     def fail(self, code: int, text: str) -> None:
         ptr, self._ptr = self._ptr, None
@@ -484,10 +544,11 @@ class ParkedCall:
 
 
 class PullServer:
-    """The C API's pull server (cpp/trpc/c_api.h): tensorpb.Tensor/Step on
-    a Server inside this process, listening on 127.0.0.1 (TCP, the shm
-    link after its handshake, and the builtin portal). `take` blocks in
-    C++ with the interpreter lock released."""
+    """The C API's pull server (cpp/trpc/c_api.h): tensorpb.Tensor/Step and
+    kvpb.Cache/Put, /Get on a Server inside this process, listening on
+    127.0.0.1 (TCP, the shm link after its handshake, and the builtin
+    portal). `take` gives the parked calls of every method in the order
+    they arrived; it blocks in C++ with the interpreter lock released."""
 
     def __init__(self, port: int = 0):
         self._ptr = lib().tpurpc_server_start(port)
@@ -499,11 +560,12 @@ class PullServer:
         """The next parked call; None on timeout; ServerClosedError once
         the queue is closed."""
         nbytes, status = ctypes.c_size_t(), ctypes.c_int()
+        what = (ctypes.c_uint64 * 3)()
         ptr = lib().tpurpc_server_take(self._ptr, timeout_us,
                                        ctypes.byref(nbytes),
-                                       ctypes.byref(status))
+                                       ctypes.byref(status), what)
         if ptr:
-            return ParkedCall(ptr, int(nbytes.value))
+            return ParkedCall(ptr, int(nbytes.value), *what)
         if status.value == -2:
             raise ServerClosedError("the pull server's queue is closed")
         return None
@@ -522,9 +584,21 @@ class PullServer:
             lib().tpurpc_server_stop(ptr)
 
 
+def _reply(rc: int, err, out: np.ndarray | None = None, got: int = 0):
+    """A client call's outcome: RpcError where it failed, else the `got`
+    bytes of the reply attachment that `out` was given room for."""
+    if rc != 0:
+        raise RpcError(rc, err.value.decode("utf-8", "replace"))
+    if out is not None:
+        if got > out.nbytes:
+            raise ValueError(f"reply of {got} bytes, room for {out.nbytes}")
+        return out[:got]
+
+
 class StepChannel:
-    """One blocking client of tensorpb.Tensor/Step (tests, the rehearsal,
-    chip_smoke.py): attachment in, attachment out, no retry."""
+    """One blocking client of the pull server's services (tests, the
+    rehearsal, chip_smoke.py), no retry: `call` is tensorpb.Tensor/Step
+    (attachment in, attachment out), `put` and `get` are kvpb.Cache's."""
 
     def __init__(self, port: int, ici: bool = False,
                  timeout_ms: int = 10000, host: str = "127.0.0.1"):
@@ -546,11 +620,31 @@ class StepChannel:
             cap, ctypes.byref(got),
             self.timeout_ms if timeout_ms is None else timeout_ms, err,
             len(err))
-        if rc != 0:
-            raise RpcError(rc, err.value.decode("utf-8", "replace"))
-        if got.value > cap:
-            raise ValueError(f"reply of {got.value} bytes, room for {cap}")
-        return out[:got.value]
+        return _reply(rc, err, out, got.value)
+
+    def put(self, session: int, layer: int,
+            data: np.ndarray) -> tuple[int, int]:
+        """Put one layer of a session's cache; (word, admitted)."""
+        data = np.ascontiguousarray(data).reshape(-1).view(np.uint8)
+        word, admitted = ctypes.c_uint32(), ctypes.c_uint64()
+        err = ctypes.create_string_buffer(256)
+        rc = lib().tpurpc_channel_put(
+            self._ptr, session, layer, _address(data), data.nbytes,
+            ctypes.byref(word), ctypes.byref(admitted), self.timeout_ms,
+            err, len(err))
+        _reply(rc, err)
+        return int(word.value), int(admitted.value)
+
+    def get(self, session: int, layer: int, cap: int) -> np.ndarray:
+        """The layer's bytes (at most `cap`); RpcError with `.code`
+        KV_NOT_FOUND where the pool has none."""
+        out = np.empty(cap, dtype=np.uint8)
+        got = ctypes.c_size_t()
+        err = ctypes.create_string_buffer(256)
+        rc = lib().tpurpc_channel_get(
+            self._ptr, session, layer, _address(out), cap, ctypes.byref(got),
+            self.timeout_ms, err, len(err))
+        return _reply(rc, err, out, got.value)
 
     def close(self) -> None:
         ptr, self._ptr = self._ptr, None

@@ -63,6 +63,16 @@ class span:
         return False
 
 
+def record(name, start, end, request=None):
+    """A span that no one thread holds: it began at `start` on one thread
+    and ends now-ish, at `end`, on another (a call's wait for the last of
+    its chunks). Kept whole, on a line of its own where a thread's id
+    stands, so `self_times` gives it its full duration and takes nothing
+    from the spans of the thread that records it. Not on the profiler's
+    clock."""
+    _ring.append((name, start, end, request, (name, start)))
+
+
 def snapshot(since=None, until=None):
     """The ring's records that touch [since, until], each clipped to it
     (times are `time.monotonic()`; None = unbounded), oldest first."""

@@ -5,7 +5,7 @@
 
 Drives the main path once through the entry points a user would call, at
 the sizes BASELINE.json's configs name, on every chip `jax.devices()`
-reports. Five legs, one JSON line each (every line names the platform,
+reports. Six legs, one JSON line each (every line names the platform,
 device kind and device count it ran beside), then one last line:
 
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
@@ -25,6 +25,11 @@ device kind and device count it ran beside), then one last line:
               16 calls of tensor.Step at 1 MiB over the shm link, each
               reply (made on the chip) against brpc_tpu.tensor_reference
               (`tensor_echo_ok`).
+- kv_put      brpc_tpu.kv_service served in this process on device 0, a pool
+              of 2 sessions x 3 layers x 9 MiB: a session's 3 layers put
+              (9 chunks each, the word made on the chip against
+              brpc_tpu.kv_reference), read back byte for byte, and evicted
+              whole by the third session (`kv_put_ok`).
 - collective  __graft_entry__.mesh_data_plane over Mesh(jax.devices()):
               fan-out rows of 4 KiB and 1 MiB, partition shards, all-reduce
               / all-gather / all-to-all at 4 MiB and 64 MiB per rank, framed
@@ -376,6 +381,67 @@ def leg_tensor_echo() -> dict:
             "seconds_calls": round(time.monotonic() - t0, 2)}
 
 
+def leg_kv_put() -> dict:
+    """kvpb.Cache served in-process on device 0 (ISSUE 33): three sessions of
+    3 layers of 9 MiB into a pool of 2, one call at a time over the shm
+    link: every word and admission number the reference's, the first
+    session read back byte for byte, then evicted whole by the third. The
+    multi-chunk, stateful device leg of a served call, from a bare
+    checkout."""
+    import jax
+    import numpy as np
+
+    from brpc_tpu import kv_reference, kv_service, native
+
+    layers, slots, nbytes = 3, 2, 9 << 20
+    dev = jax.devices()[0]
+    service = kv_service.serve(dev, layers=layers, sessions=slots,
+                               layer_bytes=nbytes)
+    ref = kv_reference.Cache(slots, layers, nbytes)
+    rng = np.random.default_rng(33)
+    wrong = []
+    t0 = time.monotonic()
+    try:
+        channel = native.StepChannel(service.port, ici=True)
+        try:
+            for session in (101, 202, 303):
+                for layer in range(layers):
+                    x = rng.integers(0, 256, nbytes, dtype=np.uint8)
+                    if channel.put(session, layer, x) != ref.put(
+                            session, layer, x):
+                        wrong.append(("put", session, layer))
+                if session == 101:
+                    for layer in range(layers):
+                        got = channel.get(session, layer, nbytes).tobytes()
+                        if got != ref.get(session, layer):
+                            wrong.append(("get", session, layer))
+            for layer in range(layers):  # 303 took 101's slot
+                try:
+                    channel.get(101, layer, nbytes)
+                    wrong.append(("evicted and answered", 101, layer))
+                except native.RpcError as e:
+                    if e.code != native.KV_NOT_FOUND:
+                        raise
+            if channel.get(303, 2, nbytes).tobytes() != ref.get(303, 2):
+                wrong.append(("get", 303, 2))
+        finally:
+            channel.close()
+    finally:
+        service.close()
+    if wrong or service.failure is not None or list(service.table) != list(
+            ref.slots):
+        raise LegFailed(f"kvpb.Cache on {dev}: differs from the reference "
+                        f"at {wrong}, table {list(service.table)} against "
+                        f"{list(ref.slots)}, service failure "
+                        f"{service.failure!r}")
+    if dev.platform != "tpu":
+        raise LegFailed(f"kvpb.Cache ran on {dev}")
+    return {"kv_put_ok": True, "puts": 3 * layers, "bytes_each": nbytes,
+            "chunks_each": nbytes // kv_service.CHUNK_BYTES,
+            "pool_bytes": service.pool_bytes, "device": str(dev),
+            "seconds_calls": round(time.monotonic() - t0, 2)}
+
+
 def leg_collective() -> dict:
     import jax
     import numpy as np
@@ -414,7 +480,7 @@ def leg_collective() -> dict:
 
 LEGS = (("build", 900, leg_build), ("served", 600, leg_served),
         ("device", 400, leg_device), ("tensor_echo", 200, leg_tensor_echo),
-        ("collective", 400, leg_collective))
+        ("kv_put", 200, leg_kv_put), ("collective", 400, leg_collective))
 
 
 # ---------------------------------------------------------------- runner

@@ -7,6 +7,7 @@
 #include <algorithm>
 #include <new>
 
+#include "tbase/crc32c.h"
 #include "tbase/flags.h"
 #include "tbase/mpmc_queue.h"
 #include "tbase/logging.h"
@@ -485,6 +486,33 @@ size_t IOBuf::copy_to(void* buf, size_t n, size_t pos) const {
         pos = 0;
     }
     return copied;
+}
+
+uint32_t IOBuf::copy_to_crc32c(void* dst, size_t cap, size_t pos,
+                               size_t* copied) const {
+    char* d = (char*)dst;
+    size_t done = 0;
+    uint32_t crc = 0;
+    const uint32_t cnt = nref_();
+    for (uint32_t i = 0; i < cnt && done < cap; ++i) {
+        const BlockRef& r = ref_at(i);
+        if (pos >= r.length) {
+            pos -= r.length;
+            continue;
+        }
+        const size_t want = std::min(cap - done, (size_t)r.length - pos);
+        crc = crc32c_copy_extend(crc, d + done,
+                                 r.block->data + r.offset + pos, want);
+        done += want;
+        pos = 0;
+    }
+    if (copied != nullptr) *copied = done;
+    static const char kZeros[4096] = {};
+    for (; done < cap; done += std::min(sizeof(kZeros), cap - done)) {
+        crc = crc32c_copy_extend(crc, d + done, kZeros,
+                                 std::min(sizeof(kZeros), cap - done));
+    }
+    return crc;
 }
 
 size_t IOBuf::copy_to(std::string* s, size_t n, size_t pos) const {

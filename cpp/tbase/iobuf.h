@@ -104,6 +104,15 @@ public:
     size_t copy_to(void* buf, size_t n, size_t pos = 0) const;
     size_t copy_to(std::string* s, size_t n = (size_t)-1, size_t pos = 0) const;
     std::string to_string() const;
+    // Stage [pos, pos + cap) of *this into dst[0..cap) in ONE pass: each
+    // block is copied and folded into a crc32c by the same walk
+    // (tbase/crc32c.h crc32c_copy_extend), and where *this ends before
+    // pos + cap the rest of dst is zero-filled and folded in the same way.
+    // Returns the crc32c of all of dst[0..cap); *copied (may be null) is
+    // the bytes that came from *this. How a request attachment, or one
+    // chunk of it, goes into a staging-ring slot (trpc/c_api.h).
+    uint32_t copy_to_crc32c(void* dst, size_t cap, size_t pos,
+                            size_t* copied) const;
     // Contiguous view of the first n bytes WITHOUT consuming: returns a
     // pointer into the first block when it already holds n contiguous
     // bytes (the common case — a readv lands whole headers in one block),

@@ -7,6 +7,7 @@
 #include <thread>
 #include <vector>
 
+#include "tbase/crc32c.h"
 #include "tbase/doubly_buffered_data.h"
 #include "tbase/endpoint.h"
 #include "tbase/fast_rand.h"
@@ -92,6 +93,48 @@ TEST(IOBuf, CopyToWithOffset) {
     buf.copy_to(&s, 5, 6);
     EXPECT_EQ(s, "world");
     EXPECT_EQ(buf.size(), 11u);  // copy_to doesn't consume
+}
+
+// ISSUE 33: one chunk of a request attachment goes into a staging slot by
+// ONE walk from the chunk's offset: blocks straddling the offset and the
+// end, a zero tail where the buffer ends first, and the crc32c of all of
+// dst, whatever the cut.
+TEST(IOBuf, CopyToCrc32cFromAnOffset) {
+    IOBuf buf;
+    std::string all;
+    for (int i = 0; i < 5; ++i) {  // odd lengths over several blocks
+        IOBuf piece;
+        piece.append(std::string(6000 + 7 * i, (char)('a' + i)));
+        buf.append(piece);
+        all += piece.to_string();
+    }
+    ASSERT_TRUE(buf.backing_block_num() >= 4u);
+    const size_t cuts[][2] = {{0, all.size()}, {0, 100}, {5990, 40},
+                              {6000, 6007}, {5, 20000}, {25000, 6000},
+                              {all.size() - 1, 8}, {all.size(), 16},
+                              {all.size() + 50, 16}, {100, 0}};
+    for (const auto& cut : cuts) {
+        const size_t pos = cut[0], cap = cut[1];
+        std::string want = pos < all.size() ? all.substr(pos, cap) : "";
+        const size_t from_buf = want.size();
+        want.resize(cap, '\0');
+        std::string got(cap + 4, '#');
+        size_t copied = 12345;
+        const uint32_t crc = buf.copy_to_crc32c(&got[0], cap, pos, &copied);
+        EXPECT_EQ(copied, from_buf);
+        EXPECT_EQ(got.substr(0, cap), want);
+        EXPECT_EQ(got.substr(cap), "####");  // nothing past cap is touched
+        EXPECT_EQ(crc, crc32c(want.data(), want.size()));
+    }
+    EXPECT_EQ(buf.size(), all.size());  // nothing was consumed
+    // The chunks of a call, laid end to end, are the call: a reader that
+    // walks 4096 at a time sees the same bytes as one that takes it whole.
+    std::string whole(16384, '#'), chunked(16384, '#');
+    buf.copy_to_crc32c(&whole[0], whole.size(), 0, nullptr);
+    for (size_t off = 0; off < chunked.size(); off += 4096) {
+        buf.copy_to_crc32c(&chunked[off], 4096, off, nullptr);
+    }
+    EXPECT_EQ(whole, chunked);
 }
 
 TEST(IOBuf, MoveSemantics) {

@@ -8,7 +8,9 @@
 #include <deque>
 #include <mutex>
 #include <string>
+#include <utility>
 
+#include "kvcache.pb.h"
 #include "rpc_meta.pb.h"
 #include "tbase/crc32c.h"
 #include "tbase/endpoint.h"
@@ -235,7 +237,7 @@ uint64_t tpurpc_ring_inflight_highwater(void* ring) {
     return ((tpurpc::DeviceStagingRing*)ring)->inflight_highwater();
 }
 
-// ---- pull server and blocking client (ISSUE 29) ----
+// ---- pull server and blocking client (ISSUE 29, 33) ----
 
 namespace {
 
@@ -244,40 +246,52 @@ using tpurpc::stage::now_us;
 tpurpc::LazyAdder g_tensor_calls("rpc_tensor_calls");
 tpurpc::LazyAdder g_tensor_bytes_in("rpc_tensor_bytes_in");
 tpurpc::LazyAdder g_tensor_failed("rpc_tensor_failed");
+tpurpc::LazyAdder g_kv_puts("rpc_kv_puts");
+tpurpc::LazyAdder g_kv_gets("rpc_kv_gets");
+tpurpc::LazyAdder g_kv_failed("rpc_kv_failed");
+tpurpc::LazyAdder g_kv_chunks("rpc_kv_chunks");
+tpurpc::LazyAdder g_kv_bytes_landed("rpc_kv_bytes_landed");
+tpurpc::LazyAdder g_kv_evictions("rpc_kv_evictions");
 std::atomic<int64_t> g_parked_highwater{0};
+std::atomic<int64_t> g_kv_pool_bytes{0};
+std::atomic<int64_t> g_kv_resident_bytes{0};
 
-int64_t ParkedHighwater(void*) {
-    return g_parked_highwater.load(std::memory_order_relaxed);
+int64_t ReadGauge(void* gauge) {
+    return ((std::atomic<int64_t>*)gauge)->load(std::memory_order_relaxed);
 }
 
-// A call between its handler and its answer: what `done` needs, and the
-// stamp tdev.take_wait starts from.
+// A call between its handler and its answer: what `done` needs, what the
+// taker is told of it, and the stamp tdev.take_wait starts from.
 class PullServer;
 struct ParkedCall {
     tpurpc::Controller* cntl;
     google::protobuf::Closure* done;
     int64_t parked_us;
     PullServer* owner;  // told when a TAKEN call is answered
+    int method;         // TPURPC_METHOD_*
+    uint64_t session;   // Put, Get
+    uint64_t layer;
+    kvpb::PutResponse* put_response;  // Put
 };
 
 void FailCall(ParkedCall* call, int code, const char* text) {
     call->cntl->SetFailed(code, "%s", text);
-    *g_tensor_failed << 1;
+    *(call->method == TPURPC_METHOD_STEP ? g_tensor_failed : g_kv_failed)
+        << 1;
     call->done->Run();
     delete call;
 }
 
-// tensorpb.Tensor/Step: stamp, park, return.
-class PullServer : public tensorpb::Tensor {
+// One queue of parked calls behind the handlers of both services: stamp,
+// park, return.
+class PullServer {
 public:
-    void Step(google::protobuf::RpcController* cntl_base,
-              const tensorpb::StepRequest* request,
-              tensorpb::StepResponse* response,
-              google::protobuf::Closure* done) override {
-        auto* cntl = static_cast<tpurpc::Controller*>(cntl_base);
-        response->set_send_ts_us(request->send_ts_us());
-        *g_tensor_bytes_in << (int64_t)cntl->request_attachment().size();
-        auto* call = new ParkedCall{cntl, done, now_us(), this};
+    PullServer() {
+        step_service.owner = this;
+        cache_service.owner = this;
+    }
+
+    void Park(ParkedCall* call) {
         int closed_code = 0;
         {
             std::lock_guard<std::mutex> g(mu_);
@@ -292,7 +306,7 @@ public:
             }
         }
         if (closed_code != 0) {
-            FailCall(call, closed_code, "tensor service is closed");
+            FailCall(call, closed_code, "the service is closed");
             return;
         }
         cv_.notify_one();
@@ -344,12 +358,50 @@ public:
         }
         cv_.notify_all();
         for (ParkedCall* call : orphans) {
-            FailCall(call, closed_code_, "tensor service closed with the "
-                                         "call parked");
+            FailCall(call, closed_code_, "the service closed with the call "
+                                         "parked");
         }
     }
 
+    // tensorpb.Tensor/Step.
+    struct StepService : public tensorpb::Tensor {
+        PullServer* owner = nullptr;
+        void Step(google::protobuf::RpcController* cntl_base,
+                  const tensorpb::StepRequest* request,
+                  tensorpb::StepResponse* response,
+                  google::protobuf::Closure* done) override {
+            auto* cntl = static_cast<tpurpc::Controller*>(cntl_base);
+            response->set_send_ts_us(request->send_ts_us());
+            *g_tensor_bytes_in << (int64_t)cntl->request_attachment().size();
+            owner->Park(new ParkedCall{cntl, done, now_us(), owner,
+                                       TPURPC_METHOD_STEP, 0, 0, nullptr});
+        }
+    };
+
+    // kvpb.Cache/Put and /Get.
+    struct CacheService : public kvpb::Cache {
+        PullServer* owner = nullptr;
+        void Put(google::protobuf::RpcController* cntl_base,
+                 const kvpb::PutRequest* request, kvpb::PutResponse* response,
+                 google::protobuf::Closure* done) override {
+            owner->Park(new ParkedCall{
+                static_cast<tpurpc::Controller*>(cntl_base), done, now_us(),
+                owner, TPURPC_METHOD_PUT, request->session(),
+                request->layer(), response});
+        }
+        void Get(google::protobuf::RpcController* cntl_base,
+                 const kvpb::GetRequest* request, kvpb::GetResponse*,
+                 google::protobuf::Closure* done) override {
+            owner->Park(new ParkedCall{
+                static_cast<tpurpc::Controller*>(cntl_base), done, now_us(),
+                owner, TPURPC_METHOD_GET, request->session(),
+                request->layer(), nullptr});
+        }
+    };
+
     tpurpc::Server server;
+    StepService step_service;
+    CacheService cache_service;
 
 private:
     std::mutex mu_;
@@ -363,28 +415,64 @@ private:
 struct ClientChannel {
     tpurpc::Channel channel;
     tensorpb::Tensor_Stub stub{&channel};
+    kvpb::Cache_Stub cache{&channel};
 };
+
+// `done` on the calling thread: trpc.handler ends, the reply is enqueued.
+void Answer(ParkedCall* call, int64_t entered_us) {
+    PullServer* owner = call->owner;
+    call->done->Run();
+    tpurpc::stage::Add(tpurpc::stage::kDevReply, now_us() - entered_us);
+    delete call;
+    if (owner->Answered()) delete owner;
+}
+
+// A client call's outcome: its error code with the text in err[0..err_cap),
+// or 0 with the reply attachment's length in *out_len and as much of it as
+// fits in out[0..cap).
+int CallOutcome(tpurpc::Controller& cntl, char* err, size_t err_cap,
+                void* out = nullptr, size_t cap = 0,
+                size_t* out_len = nullptr) {
+    if (cntl.Failed()) {
+        if (err != nullptr && err_cap > 0) {
+            snprintf(err, err_cap, "%s", cntl.ErrorText().c_str());
+        }
+        return cntl.ErrorCode() != 0 ? cntl.ErrorCode() : -1;
+    }
+    const tpurpc::IOBuf& att = cntl.response_attachment();
+    if (out_len != nullptr) *out_len = att.size();
+    if (out != nullptr && cap > 0) att.copy_to(out, cap);
+    return 0;
+}
 
 }  // namespace
 
 void* tpurpc_server_start(int port) {
     if (tpurpc_global_init() != 0) return nullptr;
     // In /vars from the first scrape, before the first call.
-    *g_tensor_calls << 0;
-    *g_tensor_bytes_in << 0;
-    *g_tensor_failed << 0;
-    *g_stage_fused_bytes << 0;
-    static auto* highwater = [] {
-        auto* v = new tpurpc::PassiveStatus<int64_t>(ParkedHighwater,
-                                                     nullptr);
-        v->expose("rpc_tensor_parked_highwater");
-        return v;
+    for (tpurpc::LazyAdder* counter :
+         {&g_tensor_calls, &g_tensor_bytes_in, &g_tensor_failed, &g_kv_puts,
+          &g_kv_gets, &g_kv_failed, &g_kv_chunks, &g_kv_bytes_landed,
+          &g_kv_evictions, &g_stage_fused_bytes}) {
+        **counter << 0;
+    }
+    static const bool gauges = [] {
+        const std::pair<const char*, std::atomic<int64_t>*> all[] = {
+            {"rpc_tensor_parked_highwater", &g_parked_highwater},
+            {"rpc_kv_pool_bytes", &g_kv_pool_bytes},
+            {"rpc_kv_resident_bytes", &g_kv_resident_bytes}};
+        for (const auto& gauge : all) {
+            (new tpurpc::PassiveStatus<int64_t>(ReadGauge, gauge.second))
+                ->expose(gauge.first);
+        }
+        return true;
     }();
-    (void)highwater;
+    (void)gauges;
     auto* ps = new PullServer;
     tpurpc::EndPoint listen;
     tpurpc::str2endpoint("127.0.0.1", port, &listen);
-    if (ps->server.AddService(ps) != 0 ||
+    if (ps->server.AddService(&ps->step_service) != 0 ||
+        ps->server.AddService(&ps->cache_service) != 0 ||
         ps->server.Start(listen, nullptr) != 0) {
         delete ps;
         return nullptr;
@@ -397,7 +485,7 @@ int tpurpc_server_port(void* server) {
 }
 
 void* tpurpc_server_take(void* server, long timeout_us, size_t* len,
-                         int* status) {
+                         int* status, uint64_t what[3]) {
     int st = 0;
     ParkedCall* call = ((PullServer*)server)->Take(timeout_us, &st);
     if (status != nullptr) *status = st;
@@ -405,6 +493,11 @@ void* tpurpc_server_take(void* server, long timeout_us, size_t* len,
     tpurpc::stage::Add(tpurpc::stage::kTakeWait,
                        now_us() - call->parked_us);
     if (len != nullptr) *len = call->cntl->request_attachment().size();
+    if (what != nullptr) {
+        what[0] = (uint64_t)call->method;
+        what[1] = call->session;
+        what[2] = call->layer;
+    }
     return call;
 }
 
@@ -425,24 +518,12 @@ void tpurpc_server_stop(void* server) {
     if (ps->Stopped()) delete ps;
 }
 
-long tpurpc_call_copy_out(void* call, void* dst, size_t cap,
+long tpurpc_call_copy_out(void* call, size_t offset, void* dst, size_t cap,
                           uint32_t* crc_out) {
-    const tpurpc::IOBuf& att = ((ParkedCall*)call)->cntl->request_attachment();
-    char* d = (char*)dst;
     size_t copied = 0;
-    uint32_t crc = 0;
-    for (size_t i = 0; i < att.backing_block_num() && copied < cap; ++i) {
-        size_t len = 0;
-        const char* data = att.backing_block_data(i, &len);
-        len = std::min(len, cap - copied);
-        crc = tpurpc::crc32c_copy_extend(crc, d + copied, data, len);
-        copied += len;
-    }
-    static const char kZeros[4096] = {};
-    for (size_t at = copied; at < cap; at += sizeof(kZeros)) {
-        crc = tpurpc::crc32c_copy_extend(
-            crc, d + at, kZeros, std::min(sizeof(kZeros), cap - at));
-    }
+    const uint32_t crc =
+        ((ParkedCall*)call)->cntl->request_attachment().copy_to_crc32c(
+            dst, cap, offset, &copied);
     *g_stage_fused_bytes << (int64_t)cap;
     if (crc_out != nullptr) *crc_out = crc;
     return (long)copied;
@@ -454,18 +535,38 @@ int tpurpc_flag_set(const char* name, const char* value) {
 
 void tpurpc_tensor_step_answered(void) { *g_tensor_calls << 1; }
 
+void tpurpc_kv_chunk_landed(size_t nbytes) {
+    *g_kv_chunks << 1;
+    *g_kv_bytes_landed << (int64_t)nbytes;
+}
+
+void tpurpc_kv_pool_state(long pool_bytes, long resident_bytes,
+                          long evicted) {
+    g_kv_pool_bytes.store(pool_bytes, std::memory_order_relaxed);
+    g_kv_resident_bytes.store(resident_bytes, std::memory_order_relaxed);
+    *g_kv_evictions << (int64_t)evicted;
+}
+
 int tpurpc_call_reply(void* handle, const void* body, size_t n,
                       const void* tail, size_t tail_n) {
     auto* call = (ParkedCall*)handle;
-    PullServer* owner = call->owner;
     const int64_t entered_us = now_us();
     tpurpc::IOBuf& att = call->cntl->response_attachment();
     if (n > 0) att.append(body, n);
     if (tail_n > 0) att.append(tail, tail_n);
-    call->done->Run();  // trpc.handler ends, the reply is enqueued
-    tpurpc::stage::Add(tpurpc::stage::kDevReply, now_us() - entered_us);
-    delete call;
-    if (owner->Answered()) delete owner;
+    if (call->method == TPURPC_METHOD_GET) *g_kv_gets << 1;
+    Answer(call, entered_us);
+    return 0;
+}
+
+int tpurpc_call_reply_put(void* handle, uint32_t word, uint64_t admitted) {
+    auto* call = (ParkedCall*)handle;
+    if (call->put_response == nullptr) return -1;  // not a Put
+    const int64_t entered_us = now_us();
+    call->put_response->set_word(word);
+    call->put_response->set_admitted(admitted);
+    *g_kv_puts << 1;
+    Answer(call, entered_us);
     return 0;
 }
 
@@ -507,16 +608,41 @@ int tpurpc_channel_call(void* channel, const void* req, size_t n, void* out,
     request.set_send_ts_us(now_us());
     cntl.request_attachment().append(req, n);
     cc->stub.Step(&cntl, &request, &response, nullptr);
-    if (cntl.Failed()) {
-        if (err != nullptr && err_cap > 0) {
-            snprintf(err, err_cap, "%s", cntl.ErrorText().c_str());
-        }
-        return cntl.ErrorCode() != 0 ? cntl.ErrorCode() : -1;
-    }
-    const tpurpc::IOBuf& att = cntl.response_attachment();
-    if (out_len != nullptr) *out_len = att.size();
-    if (out != nullptr && cap > 0) att.copy_to(out, cap);
-    return 0;
+    return CallOutcome(cntl, err, err_cap, out, cap, out_len);
+}
+
+int tpurpc_channel_put(void* channel, uint64_t session, uint32_t layer,
+                       const void* req, size_t n, uint32_t* word,
+                       uint64_t* admitted, long timeout_ms, char* err,
+                       size_t err_cap) {
+    auto* cc = (ClientChannel*)channel;
+    tpurpc::Controller cntl;
+    cntl.set_timeout_ms(timeout_ms);
+    cntl.set_max_retry(0);
+    kvpb::PutRequest request;
+    kvpb::PutResponse response;
+    request.set_session(session);
+    request.set_layer(layer);
+    cntl.request_attachment().append(req, n);
+    cc->cache.Put(&cntl, &request, &response, nullptr);
+    if (word != nullptr) *word = response.word();
+    if (admitted != nullptr) *admitted = response.admitted();
+    return CallOutcome(cntl, err, err_cap);
+}
+
+int tpurpc_channel_get(void* channel, uint64_t session, uint32_t layer,
+                       void* out, size_t cap, size_t* out_len,
+                       long timeout_ms, char* err, size_t err_cap) {
+    auto* cc = (ClientChannel*)channel;
+    tpurpc::Controller cntl;
+    cntl.set_timeout_ms(timeout_ms);
+    cntl.set_max_retry(0);
+    kvpb::GetRequest request;
+    kvpb::GetResponse response;
+    request.set_session(session);
+    request.set_layer(layer);
+    cc->cache.Get(&cntl, &request, &response, nullptr);
+    return CallOutcome(cntl, err, err_cap, out, cap, out_len);
 }
 
 void tpurpc_channel_close(void* channel) { delete (ClientChannel*)channel; }

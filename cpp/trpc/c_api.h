@@ -123,21 +123,29 @@ long tpurpc_transport_tier_sgl_max(int tier);
 // completes; bytes for socket-attached tiers).
 long tpurpc_transport_tier_ops(int tier);
 
-// ---- pull server and blocking client (ISSUE 29) ----
+// ---- pull server and blocking client (ISSUE 29, 33) ----
 // The process that holds the chip is the Python/JAX one, so a replica is a
-// Server INSIDE that process. It hosts tensorpb.Tensor/Step (attachment
-// in, attachment out, tpu_std) on the listener `echo_bench --ici-server`
-// opens (TCP handshake, then the shm queue pair; builtin portal on the
-// same port). The method's handler only stamps and PARKS the call and
-// returns: no fiber worker ever runs the caller's code or waits for its
-// interpreter lock. The process pulls parked calls with
-// tpurpc_server_take and answers each from whichever thread it likes.
+// Server INSIDE that process. It hosts two services, both tpu_std, on the
+// listener `echo_bench --ici-server` opens (TCP handshake, then the shm
+// queue pair; builtin portal on the same port):
+//   tensorpb.Tensor/Step  attachment in, attachment out (tensor.proto);
+//   kvpb.Cache/Put, /Get  a layer of a prompt's cache into, and out of, a
+//                         pool that stays on the device (kvcache.proto).
+// Every method's handler only stamps and PARKS the call and returns: no
+// fiber worker ever runs the caller's code or waits for its interpreter
+// lock. The process pulls parked calls of all methods, in the order they
+// arrived, with tpurpc_server_take and answers each from whichever thread
+// it likes.
 //
 // Stages (tvar/stage_recorder.h), both inside/around trpc.handler:
 // tdev.take_wait = handler entered -> taken; tdev.reply = reply entered ->
 // reply enqueued on the socket. /vars: rpc_tensor_calls (steps whose result
 // the device gave back: tpurpc_tensor_step_answered), rpc_tensor_bytes_in,
-// rpc_tensor_failed, rpc_tensor_parked_highwater.
+// rpc_tensor_failed, rpc_tensor_parked_highwater (calls of any method);
+// rpc_kv_puts, rpc_kv_gets (answered without error), rpc_kv_failed,
+// rpc_kv_chunks, rpc_kv_bytes_landed (tpurpc_kv_chunk_landed),
+// rpc_kv_evictions, rpc_kv_pool_bytes, rpc_kv_resident_bytes
+// (tpurpc_kv_pool_state).
 //
 // Set one of the framework's flags (tbase/flags.h; what /flags lists) by
 // name, e.g. socket_send_buffer_size: the embedding process's to choose,
@@ -147,11 +155,15 @@ int tpurpc_flag_set(const char* name, const char* value);
 // Starts the server on 127.0.0.1:`port` (0 = ephemeral). NULL on failure.
 void* tpurpc_server_start(int port);
 int tpurpc_server_port(void* server);
+// The method of a parked call, as tpurpc_server_take reports it.
+enum { TPURPC_METHOD_STEP = 0, TPURPC_METHOD_PUT = 1, TPURPC_METHOD_GET = 2 };
 // Blocks up to timeout_us (<0 = until a call or the close) for a parked
-// call. Returns its handle and sets *len to the request attachment's
-// length; NULL with *status 0 on timeout, -2 once the queue is closed.
+// call. Returns its handle, sets *len to the request attachment's length
+// and, where `what` is not NULL, what[0] to the call's method and what[1],
+// what[2] to the request's integer fields (Put, Get: session, layer; Step:
+// 0, 0); NULL with *status 0 on timeout, -2 once the queue is closed.
 void* tpurpc_server_take(void* server, long timeout_us, size_t* len,
-                         int* status);
+                         int* status, uint64_t what[3]);
 // Close the queue: every parked call fails with `code`, every later call
 // fails on arrival, every parked and later take returns (-2). Calls
 // already taken stay the taker's to answer. Idempotent.
@@ -161,28 +173,38 @@ void tpurpc_server_close_queue(void* server, int code);
 // Join), then frees the server, or leaves that to the answer of the last
 // taken call where one is still on its way.
 void tpurpc_server_stop(void* server);
-// Stage the request attachment into dst[0..cap): its blocks are walked
-// once, each copied and folded into the crc in the same pass
-// (crc32c_copy_extend), and what is left of dst is zero-filled and folded
-// in the same way. Returns the attachment bytes copied (its length when
-// cap is enough) and sets *crc_out (may be NULL) to the crc32c of all of
-// dst[0..cap). rpc_stage_fused_bytes += cap.
-long tpurpc_call_copy_out(void* call, void* dst, size_t cap,
+// Stage the request attachment from byte `offset` on into dst[0..cap):
+// its blocks are walked once, each copied and folded into the crc in the
+// same pass (IOBuf::copy_to_crc32c), and where the attachment ends before
+// offset + cap what is left of dst is zero-filled and folded in the same
+// way. Returns the attachment bytes copied and sets *crc_out (may be NULL)
+// to the crc32c of all of dst[0..cap). rpc_stage_fused_bytes += cap.
+long tpurpc_call_copy_out(void* call, size_t offset, void* dst, size_t cap,
                           uint32_t* crc_out);
 // Answer: the response attachment is body ‖ tail (one copy each; tail may
 // be NULL/0). Runs `done` on the calling thread and frees the handle.
 int tpurpc_call_reply(void* call, const void* body, size_t n,
                       const void* tail, size_t tail_n);
+// Answer a Put: the response's `word` and `admitted`, no attachment.
+int tpurpc_call_reply_put(void* call, uint32_t word, uint64_t admitted);
 // rpc_tensor_calls += 1. The service calls it where the D2H of a step's
 // result came back, before it answers with it; an answer made any other
 // way never passes here.
 void tpurpc_tensor_step_answered(void);
-// Fail the call with `code` (a TERR_* or any non-zero int) and frees the
-// handle.
+// rpc_kv_chunks += 1, rpc_kv_bytes_landed += nbytes. The service calls it
+// on the lane's completion thread, where the word of one chunk of a Put
+// came back from the device; an acknowledgement made any other way never
+// passes here.
+void tpurpc_kv_chunk_landed(size_t nbytes);
+// The device pool's gauges, and sessions evicted since the last call:
+// rpc_kv_pool_bytes, rpc_kv_resident_bytes set, rpc_kv_evictions += evicted.
+void tpurpc_kv_pool_state(long pool_bytes, long resident_bytes, long evicted);
+// Fail the call with `code` (a TERR_*, an errno or any non-zero int) and
+// frees the handle.
 int tpurpc_call_fail(void* call, int code, const char* text);
 
-// One blocking client of that service: `ici` != 0 pins the channel to the
-// shm link (Channel::InitIci), else plain TCP. NULL on failure.
+// One blocking client of those services: `ici` != 0 pins the channel to
+// the shm link (Channel::InitIci), else plain TCP. NULL on failure.
 void* tpurpc_channel_open(const char* host, int port, int ici,
                           long timeout_ms);
 // Step(attachment) -> attachment, synchronous, no retry. Returns 0 and
@@ -191,6 +213,15 @@ void* tpurpc_channel_open(const char* host, int port, int ici,
 int tpurpc_channel_call(void* channel, const void* req, size_t n, void* out,
                         size_t cap, size_t* out_len, long timeout_ms,
                         char* err, size_t err_cap);
+// Put(session, layer, attachment) -> *word, *admitted; and
+// Get(session, layer) -> attachment (as tpurpc_channel_call's reply).
+int tpurpc_channel_put(void* channel, uint64_t session, uint32_t layer,
+                       const void* req, size_t n, uint32_t* word,
+                       uint64_t* admitted, long timeout_ms, char* err,
+                       size_t err_cap);
+int tpurpc_channel_get(void* channel, uint64_t session, uint32_t layer,
+                       void* out, size_t cap, size_t* out_len,
+                       long timeout_ms, char* err, size_t err_cap);
 void tpurpc_channel_close(void* channel);
 
 // Frame `payload` as one tpu_std frame: "TRPC" header + RpcMeta

@@ -26,19 +26,20 @@ long-lived `device_path.DeviceLane`):
 
 The pool is `layers` device buffers of uint32[sessions * row], one a layer
 (the sessions' rows end to end: flat, so no row is padded to a tile and a
-chunk is one contiguous run), made when the service starts and never copied: `layers` x `sessions` x
-`layer_bytes` of the chip's memory for the server's life. A chunk is
-`chunk_bytes` (CHUNK_BYTES unless the caller says otherwise: it fits one
-ring slot), the last one of a call zero-padded in the slot (zeros add
-nothing to the word; a row is a whole number of chunks long, so the padding
-lands inside it), so the step has one shape and compiles once, before the
-first call. The answer is `brpc_tpu.kv_reference.Cache`'s: the word does
-not depend on how the call was cut, a session keeps its slot until the pool
-is full and it is the oldest, `Get` of what is not in the pool fails with
-KV_NOT_FOUND and never gives other bytes (a layer is present from its Put's
-last word until its session is evicted or the layer is put again). A Put
-that is not a multiple of 8 bytes from 8 to `layer_bytes`, or names a layer
-the pool has not, fails with TERR_REQUEST.
+chunk is one contiguous run), made when the service starts and never copied:
+`layers` x `sessions` x `layer_bytes` of the chip's memory for the server's
+life. A chunk is `chunk_bytes` (CHUNK_BYTES, 3 MiB, unless the caller says
+otherwise: it fits one ring slot of the largest slab class), the last one of
+a call zero-padded in the slot (zeros add nothing to the word; a row is a
+whole number of chunks long, so the padding lands inside it), so the step
+has one shape and compiles once, before the first call. The answer is
+`brpc_tpu.kv_reference.Cache`'s: the word does not depend on how the call
+was cut, a session keeps its slot until the pool is full and it is the
+oldest, `Get` of what is not in the pool fails with KV_NOT_FOUND and never
+gives other bytes (a layer is present from its Put's last word until its
+session is evicted or the layer is put again). A Put that is not a multiple
+of 8 bytes from 8 to `layer_bytes`, or names a layer the pool has not, fails
+with TERR_REQUEST.
 
 Get runs on the taker: a chunk at a time, `kv_get_step` reads [slot,
 offset] out of the layer's buffer and the bytes come back (D2H); it takes
@@ -66,7 +67,13 @@ from brpc_tpu import kv_reference, native, spans
 from brpc_tpu.lane_service import LaneService
 from brpc_tpu.native import KV_NOT_FOUND, TERR_NO_METHOD, TERR_REQUEST
 
-CHUNK_BYTES = 1 << 20  # what a call is cut into: one ring slot's payload
+# What a call is cut into: one ring slot's payload. The largest whole number
+# of MiB that, with the frame headroom LaneService adds to a slot (1 KiB),
+# fits the largest slab class (4 MiB, cpp/tici/block_pool.cc), so the ring's
+# four slots stay one 16 MiB arena; a chunk's H2D call and dispatch cost the
+# same at 1 and 3 MiB, so fewer, larger chunks are cheaper. A 9 MiB layer is
+# 3 chunks, with no padding.
+CHUNK_BYTES = 3 << 20
 
 
 class _Session:

@@ -27,9 +27,10 @@ device kind and device count it ran beside), then one last line:
               (`tensor_echo_ok`).
 - kv_put      brpc_tpu.kv_service served in this process on device 0, a pool
               of 2 sessions x 3 layers x 9 MiB: a session's 3 layers put
-              (9 chunks each, the word made on the chip against
-              brpc_tpu.kv_reference), read back byte for byte, and evicted
-              whole by the third session (`kv_put_ok`).
+              (in chunks of kv_service.CHUNK_BYTES, 3 MiB: 3 each, the word
+              made on the chip against brpc_tpu.kv_reference), read back
+              byte for byte, and evicted whole by the third session
+              (`kv_put_ok`).
 - collective  __graft_entry__.mesh_data_plane over Mesh(jax.devices()):
               fan-out rows of 4 KiB and 1 MiB, partition shards, all-reduce
               / all-gather / all-to-all at 4 MiB and 64 MiB per rank, framed

@@ -274,6 +274,50 @@ def test_the_word_does_not_depend_on_how_the_call_was_cut(native,
     assert svc.failure is None
 
 
+def test_the_default_chunk_is_the_largest_whole_mib_the_4mib_slot_holds():
+    """ISSUE 34: a slot is the chunk plus 1 KiB of frame headroom
+    (brpc_tpu/lane_service.py:48) and the largest slab class is 4 MiB
+    (cpp/tici/block_pool.cc:323): 3 MiB fits it, 4 MiB does not, and a
+    9 MiB layer of the configuration is 3 of them with nothing padded."""
+    from brpc_tpu.kv_service import CHUNK_BYTES
+
+    assert CHUNK_BYTES % (1 << 20) == 0
+    assert CHUNK_BYTES + 1024 <= 4 << 20 < CHUNK_BYTES + (1 << 20) + 1024
+    assert (9 << 20) % CHUNK_BYTES == 0
+
+
+def test_a_9mib_layer_crosses_the_lane_in_three_chunks_at_the_default_cut(
+        native):
+    """The configuration's own layer size (kv_handoff_1chip: 9 MiB) and
+    the program's default cut: 3 chunks a Put, a pool row with no padding,
+    the reference's word, the layer read back byte for byte."""
+    import jax
+
+    from brpc_tpu import kv_service, spans
+
+    nbytes = 9 << 20
+    svc = kv_service.serve(jax.devices("cpu")[0], layers=1, sessions=1,
+                           layer_bytes=nbytes)
+    channel = native.StepChannel(svc.port, ici=True)
+    try:
+        assert svc.row_chunks == 3 and svc.pool_bytes == nbytes
+        x = layer_bytes(34, nbytes)
+        before = counters(svc.port)
+        spans.clear()
+        assert channel.put(7, 0, x) == (kv_reference.word(x), 0)
+        assert channel.get(7, 0, nbytes).tobytes() == x.tobytes()
+        names = span_names("kv.reply")
+        assert names.count("kv.fill") == names.count("ring.launch") == 3
+        after = counters(svc.port)
+        assert after["rpc_kv_chunks"] - before["rpc_kv_chunks"] == 3
+        assert (after["rpc_kv_bytes_landed"]
+                - before["rpc_kv_bytes_landed"]) == nbytes
+    finally:
+        channel.close()
+        svc.close()
+    assert svc.failure is None
+
+
 @pytest.mark.parametrize("nbytes", [8, 16384, 16392, 40000, 65528])
 def test_a_put_shorter_than_the_layer_reads_back_at_its_own_length(
         native, service, channel, nbytes):

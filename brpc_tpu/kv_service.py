@@ -30,16 +30,19 @@ A Get, thread by thread (ISSUE 35):
     taker thread        take a parked Get -> the layer's length from the
                         table (not present: KV_NOT_FOUND at once) -> the
                         reply begun in parts of a chunk each
-                        (ParkedCall.reply_begin: blocks of the shared region
-                        where they are free) -> a READ chunk submitted for
-                        each chunk of the layer (a credit, nothing else)
+                        (ParkedCall.reply_begin: no memory yet) -> a READ
+                        chunk submitted for each chunk of the layer (a
+                        credit, nothing else)
     lane's dispatch     jitted `kv_get_step` a chunk, on the layer's pool
     thread              buffer as the last step before it left it, and the
                         D2H of the chunk asked for
     lane's completion   a chunk's bytes are back -> copied once, in C, from
     thread              the D2H buffer into the reply at their offset
-                        (reply_write) and counted as read; the call's last
-                        one -> the reply is committed and sent
+                        (reply_write: the part takes its memory here, the
+                        shared region's blocks where it can give them all,
+                        which the link posts by reference) and counted as
+                        read; the call's last one -> the reply is committed
+                        and sent
 
 Every pool step runs on the dispatch thread, in submit order, so a step
 never races another for the buffer it replaces, and a Get taken after a

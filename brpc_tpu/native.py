@@ -559,8 +559,10 @@ class ParkedCall:
     def reply_begin(self, nbytes: int, part_bytes: int) -> None:
         """Begin a reply of `nbytes` written in parts of `part_bytes` (the
         last one shorter): `reply_write` each, from one thread at a time,
-        then `reply_commit` answers. Each part is one block of the shared
-        region where one is free, which the link posts by reference."""
+        then `reply_commit` answers. A part takes its memory at its first
+        write: the shared region's where it can give the whole part (a slab
+        slot, else a chain of its 8 KiB blocks), which the link posts by
+        reference."""
         if lib().tpurpc_call_reply_begin(self._ptr, nbytes, part_bytes):
             raise ValueError("the reply was begun already")
 

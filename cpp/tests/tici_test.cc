@@ -3,8 +3,10 @@
 // endpoint plays the role loopback TCP plays"). Covers the block pool,
 // the queue-pair data path, credit flow control, event suppression, EOF,
 // and a full RPC echo over the link.
+#include <sys/wait.h>
 #include <unistd.h>
 
+#include <algorithm>
 #include <atomic>
 #include <condition_variable>
 #include <cstring>
@@ -333,6 +335,122 @@ TEST(SlabPool, JumboSlotsFreedOnOtherThreadsComeBack) {
     // One attachment live at a time: at most one fresh carve, whatever
     // the host's core count.
     EXPECT_LE(IciBlockPool::slab_class_stat(cls).carved, carved0 + 1);
+}
+
+// ---------------- the region's general blocks, in batches ----------------
+
+namespace {
+
+constexpr size_t kBlock = IOBuf::DEFAULT_BLOCK_SIZE;
+constexpr size_t kPartBlocks = 386;  // a 3 MiB reply part of 8160-byte payloads
+
+// Runs `check` in a forked child and gives its exit status: what a check
+// takes of the pool's books stays in the child's copy of them, so one that
+// exhausts the region's carve leaves later tests their room. The region's
+// memory is shared with the child; the checks take and give back blocks
+// and write nothing into them. A check returns 0, or the number of the
+// first expectation that failed; a child that hangs is ended by its alarm.
+int InChild(int (*check)()) {
+    const pid_t pid = fork();
+    if (pid == 0) {
+        alarm(30);
+        _exit(check());
+    }
+    int status = 0;
+    if (waitpid(pid, &status, 0) != pid) return -1;
+    return WIFEXITED(status) ? WEXITSTATUS(status) : 128 + WTERMSIG(status);
+}
+
+// Where a block taken by AllocateSharedBlocks may lie: inside the region,
+// whole, short of the link's bounce band (the region's last 8 MiB).
+bool InGeneralArea(const void* b) {
+    uint64_t off = 0;
+    return IciBlockPool::OffsetOf(b, &off) && off % kBlock == 0 &&
+           off + kBlock <= IciBlockPool::shm_size() - (8u << 20);
+}
+
+// Takes every block the region's general area can give, a part's worth at
+// a time and then one at a time, and checks where each lies and what is
+// left for the link.
+int TakeTheWholeGeneralArea() {
+    std::vector<void*> taken;
+    std::vector<void*> part(kPartBlocks);
+    while (IciBlockPool::AllocateSharedBlocks(kPartBlocks, part.data())) {
+        taken.insert(taken.end(), part.begin(), part.end());
+    }
+    void* one = nullptr;
+    while (IciBlockPool::AllocateSharedBlocks(1, &one)) taken.push_back(one);
+    if (taken.size() < kPartBlocks) return 1;  // not even one part's worth
+    for (void* b : taken) {
+        if (!InGeneralArea(b)) return 2;
+    }
+    std::vector<void*> sorted = taken;
+    std::sort(sorted.begin(), sorted.end());
+    if (std::adjacent_find(sorted.begin(), sorted.end()) != sorted.end()) {
+        return 3;  // a block given twice
+    }
+    // Dry: all or none, so a request it cannot serve takes nothing, and it
+    // never grows the pool into an anonymous region for it.
+    const size_t free0 = IciBlockPool::free_blocks();
+    if (IciBlockPool::AllocateSharedBlocks(kPartBlocks, part.data())) return 4;
+    if (IciBlockPool::free_blocks() != free0) return 5;
+    // The link's bounce band is still its own.
+    void* bounce = IciBlockPool::AllocateSharedBlock();
+    uint64_t off = 0;
+    if (bounce == nullptr || !IciBlockPool::OffsetOf(bounce, &off) ||
+        off < IciBlockPool::shm_size() - (8u << 20)) {
+        return 6;
+    }
+    IciBlockPool::DeallocateShared(bounce);
+    // Given back, every block is on the shared freelist again: the whole
+    // lot can be taken in one batch.
+    for (void* b : taken) IciBlockPool::DeallocateShared(b);
+    std::vector<void*> again(taken.size());
+    if (!IciBlockPool::AllocateSharedBlocks(again.size(), again.data())) {
+        return 7;
+    }
+    for (void* b : again) IciBlockPool::DeallocateShared(b);
+    return 0;
+}
+
+// A Get's three parts taken and given back, round after round: the free
+// count is back where it was after each.
+int AGetsPartsComeBackEachRound() {
+    std::vector<void*> blocks(3 * kPartBlocks);
+    if (!IciBlockPool::AllocateSharedBlocks(blocks.size(), blocks.data())) {
+        return 1;
+    }
+    for (void* b : blocks) IciBlockPool::DeallocateShared(b);
+    const size_t free0 = IciBlockPool::free_blocks();
+    for (int round = 0; round < 16; ++round) {
+        for (size_t k = 0; k < 3; ++k) {
+            if (!IciBlockPool::AllocateSharedBlocks(
+                    kPartBlocks, blocks.data() + k * kPartBlocks)) {
+                return 2;
+            }
+        }
+        for (void* b : blocks) {
+            if (!InGeneralArea(b)) return 3;
+        }
+        if (IciBlockPool::free_blocks() != free0 - blocks.size()) return 4;
+        for (void* b : blocks) IciBlockPool::DeallocateShared(b);
+        if (IciBlockPool::free_blocks() != free0) return 5;
+    }
+    return 0;
+}
+
+}  // namespace
+
+TEST(SharedBlocks, TakeTheGeneralAreaOnlyAndNeverGrowForIt) {
+    ASSERT_EQ(0, IciBlockPool::Init());
+    ASSERT_TRUE(IciBlockPool::shm_base() != nullptr);
+    EXPECT_EQ(0, InChild(TakeTheWholeGeneralArea));
+}
+
+TEST(SharedBlocks, AGetsPartsGoBackToTheSharedFreelist) {
+    ASSERT_EQ(0, IciBlockPool::Init());
+    ASSERT_TRUE(IciBlockPool::shm_base() != nullptr);
+    EXPECT_EQ(0, InChild(AGetsPartsComeBackEachRound));
 }
 
 // ---------------- device staging ring (ISSUE 9a) ----------------

@@ -260,6 +260,29 @@ void* IciBlockPool::AllocateSharedBlock() {
     return nullptr;
 }
 
+bool IciBlockPool::AllocateSharedBlocks(size_t n, void** out) {
+    PoolState& p = pool();
+    constexpr size_t kBlock = IOBuf::DEFAULT_BLOCK_SIZE;
+    std::lock_guard<std::mutex> g(p.mu);
+    if (p.shm_base == nullptr) return false;
+    const bool carving_primary =
+        !p.regions.empty() && p.regions.back().base == p.shm_base;
+    const size_t carvable =
+        carving_primary ? (p.carve_limit() - p.carve_offset) / kBlock : 0;
+    if (p.freelist_shared.size() + carvable < n) return false;
+    size_t k = 0;
+    for (; k < n && !p.freelist_shared.empty(); ++k) {
+        out[k] = p.freelist_shared.back();
+        p.freelist_shared.pop_back();
+    }
+    for (; k < n; ++k) {
+        out[k] = p.shm_base + p.carve_offset;
+        p.carve_offset += kBlock;
+    }
+    p.live.fetch_add(n, std::memory_order_relaxed);
+    return true;
+}
+
 void* IciBlockPool::AllocateRegistered(size_t n) {
     PoolState& p = pool();
     std::lock_guard<std::mutex> g(p.mu);

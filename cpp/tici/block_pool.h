@@ -38,6 +38,14 @@ public:
     // null when none is free (bounce buffers for the cross-process send
     // path, which must be peer-visible). Deallocate() as usual.
     static void* AllocateSharedBlock();
+    // `n` DEFAULT_BLOCK_SIZE blocks of the shared region's general area
+    // into out[0..n), taken in one batch under the pool mutex: freed
+    // shared blocks first, then the primary's carve. All or none: false,
+    // and nothing taken, when the region cannot give n -- never from the
+    // bounce band, never from an overflow region. The caller frees each
+    // with DeallocateShared, so it comes back here and to no thread's
+    // cache.
+    static bool AllocateSharedBlocks(size_t n, void** out);
 
     // A large contiguous chunk carved from registered region memory —
     // staging buffers for device DMA (the JAX device-path benchmark

@@ -267,37 +267,69 @@ int64_t ReadGauge(void* gauge) {
     return ((std::atomic<int64_t>*)gauge)->load(std::memory_order_relaxed);
 }
 
-// One part of a reply written in parts: `n` writable bytes at `data`, held
-// by `buf` (one block).
+// One part of a reply written in parts: `n` writable bytes, held by `buf`,
+// in blocks of `stride` bytes each (the last one shorter) starting at
+// `data[i]`; no memory (`data` empty) until it is first written.
 struct ReplyPart {
     tpurpc::IOBuf buf;
-    char* data = nullptr;
+    std::vector<char*> data;
+    size_t stride = 0;
     size_t n = 0;
 };
 
-// A part's block: a slab of the shared region where one is free (the link
-// posts it by reference), else process memory (the link bounce-copies it).
-void NewReplyPart(size_t n, ReplyPart* part) {
-    part->n = n;
-    if (tpurpc::IciBlockPool::AllocatePoolAttachment(n, &part->buf,
-                                                     &part->data)) {
+// Appends `len` bytes of a block whose header is at `mem` to the part; the
+// part's IOBuf holds the only reference.
+void AppendPartBlock(ReplyPart* part, void* mem, uint32_t cap, uint32_t len,
+                     void (*dealloc)(void*)) {
+    auto* b = new (mem) tpurpc::IOBuf::Block;
+    b->nshared.store(1, std::memory_order_relaxed);
+    b->size = len;
+    b->cap = cap;
+    b->portal_next = nullptr;
+    b->dealloc = dealloc;
+    tpurpc::IOBuf::BlockRef ref;
+    ref.offset = 0;
+    ref.length = len;
+    ref.block = b;
+    part->buf.append_ref(ref);
+    b->dec_ref();
+    part->data.push_back(b->data);
+}
+
+// A part's memory, in the shared region where it can give the whole part,
+// so the link posts it by reference: one slab slot, else a chain of the
+// region's general blocks. Else process memory, which the link
+// bounce-copies. Taken when the part is first written, not when the reply
+// is begun, so a reply holds the region only from its bytes' arrival to
+// the peer's consuming them.
+void NewReplyPart(ReplyPart* part) {
+    using tpurpc::IciBlockPool;
+    using tpurpc::IOBuf;
+    const size_t n = part->n;
+    char* slot = nullptr;
+    if (IciBlockPool::AllocatePoolAttachment(n, &part->buf, &slot)) {
+        part->data.push_back(slot);
+        part->stride = n;
         *g_reply_parts_shared << 1;
         return;
     }
-    void* mem = malloc(offsetof(tpurpc::IOBuf::Block, data) + n);
-    auto* b = new (mem) tpurpc::IOBuf::Block;
-    b->nshared.store(1, std::memory_order_relaxed);
-    b->size = (uint32_t)n;
-    b->cap = (uint32_t)n;
-    b->portal_next = nullptr;
-    b->dealloc = free;
-    tpurpc::IOBuf::BlockRef ref;
-    ref.offset = 0;
-    ref.length = (uint32_t)n;
-    ref.block = b;
-    part->buf.append_ref(ref);
-    b->dec_ref();  // the IOBuf holds the only reference
-    part->data = b->data;
+    const size_t stride =
+        IOBuf::DEFAULT_BLOCK_SIZE - offsetof(IOBuf::Block, data);
+    std::vector<void*> blocks((n + stride - 1) / stride);
+    if (IciBlockPool::AllocateSharedBlocks(blocks.size(), blocks.data())) {
+        part->stride = stride;
+        part->data.reserve(blocks.size());
+        for (size_t i = 0; i < blocks.size(); ++i) {
+            AppendPartBlock(part, blocks[i], (uint32_t)stride,
+                            (uint32_t)std::min(stride, n - i * stride),
+                            IciBlockPool::DeallocateShared);
+        }
+        *g_reply_parts_shared << 1;
+        return;
+    }
+    part->stride = n;
+    AppendPartBlock(part, malloc(offsetof(IOBuf::Block, data) + n),
+                    (uint32_t)n, (uint32_t)n, free);
     *g_reply_parts_local << 1;
 }
 
@@ -622,8 +654,7 @@ int tpurpc_call_reply_begin(void* handle, size_t n, size_t part_bytes) {
     call->part_bytes = part_bytes;
     call->parts.resize((n + part_bytes - 1) / part_bytes);
     for (size_t k = 0; k < call->parts.size(); ++k) {
-        NewReplyPart(std::min(part_bytes, n - k * part_bytes),
-                     &call->parts[k]);
+        call->parts[k].n = std::min(part_bytes, n - k * part_bytes);
     }
     return 0;
 }
@@ -634,9 +665,20 @@ int tpurpc_call_reply_write(void* handle, size_t offset, const void* src,
     const size_t k = call->part_bytes ? offset / call->part_bytes : 0;
     if (k >= call->parts.size()) return -1;
     ReplyPart& part = call->parts[k];
-    const size_t at = offset - k * call->part_bytes;
-    if (len > part.n - at) return -1;  // one write stays inside one part
-    memcpy(part.data + at, src, len);
+    size_t at = offset - k * call->part_bytes;
+    if (at > part.n || len > part.n - at) {
+        return -1;  // one write stays inside one part
+    }
+    if (part.data.empty()) NewReplyPart(&part);
+    const char* from = (const char*)src;
+    while (len > 0) {  // scattered across the part's blocks
+        const size_t within = at % part.stride;
+        const size_t n = std::min(len, part.stride - within);
+        memcpy(part.data[at / part.stride] + within, from, n);
+        at += n;
+        from += n;
+        len -= n;
+    }
     *g_reply_part_writes << 1;
     return 0;
 }
@@ -645,7 +687,10 @@ int tpurpc_call_reply_commit(void* handle) {
     auto* call = (ParkedCall*)handle;
     const int64_t entered_us = now_us();
     tpurpc::IOBuf& att = call->cntl->response_attachment();
-    for (ReplyPart& part : call->parts) att.append(part.buf);
+    for (ReplyPart& part : call->parts) {
+        if (part.data.empty()) NewReplyPart(&part);  // never written
+        att.append(part.buf);
+    }
     call->parts.clear();
     if (call->method == TPURPC_METHOD_GET) *g_kv_gets << 1;
     Answer(call, entered_us);

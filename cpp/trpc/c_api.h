@@ -189,12 +189,13 @@ int tpurpc_call_reply(void* call, const void* body, size_t n,
                       const void* tail, size_t tail_n);
 // Answer with an attachment written in parts (ISSUE 35), from any thread
 // but one at a time: begin makes it `n` bytes in parts of `part_bytes`
-// (the last one shorter), each one block: a slab of the shared region
-// where one is free, so the link posts it by reference and copies nothing
-// (/vars rpc_reply_parts_shared), else process memory, which the link
-// bounce-copies (rpc_reply_parts_local). write copies src[0..len) to
-// byte `offset` in one pass; a write stays inside one part
-// (rpc_reply_part_writes += 1). commit answers the call with it, as
+// (the last one shorter), each in the shared region where it can give the
+// whole part -- one slab slot, else a chain of its general 8 KiB blocks --
+// so the link posts it by reference and copies nothing (/vars
+// rpc_reply_parts_shared), else one block of process memory, which the
+// link bounce-copies (rpc_reply_parts_local). write copies src[0..len) to
+// byte `offset` in one pass, across the part's blocks; a write stays
+// inside one part (rpc_reply_part_writes += 1). commit answers the call with it, as
 // tpurpc_call_reply does. 0, or -1 for a begin twice or a write out of
 // bounds. A call failed after begin frees its parts.
 int tpurpc_call_reply_begin(void* call, size_t n, size_t part_bytes);

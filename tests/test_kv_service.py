@@ -2,8 +2,9 @@
 service, the attachment staged from an offset, and `brpc_tpu.kv_service`
 over them, held to the plain reference exactly (integers and bytes: limit
 0). CPU backend, a small pool (3 layers, 2 session slots, 64 KiB a layer,
-16 KiB chunks); every test has a time limit of its own, so a hang fails
-here and stalls nothing."""
+16 KiB chunks) -- but where a Get's reply parts lie, which takes 9 MiB
+layers in a process of its own; every test has a time limit of its own, so
+a hang fails here and stalls nothing."""
 import signal
 import threading
 import urllib.request
@@ -184,6 +185,30 @@ def test_take_says_which_method_and_the_requests_fields(native, pull_server):
                    "get": native.KV_NOT_FOUND}
     with pytest.raises(ValueError):
         put.reply_put(1, 2)  # answered once, for good
+
+
+def test_a_reply_write_outside_one_part_is_refused_and_writes_nothing(
+        native, pull_server):
+    """A reply of 40,000 bytes in parts of 16 KiB (the last one 7,232): a
+    write that crosses into the next part, one that runs past the end of the
+    short last part, and one past the last part are refused; the writes
+    that fit answer with exactly their bytes."""
+    got = []
+    caller = threading.Thread(target=lambda: got.append(native.StepChannel(
+        pull_server.port, ici=True).get(1, 0, 40000).tobytes()))
+    caller.start()
+    call = pull_server.take(10_000_000)
+    call.reply_begin(40000, 16384)
+    x = layer_bytes(9, 40000)
+    for offset, n in ((16000, 1000), (32768 + 7000, 300), (32768 + 8000, 8),
+                      (49152, 8)):
+        with pytest.raises(ValueError):
+            call.reply_write(offset, x[:n])
+    for at in (0, 16384, 32768):
+        call.reply_write(at, x[at:at + 16384])
+    call.reply_commit()
+    caller.join(30)
+    assert not caller.is_alive() and got == [x.tobytes()]
 
 
 @pytest.mark.parametrize("offset, cap", [
@@ -789,3 +814,147 @@ def test_a_gets_reply_is_its_chunks_d2h_buffers_written_in_parts(
         == 3
     assert len(seen) == 3 and len({id(b) for b in seen}) == 3
     assert all(b.nbytes == CHUNK for b in seen)
+
+
+# ------------------------------------------- where a Get's reply parts lie
+
+# The cell's order in a process of its own (the pool is one per process):
+# four streams Put 9 MiB layers at once, whose received blocks carve the
+# shared region's general area past the room for another 4 MiB slab arena
+# (the next one lands outside the region); then a Get, whose 3 MiB parts
+# are chains of the region's 8 KiB blocks; then a Get whose parts are
+# taken while every free block of the region is held (native.region_hold),
+# released once the parts fell back to process memory.
+REGION_SCENARIO = """
+import json, sys, threading, time, urllib.request
+import numpy as np
+sys.path.insert(0, {root!r})
+import jax
+from brpc_tpu import kv_service, native
+
+MIB = 1 << 20
+LAYER = 9 * MIB
+svc = kv_service.serve(jax.devices("cpu")[0], layers=2, sessions=4,
+                       layer_bytes=LAYER)
+
+def fetch(path):
+    with urllib.request.urlopen(f"http://127.0.0.1:{{svc.port}}{{path}}",
+                                timeout=10) as r:
+        return r.read().decode()
+
+def state():
+    got = {{ln.split(" : ")[0]: int(ln.split(" : ")[1].split()[0])
+           for ln in fetch("/vars").splitlines()
+           if ln.startswith("rpc_reply_part")}}
+    (jumbo,) = [c for c in json.loads(fetch("/pools?format=json"))["classes"]
+                if c["bytes"] == 4 * MIB]
+    got["slots_carved"] = jumbo["carved"]
+    return got
+
+def moved(before):
+    after = state()
+    return {{k: after[k] - before[k] for k in after}}
+
+def layer(stream, n):
+    return np.random.default_rng(10 * stream + n).integers(
+        0, 256, LAYER, dtype=np.uint8)
+
+start = threading.Barrier(4)
+
+def stream(s):
+    ch = native.StepChannel(svc.port, ici=True)
+    start.wait()
+    for n in range(2):
+        ch.put(s, n, layer(s, n))
+    ch.close()
+
+streams = [threading.Thread(target=stream, args=(s,)) for s in range(4)]
+for t in streams:
+    t.start()
+for t in streams:
+    t.join()
+ch = native.StepChannel(svc.port, ici=True)
+before = state()
+equal = bool(np.array_equal(ch.get(2, 1, LAYER), layer(2, 1)))
+print("SHARED", json.dumps(dict(moved(before), equal=equal)), flush=True)
+
+gate, entered = threading.Event(), threading.Event()
+real = svc._read_chunk
+
+def gated(n, where):
+    entered.set()
+    gate.wait(30)
+    return real(n, where)
+
+svc._read_chunk = gated
+answer = {{}}
+
+def getter():
+    answer["equal"] = bool(np.array_equal(ch.get(1, 0, LAYER), layer(1, 0)))
+
+before = state()
+t = threading.Thread(target=getter)
+t.start()
+entered.wait(30)  # the Get is in: its request needed the region too
+held = native.region_hold(1 << 40)
+gate.set()
+deadline = time.monotonic() + 30
+while (moved(before)["rpc_reply_parts_local"] < 3
+       and time.monotonic() < deadline):
+    time.sleep(0.005)
+native.region_hold(-1)
+t.join(30)
+svc._read_chunk = real
+print("LOCAL", json.dumps(dict(moved(before), held=held, **answer)),
+      flush=True)
+ch.close()
+svc.close()
+"""
+
+
+@pytest.fixture(scope="module")
+def region_scenario(cpp_build):
+    import json
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parent.parent
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         textwrap.dedent(REGION_SCENARIO.format(root=str(root)))],
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        timeout=LIMIT_S, env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return {key: json.loads(rest) for key, _, rest in
+            (ln.partition(" ") for ln in proc.stdout.splitlines())
+            if key in ("SHARED", "LOCAL")}
+
+
+def test_after_the_puts_a_gets_parts_are_the_regions_own_blocks(
+        region_scenario):
+    """The Puts' received blocks left no room in the region for another
+    slab arena, so each 3 MiB part is a chain of the region's general 8 KiB
+    blocks: all three shared (posted by reference), none in process memory,
+    the bytes equal, and no slab slot of the region given to a part (the 4
+    MiB class carved at most the one slot outside it, tried and returned)."""
+    got = region_scenario["SHARED"]
+    assert got["equal"] is True
+    assert got["rpc_reply_part_writes"] == 3
+    assert got["rpc_reply_parts_shared"] == 3
+    assert got["rpc_reply_parts_local"] == 0
+    assert got["slots_carved"] <= 1
+
+
+def test_a_gets_parts_fall_back_to_process_memory_when_the_region_is_held(
+        region_scenario):
+    """Every free block of the region held while the parts are taken: all
+    three are process memory, counted local, and the Get is still answered
+    with its bytes once the hold is given back."""
+    got = region_scenario["LOCAL"]
+    assert got["held"] > 0
+    assert got["rpc_reply_parts_local"] == 3
+    assert got["rpc_reply_parts_shared"] == 0
+    assert got["equal"] is True
